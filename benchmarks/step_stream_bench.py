@@ -108,9 +108,6 @@ async def bench_hub(n_steps: int, batch: int) -> float:
 
 
 async def main():
-    from dynamo_tpu.runtime.config import apply_platform_env
-
-    apply_platform_env()
     n_steps = int(sys.argv[1]) if len(sys.argv) > 1 else 2000
     batch = int(sys.argv[2]) if len(sys.argv) > 2 else 64
     direct = await bench_direct(n_steps, batch)

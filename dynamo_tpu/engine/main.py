@@ -24,7 +24,7 @@ from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.llm.model_card import ModelDeploymentCard, register_llm
 from dynamo_tpu.router.publisher import KvEventPublisher, WorkerMetricsPublisher
 from dynamo_tpu.runtime import DistributedRuntime
-from dynamo_tpu.runtime.config import setup_logging
+from dynamo_tpu.runtime.config import place_compile_cache, setup_logging
 
 
 def build_engine(cli, cfg: ModelConfig, args: EngineArgs):
@@ -921,6 +921,7 @@ async def amain():
 
 def main():
     setup_logging()
+    place_compile_cache()
     asyncio.run(amain())
 
 

@@ -71,13 +71,17 @@ def parse_spec(spec: str) -> tuple[int, Optional[int]]:
     return bits, group
 
 
-def quantize(w: jax.Array, bits: int = 8, group: Optional[int] = None) -> dict:
+def quantize(w, bits: int = 8, group: Optional[int] = None) -> dict:
     """Symmetric quantization of ``w[..., I, O]`` along the contraction dim.
 
     group=None → one scale per output channel; group=g → one scale per
-    (g-chunk of I, output channel)."""
+    (g-chunk of I, output channel). A numpy ``w`` is quantized on the host
+    (checkpoint loaders: the full-width original never reaches the
+    device); a jax array or tracer is quantized where it lives, so the
+    same math runs under ``jit`` for on-device random init."""
+    xp = np if isinstance(w, np.ndarray) else jnp
     qmax = (1 << (bits - 1)) - 1  # 127 / 7
-    wf = np.asarray(w, np.float32)
+    wf = w.astype(xp.float32)
     I, O = wf.shape[-2], wf.shape[-1]
     if group is None:
         group = I
@@ -85,9 +89,13 @@ def quantize(w: jax.Array, bits: int = 8, group: Optional[int] = None) -> dict:
         raise ValueError(f"contraction dim {I} not divisible by group {group}")
     G = I // group
     grp = wf.reshape(*wf.shape[:-2], G, group, O)
-    s = np.max(np.abs(grp), axis=-2, keepdims=True) / qmax  # [..., G, 1, O]
-    s = np.maximum(s, 1e-12)
-    q = np.clip(np.rint(grp / s), -qmax, qmax)
+    # under jit the barrier keeps this a true division, bit-equal to the
+    # host path (XLA would multiply by the inexact reciprocal of a constant)
+    div = qmax if xp is np else jax.lax.optimization_barrier(
+        jnp.float32(qmax))
+    s = xp.max(xp.abs(grp), axis=-2, keepdims=True) / div  # [..., G, 1, O]
+    s = xp.maximum(s, 1e-12)
+    q = xp.clip(xp.rint(grp / s), -qmax, qmax)
     dt = jnp.int8 if bits == 8 else jnp.int4
     return {"q": jnp.asarray(q.reshape(wf.shape), dt),
             "s": jnp.asarray(s[..., 0, :], jnp.float32)}  # [..., G, O]
@@ -150,7 +158,7 @@ def stack_layers(xs: list):
     return jnp.stack(xs)
 
 
-def _quant_walk(tree: dict, bits: int, group: Optional[int], leaf) -> dict:
+def quant_walk(tree: dict, bits: int, group: Optional[int], leaf) -> dict:
     """Shared eligibility walk for the real and abstract quantizers:
     ``leaf(v, group)`` maps each eligible weight; narrow projections that
     do not divide the group fall back to per-channel (or stay full-width
@@ -158,7 +166,7 @@ def _quant_walk(tree: dict, bits: int, group: Optional[int], leaf) -> dict:
     out = {}
     for k, v in tree.items():
         if isinstance(v, dict):
-            out[k] = _quant_walk(v, bits, group, leaf)
+            out[k] = quant_walk(v, bits, group, leaf)
         elif k in QUANT_KEYS:
             g = group
             if g is not None and v.shape[-2] % g:
@@ -190,7 +198,7 @@ def quantize_params(params: dict, spec: str) -> dict:
     host (numpy) so the bf16 originals never need to be device-resident
     together with the quantized copies."""
     bits, group = parse_spec(spec)
-    return _quant_walk(params, bits, group,
+    return quant_walk(params, bits, group,
                        lambda v, g: quantize(v, bits=bits, group=g))
 
 
@@ -208,24 +216,29 @@ def quantize_params_abstract(params: dict, spec: str) -> dict:
                 "s": jax.ShapeDtypeStruct((*v.shape[:-2], G, v.shape[-1]),
                                           jnp.float32)}
 
-    return _quant_walk(params, bits, group, leaf)
+    return quant_walk(params, bits, group, leaf)
+
+
+def qtensor_shardings(sh, ndim: int) -> dict:
+    """Shardings of one QTensor from its weight's: ``q`` like the weight,
+    ``s`` like the weight with its contraction dim replicated (scales are
+    [..., G, O] — G rarely divides meshes evenly, and they are tiny)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    spec = list(sh.spec) + [None] * (ndim - len(sh.spec))
+    s_spec = list(spec)
+    s_spec[-2] = None  # scales: replicate the grouped dim
+    return {"q": NamedSharding(sh.mesh, P(*spec)),
+            "s": NamedSharding(sh.mesh, P(*s_spec))}
 
 
 def quant_shardings(shardings: dict, params: dict) -> dict:
     """Mirror a param-sharding tree onto a (partially) quantized param
-    tree: each QTensor gets ``q`` sharded like the original weight and
-    ``s``/``z`` sharded like the weight with its contraction dim
-    replicated (scales are [..., G, O] — G rarely divides meshes evenly,
-    and they are tiny)."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    tree (see :func:`qtensor_shardings`; ``z`` follows ``s``)."""
 
     def walk(sh, pt):
         if is_qtensor(pt):
-            spec = list(sh.spec) + [None] * (len(pt["q"].shape) - len(sh.spec))
-            s_spec = list(spec)
-            s_spec[-2] = None  # scales: replicate the grouped dim
-            out = {"q": NamedSharding(sh.mesh, P(*spec)),
-                   "s": NamedSharding(sh.mesh, P(*s_spec))}
+            out = qtensor_shardings(sh, len(pt["q"].shape))
             if "z" in pt:
                 out["z"] = out["s"]
             return out

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.ops.paged_attention import kernel_interpret_mode
+
 _NEG = -1e30
 
 
@@ -153,7 +155,7 @@ def flash_prefill(q, k, v, pos_base, kv_lens, *, sliding_window=None,
     while T % TK:
         TK //= 2
 
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = interpret or kernel_interpret_mode()
 
     # group-major views: q5 [B,KV,G,S,hd], k4/v4 [B,KV,T,hd]
     q5 = q.reshape(B, S, KV, G, hd).transpose(0, 2, 3, 1, 4)

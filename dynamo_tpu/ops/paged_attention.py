@@ -39,10 +39,11 @@ _NEG = -1e30
 _LANE = 128
 
 
-def _hbm_space(pltpu):
-    """``pltpu.HBM`` where the jax version has it; ``ANY`` (compiler keeps
-    un-blocked operands off VMEM) on versions that predate the alias."""
-    return getattr(pltpu, "HBM", pltpu.ANY)
+def kernel_interpret_mode() -> bool:
+    """Pallas TPU kernels compile through Mosaic on the TPU backend and run
+    in interpret mode everywhere else (the CPU tests). Every kernel wrapper
+    asks here, so the engine can state — and check — which one it got."""
+    return jax.default_backend() != "tpu"
 
 
 def _decode_kernel(block_tables_ref, kv_lens_ref, window_ref,
@@ -252,7 +253,7 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, kv_lens, *,
             q, k_cache, v_cache, block_tables, kv_lens, block_size=bs,
             window=window, sinks=sinks, k_scales=k_scales,
             v_scales=v_scales, scale_slot_base=scale_slot_base)
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = interpret or kernel_interpret_mode()
     has_sink = sinks is not None
     win_arr = jnp.asarray([0 if window is None else window],
                           jnp.int32).reshape(1)
@@ -294,8 +295,8 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, kv_lens, *,
     in_specs = [
         pl.BlockSpec((1, H, KVhd), lambda b, *_: (b, 0, 0)),
         pl.BlockSpec((1, H, 1), lambda b, *_: (0, 0, 0)),
-        pl.BlockSpec(memory_space=_hbm_space(pltpu)),
-        pl.BlockSpec(memory_space=_hbm_space(pltpu)),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     scratch = [
         pltpu.VMEM((D, bs, KVhd), k_cache.dtype),  # D pages in flight
@@ -316,8 +317,8 @@ def paged_attention_decode(q, k_cache, v_cache, block_tables, kv_lens, *,
                 pl.BlockSpec((KV, padded_slots), lambda b, *_: (0, 0))]
             operands += [lane_pack_t(k_scales), lane_pack_t(v_scales)]
         else:
-            in_specs += [pl.BlockSpec(memory_space=_hbm_space(pltpu)),
-                         pl.BlockSpec(memory_space=_hbm_space(pltpu))]
+            in_specs += [pl.BlockSpec(memory_space=pltpu.HBM),
+                         pl.BlockSpec(memory_space=pltpu.HBM)]
             scratch += [pltpu.VMEM((D, bs, KV), jnp.float32),
                         pltpu.VMEM((D, bs, KV), jnp.float32)]
             operands += [k_scales.astype(jnp.float32),
@@ -542,7 +543,7 @@ def mla_paged_decode(q_eff, q_rot, latent_cache, rope_cache, block_tables,
     PR = q_rot.shape[-1]
     bs = block_size
     quant = c_scales is not None
-    interpret = interpret or jax.default_backend() != "tpu"
+    interpret = interpret or kernel_interpret_mode()
 
     qe = (q_eff.astype(jnp.float32) * scale).astype(q_eff.dtype)
     qr = (q_rot.astype(jnp.float32) * scale).astype(q_rot.dtype)
@@ -556,8 +557,8 @@ def mla_paged_decode(q_eff, q_rot, latent_cache, rope_cache, block_tables,
     in_specs = [
         pl.BlockSpec((1, H, R), lambda b, *_: (b, 0, 0)),
         pl.BlockSpec((1, H, PR), lambda b, *_: (b, 0, 0)),
-        pl.BlockSpec(memory_space=_hbm_space(pltpu)),
-        pl.BlockSpec(memory_space=_hbm_space(pltpu)),
+        pl.BlockSpec(memory_space=pltpu.HBM),
+        pl.BlockSpec(memory_space=pltpu.HBM),
     ]
     operands = [latent_cache, rope_cache]
     if quant:

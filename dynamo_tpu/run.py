@@ -71,6 +71,8 @@ async def start_worker(runtime, out: str, cli):
         return [handle]
 
     # native JAX engine (aggregated role)
+    from dynamo_tpu.runtime.config import place_compile_cache
+    place_compile_cache()
     from dynamo_tpu.engine.config import EngineArgs, ModelConfig
     from dynamo_tpu.engine.engine import AsyncJaxEngine
     from dynamo_tpu.disagg.handlers import DecodeWorkerHandler

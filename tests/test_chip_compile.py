@@ -1,0 +1,220 @@
+"""The chip's own compiler, asked without the chip.
+
+libtpu is installed wherever these tests run, and it compiles for a TPU that
+is DESCRIBED, not attached (``jax.experimental.topologies``). That is the
+only check short of a chip run that sees what Mosaic refuses — interpret
+mode and ``jax.export`` (tests/test_tpu_export.py stops at the dialect
+verifier) passed kernels the compiler then rejected for a DMA offset it
+could not prove tile-aligned and for lane-dim slices off a multiple of 128.
+Every kernel the serving path can select is compiled here at Mistral-7B and
+Qwen2-7B widths, and the compiled text must contain the Mosaic call.
+
+Rules this file keeps (on-chip-measurement guide §2): the topology, the
+sharding and every shape built from it live in module-scoped fixtures — only
+one process may hold libtpu, so nothing here runs at import, in conftest, in
+a child process or through an autouse fixture, and all of it stays in this
+one file. The persistent compilation cache is off around these compiles (an
+entry written for a described chip cannot be read back without one).
+"""
+
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+#: (H, KV, hd) of the models the dense cells will use
+MISTRAL_7B = (32, 8, 128)
+QWEN2_7B = (28, 4, 128)
+#: (T, R, W) of a decode-heavy step and of a mixed prefill+decode step at
+#: the engine's defaults (max_num_seqs 64, 2048 tokens, 4096 context)
+DECODE_HEAVY = (64, 64, 256)
+MIXED = (2048, 64, 256)
+BS = 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def compile_for_chip(one_chip, no_compile_cache):
+    """compile_for_chip(fn, *specs) -> compiled text (``text=False``: the
+    executable), with every spec placed on the described chip and every
+    kernel wrapper taking its Mosaic path (the local backend is the CPU,
+    where they would interpret)."""
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    def run(fn, *specs, text=True):
+        specs = jax.tree.map(place, specs)
+        with mock.patch("dynamo_tpu.ops.paged_attention.kernel_interpret_mode",
+                        return_value=False), \
+             mock.patch("dynamo_tpu.ops.ragged_attention."
+                        "kernel_interpret_mode", return_value=False), \
+             mock.patch("dynamo_tpu.ops.flash_prefill.kernel_interpret_mode",
+                        return_value=False):
+            # an already-jitted step keeps its own donation of the caches
+            jitted = (fn if hasattr(fn, "lower")
+                      else jax.jit(fn, out_shardings=one_chip))
+            compiled = jitted.lower(*specs).compile()
+            return compiled.as_text() if text else compiled
+
+    return run
+
+
+def spec(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize("trw", [DECODE_HEAVY, MIXED],
+                         ids=["decode_heavy", "mixed"])
+@pytest.mark.parametrize("widths", [MISTRAL_7B, QWEN2_7B],
+                         ids=["mistral_7b", "qwen2_7b"])
+@pytest.mark.parametrize("pages", ["bf16", "int8"])
+def test_ragged_kernel_compiles_for_v5e(compile_for_chip, pages, widths, trw):
+    """The one kernel on the serving path, both page dtypes, with the
+    sliding window traced as the layer scan traces it."""
+    from dynamo_tpu.ops.ragged_attention import ragged_paged_attention
+
+    (H, KV, hd), (T, R, W) = widths, trw
+    slots = 4096 * BS
+    q = spec((T, H, hd), jnp.bfloat16)
+    bt, rows3 = spec((R, W), jnp.int32), spec((R, 3), jnp.int32)
+    win = spec((), jnp.int32)
+    if pages == "bf16":
+        kc = spec((slots, KV, hd), jnp.bfloat16)
+        text = compile_for_chip(
+            lambda q, k, v, bt, r3, w: ragged_paged_attention(
+                q, k, v, bt, r3, block_size=BS, window=w),
+            q, kc, kc, bt, rows3, win)
+    else:
+        # one layer's scale slice of a 2-layer stacked cache, rebased
+        kc = spec((2 * slots, KV, hd), jnp.int8)
+        sc = spec((slots, KV), jnp.float32)
+        text = compile_for_chip(
+            lambda q, k, v, bt, r3, w, ks, vs, base: ragged_paged_attention(
+                q, k, v, bt, r3, block_size=BS, window=w, k_scales=ks,
+                v_scales=vs, scale_slot_base=base),
+            q, kc, kc, bt, rows3, win, sc, sc, spec((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("pages", ["bf16", "int8"])
+def test_flash_prefill_compiles_for_v5e(compile_for_chip, pages):
+    from dynamo_tpu.ops.flash_prefill import flash_prefill_paged
+
+    (H, KV, hd), L, B, S, nb = MISTRAL_7B, 2, 2, 256, 64
+    slots = nb * BS
+    q = spec((B, S, H, hd), jnp.bfloat16)
+    if pages == "bf16":
+        kc = spec((L, slots, KV, hd), jnp.bfloat16)
+    else:
+        kc = {"q": spec((L, slots, KV, hd), jnp.int8),
+              "s": spec((L, slots, KV), jnp.float32)}
+    text = compile_for_chip(
+        lambda *a: flash_prefill_paged(*a, block_size=BS),
+        q, kc, kc, spec((), jnp.int32), spec((B, nb), jnp.int32),
+        spec((B, S), jnp.int32), spec((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_gqa_decode_kernel_compiles_for_v5e(compile_for_chip):
+    """The retired bf16 decode kernel, while forward(ragged=None) can still
+    reach it (the make_step_fn / make_verify_fn oracles)."""
+    from dynamo_tpu.ops.paged_attention import paged_attention_decode
+
+    (H, KV, hd), B, nb = MISTRAL_7B, 8, 64
+    kc = spec((nb * BS, KV, hd), jnp.bfloat16)
+    text = compile_for_chip(
+        lambda *a: paged_attention_decode(*a, block_size=BS),
+        spec((B, H, hd), jnp.bfloat16), kc, kc, spec((B, nb), jnp.int32),
+        spec((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv):
+    """The whole jitted ragged step — layer scan, int8 weights, the kernel
+    inside — at Mistral-7B widths (depth cut to 2: the scan makes the
+    program the same modulo the leading L), and the cache must pass into
+    the kernel without a relayout copy of the pool."""
+    import dataclasses
+
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.models import mistral_7b
+
+    cfg = dataclasses.replace(mistral_7b(), num_layers=2)
+    args = EngineArgs()
+    T, nb = 256, 2048
+    R, W = args.ragged_rows(T), args.max_blocks_per_seq
+    C, _ = M.ragged_grid_shape(T)
+    params = jax.eval_shape(lambda: M.init_params(
+        cfg, jax.random.key(0), quantization="int8"))
+    shape = (cfg.num_layers, nb * BS, cfg.num_kv_heads, cfg.head_dim)
+    cache = (spec(shape, jnp.bfloat16) if kv == "bf16" else
+             {"q": spec(shape, jnp.int8), "s": spec(shape[:-1], jnp.float32)})
+    step = M.make_ragged_step_fn(cfg, BS, None, use_pallas=True,
+                                 kv_quant=kv == "int8")
+    text = compile_for_chip(
+        step, params, spec((5, T), jnp.int32), spec((R, 3), jnp.int32),
+        spec((C,), jnp.int32), spec((R, W), jnp.int32), cache, cache)
+    assert "tpu_custom_call" in text
+    # the pool is 2 x 2 layers x 32768 slots x 8 x 128: no op but the
+    # in-place page write may produce an array of that size
+    pool = f"[{cfg.num_layers},{nb * BS},{cfg.num_kv_heads},{cfg.head_dim}]"
+    copies = [ln for ln in text.splitlines()
+              if " copy(" in ln and pool in ln.split(" copy(")[0]]
+    assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_init_leaf_never_holds_a_float32_copy_on_v5e(compile_for_chip,
+                                                     quantized):
+    """The program that builds a stacked leaf casts (and quantizes) as it
+    generates: output plus temporaries stay below the leaf's float32 size
+    (all 32 layers of Mistral-7B's gate stack are 7.5 GB in f32 — half the
+    chip). Only the TPU compiler shows it: the CPU backend does not fuse
+    the generator."""
+    import dataclasses
+
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.models import mistral_7b
+
+    cfg = dataclasses.replace(mistral_7b(), num_layers=4)
+    leaf = M._init_layer_stack(cfg, jax.random.key(0), cfg.num_layers,
+                               False, jnp.bfloat16)["w_gate"]
+    program = M._leaf_program(leaf.shape, leaf.dtype, True,
+                              (8, None) if quantized else None, None)
+    mem = compile_for_chip(
+        program, jax.eval_shape(lambda: jax.random.key(0)),
+        spec((), jnp.float32), text=False).memory_analysis()
+    f32_bytes = 4 * 4 * cfg.hidden_size * cfg.intermediate_size
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < f32_bytes, (
+        mem.output_size_in_bytes, mem.temp_size_in_bytes)

@@ -230,10 +230,10 @@ async def test_pallas_attention_engine_equivalence():
     """Engine outputs with the Pallas decode kernel (interpret on CPU) must
     match the XLA attention path token-for-token."""
     prompt = list(range(1, 40))
-    # KV*hd must be a lane multiple for the kernel: tiny() has KV=2, hd=16 →
-    # 32 lanes → kernel falls back; use a cfg with KV*hd = 128
+    # hd must be a lane multiple for the kernel: tiny() has hd=16 → the
+    # kernel falls back; use a cfg with hd = 128
     cfg = ModelConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
-                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=64,
+                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=128,
                       dtype="float32", max_position_embeddings=512)
     outs = []
     for use_pallas in (False, True):
@@ -285,7 +285,7 @@ async def test_multi_step_decode_with_pallas_kernel():
     """Burst path + Pallas kernel (interpret on CPU) matches the XLA path."""
     prompt = list(range(1, 30))
     cfg = ModelConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
-                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=64,
+                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=128,
                       dtype="float32", max_position_embeddings=512)
     outs = []
     for use_pallas in (False, True):

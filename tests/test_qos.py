@@ -512,40 +512,6 @@ def _to_decode(sched, seq):
     assert seq in sched.running
 
 
-def test_decode_sit_out_is_bucket_aware():
-    """TTFT protection sheds worse-class decode rows from a step carrying
-    a better-class prefill chunk ONLY when that drops the decode batch
-    into a smaller compiled bucket. In particular it never sheds to an
-    EMPTY batch: dropping the dispatch wholesale measured consistently
-    WORSE on bench.py --qos (interactive TTFT p95 117ms vs 84ms — step-
-    shape oscillation costs more than the batched rows), so an all-worse
-    decode batch rides along."""
-    # bucket-shrinking shed: {int, bat} decode (bucket 2) + int prefill
-    # -> batch row shed, decode bucket drops to 1
-    sched = _sched(max_num_seqs=4)
-    b, i1 = _seq("bat", "batch", isl=8), _seq("int", "interactive", isl=8)
-    _to_decode(sched, b)
-    _to_decode(sched, i1)
-    i2 = _seq("int", "interactive", isl=8)
-    sched.add(i2)
-    plan = sched.plan()
-    assert [w.seq for w in plan.prefill] == [i2]
-    assert plan.decode == [i1]  # batch row shed: bucket 2 -> 1
-    for w in plan.prefill:
-        sched.commit_computed(w.seq, w.start + w.chunk)
-        sched.append_token(w.seq, 5)
-    plan = sched.plan()  # prefill done: the shed row decodes again
-    assert {id(s) for s in plan.decode} == {id(b), id(i1), id(i2)}
-
-    # all-worse decode: never shed to empty — the batch row rides along
-    sched2 = _sched(max_num_seqs=4)
-    b2 = _seq("bat", "batch", isl=8)
-    _to_decode(sched2, b2)
-    sched2.add(_seq("int", "interactive", isl=8))
-    plan = sched2.plan()
-    assert plan.prefill and plan.decode == [b2]
-
-
 def test_admission_preemption_no_livelock():
     """Regression: a higher-class arrival whose tenant carries MORE
     virtual time than the running batch tenant, with only the recompute

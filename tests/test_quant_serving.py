@@ -41,11 +41,11 @@ pytestmark = pytest.mark.anyio
 # ------------------------------------------- ops: int8-KV ragged vs oracle
 
 
-def make_int8_case(key, rows, H=8, KV=2, hd=64, bs=8, num_blocks=24, W=6,
+def make_int8_case(key, rows, H=8, KV=2, hd=128, bs=8, num_blocks=24, W=6,
                    pad_rows=2, pad_tokens=3):
     """Mixed decode/prefill rows over an int8-quantized paged cache.
-    KV·hd = 128 keeps the Pallas lane alignment (the tiny serving config
-    is 2·16 = 32 and legitimately degrades — see the taxonomy tests)."""
+    hd = 128 keeps the Pallas lane alignment (the tiny serving config
+    is hd = 16 and legitimately degrades — see the taxonomy tests)."""
     ks = jax.random.split(key, 3)
     kf = jax.random.normal(ks[0], (num_blocks * bs, KV, hd), jnp.float32)
     vf = jax.random.normal(ks[1], (num_blocks * bs, KV, hd), jnp.float32)
@@ -140,14 +140,14 @@ def test_ragged_oracle_env_switch(monkeypatch):
 def test_ragged_fallback_reason_taxonomy(monkeypatch):
     import dataclasses
 
-    tiny = ModelConfig.tiny()  # KV·hd = 2·16: not lane-aligned
+    tiny = ModelConfig.tiny()  # hd = 16: not lane-aligned
     assert M.ragged_fallback_reason(tiny, None, use_pallas=False) is None
     assert M.ragged_fallback_reason(tiny, None, use_pallas=True) == \
         "lane_align"
     capped = dataclasses.replace(tiny, attn_logit_softcap=30.0)
     assert M.ragged_fallback_reason(capped, None, use_pallas=True) == \
         "softcap"
-    aligned = dataclasses.replace(tiny, head_dim=64)  # 2·64 = 128
+    aligned = dataclasses.replace(tiny, head_dim=128)
     assert M.ragged_fallback_reason(aligned, None, use_pallas=True) is None
     monkeypatch.setenv("DYN_KV_SCALE_VMEM_BYTES", "0")
     assert M.ragged_fallback_reason(aligned, None, use_pallas=True,

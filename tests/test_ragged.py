@@ -39,7 +39,7 @@ pytestmark = pytest.mark.anyio
 # ------------------------------------------------------------- ops level
 
 
-def make_ragged_case(key, rows, H=8, KV=4, hd=32, bs=8, num_blocks=64, W=6,
+def make_ragged_case(key, rows, H=8, KV=4, hd=128, bs=8, num_blocks=64, W=6,
                      pad_rows=1, pad_tokens=3):
     """rows: list of (q_len, kv_len). Returns (q, kc, vc, bt, rows3, T_real)."""
     ks = jax.random.split(key, 3)
