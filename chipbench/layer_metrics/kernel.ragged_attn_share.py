@@ -1,0 +1,9 @@
+"""The Mosaic ragged attention kernel's share of device busy time."""
+SOURCE = "trace"
+
+
+def compute(src):
+    d = src.device()
+    if not d or not src.trace["kernel_on_device"]:
+        return None
+    return 100.0 * d["kernel_s"] / d["busy_s"]
