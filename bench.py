@@ -2771,8 +2771,11 @@ async def tools_bench(on_tpu: bool = False, reps: int = 3,
         {"type": "function", "function": {
             "name": "put", "parameters": {
                 "type": "object",
+                # n is bounded: a bare integer is an unbounded language, and
+                # the random-weight model greedily emits digits up to OSL
                 "properties": {"k": {"enum": ["a", "b"]},
-                               "n": {"type": "integer"}}}}},
+                               "n": {"type": "integer",
+                                     "enum": [0, 1, 12, 250]}}}}},
     ]
     pattern = tool_constraint(tools, "required", None)
     tool_names = {"get", "put"}

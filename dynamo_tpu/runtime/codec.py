@@ -38,3 +38,18 @@ async def read_frame(reader: asyncio.StreamReader) -> Any:
 async def write_frame(writer: asyncio.StreamWriter, obj: Any) -> None:
     writer.write(pack_frame(obj))
     await writer.drain()
+
+
+async def close_server(server: asyncio.base_events.Server, writers) -> None:
+    """Stop listening, end every accepted connection, then wait for both.
+
+    Since Python 3.12 ``Server.wait_closed()`` waits until every accepted
+    connection is gone, and a peer that is frozen (not dead) sends neither
+    data nor FIN — so the connections are ended from this side first.
+    ``abort()`` rather than ``close()``: a close first flushes the write
+    buffer, which a peer that has stopped reading never lets happen.
+    """
+    server.close()
+    for w in writers:
+        w.transport.abort()
+    await server.wait_closed()

@@ -39,7 +39,7 @@ from dataclasses import dataclass, field
 from typing import AsyncIterator, Awaitable, Callable, Optional
 
 from dynamo_tpu.runtime.chaos import get_chaos
-from dynamo_tpu.runtime.codec import read_frame, write_frame
+from dynamo_tpu.runtime.codec import close_server, read_frame, write_frame
 
 logger = logging.getLogger("dynamo.control_plane")
 
@@ -905,17 +905,7 @@ class ControlPlaneServer:
             except Exception:
                 logger.exception("final state snapshot failed")
         if self._server:
-            self._server.close()
-        for conn in list(self._conns):
-            try:
-                conn.writer.close()
-            except Exception:
-                pass
-        if self._server:
-            try:
-                await asyncio.wait_for(self._server.wait_closed(), 5.0)
-            except asyncio.TimeoutError:
-                logger.warning("control-plane server connections did not drain")
+            await close_server(self._server, [c.writer for c in self._conns])
         await self.core.close()
 
     async def _on_conn(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):

@@ -25,7 +25,12 @@ from dataclasses import dataclass
 from typing import Any, AsyncIterator, Optional
 
 from dynamo_tpu.runtime.chaos import ChaosError, get_chaos
-from dynamo_tpu.runtime.codec import pack_frame, read_frame, write_frame
+from dynamo_tpu.runtime.codec import (
+    close_server,
+    pack_frame,
+    read_frame,
+    write_frame,
+)
 from dynamo_tpu.runtime.context import (
     STREAM_ERR_MSG,
     Context,
@@ -153,6 +158,9 @@ class ResponseStreamServer:
         self._server: Optional[asyncio.base_events.Server] = None
         self._port = 0
         self._pending: dict[str, tuple[asyncio.Queue, Context]] = {}
+        #: writers of the connections being served, so stop() can end them:
+        #: a sender that is frozen, not gone, would otherwise hold it for ever
+        self._writers: set[asyncio.StreamWriter] = set()
 
     async def start(self):
         if self._server is not None:
@@ -163,8 +171,8 @@ class ResponseStreamServer:
 
     async def stop(self):
         if self._server:
-            self._server.close()
-            await self._server.wait_closed()
+            # each _on_conn sees EOF and hands its receiver the stream error
+            await close_server(self._server, self._writers)
             self._server = None
         for q, _ in self._pending.values():
             _put_sentinel(q, {"t": "err", "msg": STREAM_ERR_MSG})
@@ -187,6 +195,7 @@ class ResponseStreamServer:
         self._pending.pop(info.stream_id, None)
 
     async def _on_conn(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+        self._writers.add(writer)
         try:
             prologue = await read_frame(reader)
             stream_id = prologue.get("stream_id")
@@ -223,6 +232,7 @@ class ResponseStreamServer:
         except Exception:
             logger.exception("response connection failed")
         finally:
+            self._writers.discard(writer)
             try:
                 writer.close()
             except Exception:

@@ -5,9 +5,16 @@ CPU devices, Pallas kernels run in interpret mode, and what only the chip's
 compiler can say is asked of a DESCRIBED chip (tests/test_chip_compile.py).
 The environment is set here, before any test module imports jax; the chip
 itself is exercised by ``chip_smoke.py``.
+
+Tiers: ``pytest tests/ -m 'not slow'`` is tier 1 (the driver's gate, run with
+``-n 6 --dist loadfile``); ``pytest tests/`` is everything. Every phase of
+every test runs under one time limit (``TEST_LIMIT_S`` below).
 """
 
+import asyncio
+import contextlib
 import os
+import signal
 
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -24,38 +31,86 @@ def anyio_backend():
 
 
 # ---------------------------------------------------------------- test tiers
-# Default `pytest tests/` = fast tier (< ~8 min): the slow tier
-# (tests/slow_tier.txt — heavy sharding/parity variants with faster siblings)
-# is deselected. DYN_TEST_FULL=1 runs everything (the pre-snapshot gate).
-# Explicitly-named tests always run: `pytest tests/test_mla.py::x` works
-# regardless of tier.
+# ``@pytest.mark.slow`` (``pytest.param(..., marks=pytest.mark.slow)`` for one
+# case of a parametrised test) is the only way a test is called slow: heavy
+# sharding/parity variants and multi-process drives that keep a faster sibling
+# in tier 1.
 
-def _slow_tier() -> set:
-    path = os.path.join(os.path.dirname(__file__), "slow_tier.txt")
+
+# --------------------------------------------------------- per-test time limit
+#: seconds any one phase (setup, call, teardown) of a test may take. A hang
+#: then FAILS that test, by name, and the worker goes on to the next, instead
+#: of the whole run sleeping until the driver's clock cuts it. Four times the
+#: slowest tier-1 test on 6 busy workers (test_parity.py::test_llama_parity,
+#: 21-42 s over three whole runs; ROADMAP D13).
+TEST_LIMIT_S = 180.0
+
+
+class TestLimitExceeded(Exception):
+    __test__ = False  # an exception, not a test class
+
+
+def _first_attr(obj, *names):
+    return next((v for v in (getattr(obj, n, None) for n in names)
+                 if v is not None), None)
+
+
+def _await_chain(coro) -> str:
+    """``file:line fn`` of every frame down the await chain of a suspended
+    coroutine (Task.get_stack() shows only the outermost)."""
+    places = []
+    while coro is not None:
+        frame = _first_attr(coro, "cr_frame", "gi_frame", "ag_frame")
+        if frame is None:  # a Future: the end of the chain
+            break
+        places.append(f"{os.path.basename(frame.f_code.co_filename)}:"
+                      f"{frame.f_lineno} {frame.f_code.co_name}")
+        coro = _first_attr(coro, "cr_await", "gi_yieldfrom", "ag_await")
+    return " -> ".join(places)
+
+
+def _waiting_tasks() -> str:
+    """Where each task of the loop running in this thread is suspended; the
+    main thread's own traceback only shows the loop idling in ``select``."""
     try:
-        with open(path) as f:
-            return {ln.strip() for ln in f
-                    if ln.strip() and not ln.startswith("#")}
-    except OSError:
-        return set()
+        tasks = asyncio.all_tasks()
+    except RuntimeError:  # no loop running: the traceback has the place
+        return ""
+    return "".join(f"\n  {t.get_name()}: {_await_chain(t.get_coro())}" for t in tasks)
 
 
-def pytest_collection_modifyitems(config, items):
-    if os.environ.get("DYN_TEST_FULL"):
-        return
-    if any("::" in a for a in config.args):
-        return  # explicit node selection overrides tiering
-    slow = _slow_tier()
-    # node ids are root-relative when run from the repo root; normalize so
-    # `cd tests && pytest` keeps the same tier
-    def in_slow(item):
-        nid = item.nodeid
-        return nid in slow or f"tests/{nid}" in slow
+@contextlib.contextmanager
+def time_limit(seconds: float, name: str):
+    """Raise TestLimitExceeded in the main thread once ``seconds`` have
+    passed, and every ``seconds`` again while the block has not ended (the
+    clean-up that the first raise sets off may hang too). An interval timer
+    and SIGALRM: no dependency, and it reaches a loop idling in ``select``.
+    It raises and does not kill, so ``finally`` blocks run and spawned
+    processes are reaped."""
+    def on_alarm(signum, frame):
+        raise TestLimitExceeded(
+            f"{name} exceeded its limit of {seconds:g} s{_waiting_tasks()}")
 
-    dropped = [it for it in items if in_slow(it)]
-    if dropped:
-        config.hook.pytest_deselected(items=dropped)
-        items[:] = [it for it in items if not in_slow(it)]
+    previous = signal.signal(signal.SIGALRM, on_alarm)
+    signal.setitimer(signal.ITIMER_REAL, seconds, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
+
+
+def _limited(phase: str):
+    @pytest.hookimpl(wrapper=True)
+    def hook(item):
+        with time_limit(TEST_LIMIT_S, f"{item.nodeid} ({phase})"):
+            return (yield)
+    return hook
+
+
+pytest_runtest_setup = _limited("setup")
+pytest_runtest_call = _limited("call")
+pytest_runtest_teardown = _limited("teardown")
 
 
 # ------------------------------------------------------------- chaos fixture

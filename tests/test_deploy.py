@@ -247,6 +247,7 @@ async def test_operator_restarts_on_command_change(tmp_path):
         await op.stop()
 
 
+@pytest.mark.slow
 async def test_kubectl_contract_full_surface(tmp_path, monkeypatch):
     """The k8s path with a REAL subprocess against a fake kubectl binary
     (r2 verdict #10: no cluster in this environment, so the full CLI/JSON

@@ -172,6 +172,7 @@ async def test_pipelined_prefill_stream_chunks_then_final():
     assert nxt + final.bundle.k.shape[1] == (len(prompt) + 3) // 4
 
 
+@pytest.mark.slow
 async def test_pipelined_disagg_matches_aggregated():
     """Full handler flow with streamed chunk scatter == aggregated tokens."""
     prompt = list(range(1, 151))
@@ -451,6 +452,7 @@ class _LocalPrefillClient:
         return stream()
 
 
+@pytest.mark.slow
 async def test_direct_transfer_same_process_matches_aggregated():
     """Co-located prefill+decode negotiate the zero-copy direct path: only
     descriptor frames cross the wire (no page bytes), the decode engine

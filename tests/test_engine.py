@@ -472,7 +472,8 @@ async def test_decode_batch_capped_at_largest_bucket():
     await eng.close()
 
 
-@pytest.mark.parametrize("arch", ["mla_tiny", "gptoss_tiny", "moe_tiny"])
+@pytest.mark.parametrize("arch", [
+    pytest.param("mla_tiny", marks=pytest.mark.slow), "gptoss_tiny", "moe_tiny"])
 async def test_engine_embed_all_families(arch):
     """/v1/embeddings backing path must work for EVERY served family —
     MLA, gpt-oss (windows+sinks), MoE — via the serving forward (r2

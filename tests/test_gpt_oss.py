@@ -63,6 +63,7 @@ def _paged_inputs(token_rows, block_size=4):
             nxt + 1)
 
 
+@pytest.mark.slow
 def test_gpt_oss_logits_parity_vs_hf(hf_checkpoint):
     """Sequences LONGER than the sliding window on the sliding layers —
     window masking, sink softmax, router bias, and the clamped GLU all have
@@ -287,6 +288,7 @@ def _decode_kernel_parity(cfg, seed):
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.slow
 def test_gpt_oss_pallas_decode_matches_xla():
     """Decode kernel with per-layer windows + attention sinks (interpret
     mode) must equal the XLA path — including page SKIPPING on the sliding

@@ -360,6 +360,7 @@ async def test_engine_int8_multi_step_decode():
     await e_ref.close()
 
 
+@pytest.mark.slow
 async def test_engine_int8_spec_decode():
     e_q = _engine(kv_cache_dtype="int8", speculative_tokens=3)
     e_ref = _engine(kv_cache_dtype="int8")

@@ -139,11 +139,10 @@ async def test_onboard_from_disk_after_host_pressure(tmp_path):
     got2 = await collect(eng, req(prompt))
     assert got2 == want
     # once promotion lands the prefix on host, the next cleared-cache
-    # admission onboards it synchronously
-    for _ in range(100):
-        if len(eng.kvbm.host) >= 2:
-            break
-        await asyncio.sleep(0.02)
+    # admission onboards it synchronously. Wait for the promotion task
+    # itself: the two-block host tier is full at every moment, so its size
+    # says nothing about WHICH blocks it holds
+    await asyncio.gather(*list(eng._offload_tasks))
     eng.pool.clear()
     got3 = await collect(eng, req(prompt))
     assert got3 == want

@@ -388,6 +388,7 @@ def test_mla_pallas_decode_sharded():
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.slow
 def test_mla_flash_prefill_matches_xla():
     """The latent flash-prefill kernel (interpret mode on CPU) must equal
     the XLA score-materializing path — logits AND the written caches —

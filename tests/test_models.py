@@ -52,6 +52,7 @@ def test_hf_mapping_round_trip():
     assert cfg.is_moe and cfg.num_experts == 8
 
 
+@pytest.mark.slow
 async def test_moe_engine_generates_deterministically():
     cfg = models.get_model_config("moe_tiny")
     args = EngineArgs(block_size=4, num_blocks=64, max_num_seqs=4,
@@ -75,6 +76,7 @@ async def test_moe_engine_generates_deterministically():
     assert t1 == t2 and len(t1) == 6
 
 
+@pytest.mark.slow
 def test_moe_ep_matches_dense_einsum():
     """The shard_map EP dispatch (capacity-bounded one-hot + psum) must
     reproduce the dense all-experts formulation when capacity is ample."""
@@ -196,6 +198,7 @@ def test_moe_ep_indivisible_batch_falls_back():
     assert logits.shape == (B, cfg.vocab_size)
 
 
+@pytest.mark.slow
 def test_moe_ep_skew_invariance_and_structure():
     """Hot-expert skew must NOT change outputs when capacity can hold the
     worst case (cf >= E/K), the dispatch must be all-to-all (token-sharded),

@@ -69,6 +69,7 @@ def test_ring_attention_kv_len_padding():
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.slow
 def test_ring_attention_on_submesh_with_dp_tp():
     """sp ring composes with dp/tp axes present in the same mesh."""
     mesh = make_mesh(MeshConfig(dp=2, sp=2, tp=2))
@@ -214,7 +215,8 @@ def _pp_inputs(cfg, B, S, W, block_size, kv_len):
     return tokens, positions, slot_map, block_tables, kv_lens, last_idx
 
 
-@pytest.mark.parametrize("pp,M", [(2, 2), (4, 4), (2, 4)])
+@pytest.mark.parametrize("pp,M", [
+    pytest.param(2, 2, marks=pytest.mark.slow), (4, 4), (2, 4)])
 def test_pp_forward_matches_dense(pp, M):
     """GPipe-pipelined prefill (pp stages, M microbatches) must equal the
     plain scan forward: logits AND every cache slot."""

@@ -102,6 +102,7 @@ def test_penalties_compose_with_logit_bias(engine):
     assert _sample_one(engine, seq, [0.0, 1.0, 2.0, 5.0, 0.0]) == 0
 
 
+@pytest.mark.slow
 @pytest.mark.anyio
 async def test_e2e_presence_penalty_forbids_repeats():
     """Greedy decode on random weights repeats tokens; an overwhelming

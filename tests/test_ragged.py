@@ -239,6 +239,7 @@ async def test_ragged_packing_invariant_chunked_prefill():
         kw_b=dict(max_num_batched_tokens=64))
 
 
+@pytest.mark.slow
 async def test_ragged_packing_invariant_mixed():
     """Staggered arrivals: prefill chunks ride steps that carry decode
     rows — the regime the ragged launch exists for."""
@@ -249,6 +250,7 @@ async def test_ragged_packing_invariant_mixed():
         sampling=({}, dict(temperature=0.9, seed=11)), stagger=True)
 
 
+@pytest.mark.slow
 async def test_ragged_sliding_window_packing_invariant():
     cfg = dataclasses.replace(ModelConfig.tiny(), sliding_window=8)
     prompts = [list(range(1, 40)), list(range(50, 64))]
@@ -264,6 +266,7 @@ async def test_ragged_sliding_window_packing_invariant():
         await e_b.close()
 
 
+@pytest.mark.slow
 async def test_ragged_int8_kv_packing_invariant():
     """int8 paged cache: the ragged path dequantizes in the gather (same
     contract as every XLA attention read) — streams stay bit-identical

@@ -139,6 +139,29 @@ async def test_cancellation_stops_worker(cluster):
     await client.stop()
 
 
+async def test_shutdown_with_silent_sender(local_rt):
+    """A sender that connected, sent the prologue and then froze (neither
+    data nor FIN) must not hold shutdown(): the runtime closes the
+    connection itself and the receiver ends in the retryable stream error."""
+    from dynamo_tpu.runtime.codec import read_frame, write_frame
+    from dynamo_tpu.runtime.context import STREAM_ERR_MSG
+
+    server = await local_rt.response_server()
+    info, receiver = server.register_stream(Context())
+    reader, writer = await asyncio.open_connection("127.0.0.1", info.port)
+    try:
+        await write_frame(writer, {"stream_id": info.stream_id})
+        assert (await read_frame(reader))["t"] == "ok"
+        # ... and now the peer says nothing more
+        await asyncio.wait_for(local_rt.shutdown(), 2.0)
+        with pytest.raises(StreamError) as ei:
+            async for _ in receiver:
+                pass
+        assert str(ei.value) == STREAM_ERR_MSG and ei.value.retryable
+    finally:
+        writer.close()
+
+
 async def test_instance_discovery_follows_lease(cluster):
     worker_rt, client_rt = cluster
     ep_w = worker_rt.namespace("ns").component("c").endpoint("d")

@@ -157,6 +157,7 @@ async def test_draft_model_greedy_invariance():
     await eng.close()
 
 
+@pytest.mark.slow
 async def test_draft_model_batched_invariance():
     import asyncio
 

@@ -264,7 +264,10 @@ async def test_schema_validity_property():
             if kind == 0:
                 props[name] = {"type": "boolean"}
             elif kind == 1:
-                props[name] = {"type": "integer"}
+                # bounded: a bare integer is an unbounded language, and a
+                # constraint says which tokens are legal, not when to stop —
+                # a model that greedily prefers digits runs into max_tokens
+                props[name] = {"type": "integer", "enum": [0, 7, 42, -3]}
             elif kind == 2:
                 props[name] = {"enum": ["a", "bc"]}
             else:
