@@ -1,7 +1,9 @@
-"""The parts of ``run.py`` that decide ``correct`` or describe the traced
-slice, on synthetic records."""
+"""The parts of ``run.py`` that decide ``correct``, describe the traced slice
+or count ``setup_s``, on synthetic records."""
 
 from types import SimpleNamespace
+
+import pytest
 
 import run
 
@@ -44,3 +46,17 @@ def test_slice_is_set_against_the_window():
     assert out["window"]["prefill_tokens_per_s"] == 28.0
     assert out["slice_over_window"]["decode_tokens_per_s"] == 1.0
     assert out["slice_over_window"]["steps_per_s"] == 1.0
+
+
+@pytest.mark.parametrize("system_s, rehearsal_s", [
+    (53.0, 0.0),        # a warm cache: no rehearsal, the count as it was
+    (53.0, 58.2),       # the same set-up on a side whose harness rehearsed
+    (241.0, 58.2),      # a checkout's cold first run, which always rehearses
+])
+def test_setup_counts_the_system_not_the_rehearsal(system_s, rehearsal_s):
+    t_start = 1000.0
+    window_start = t_start + system_s + rehearsal_s
+    got = run.setup_seconds(window_start, t_start, rehearsal_s)
+    assert got == pytest.approx(system_s)
+    if not rehearsal_s:
+        assert got == window_start - t_start
