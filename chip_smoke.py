@@ -310,10 +310,14 @@ def check_steps(fleet: Fleet, expect_mixed: bool):
     mixed = sum(1 for s in steps
                 if s.get("decode_rows") and s.get("prefill_chunks"))
     compiled = [s.get("compile_sig") for s in steps if s.get("compile_s")]
+    wide = sum(s.get("wide_tile_rows", 0) for s in steps)
     emit(phase="steps", recorded=len(steps), mixed_prefill_decode=mixed,
-         compiled_while_serving=compiled)
+         wide_tile_rows=wide, compiled_while_serving=compiled)
     if expect_mixed and not mixed:
         raise SystemExit("no step mixed decode rows with a prefill chunk")
+    if not wide:
+        raise SystemExit("no step record counts a row above the ragged "
+                         "kernel's small query tile (every prompt here is)")
     if compiled:
         raise SystemExit(f"steps compiled while serving: {compiled}")
 
@@ -328,8 +332,10 @@ def check_metrics(fleet: Fleet, expect_fallback_empty: bool):
         raise SystemExit(f"frontend /metrics: ttft {ttft} itl {itl}")
     fallback = metric_samples(worker, "dynamo_ragged_fallback_total")
     steps = metric_samples(worker, "dynamo_engine_step_steps")
+    wide = metric_samples(worker, "dynamo_ragged_wide_tile_rows_total")
     emit(phase="metrics", ttft_count=sum(ttft.values()),
          itl_count=sum(itl.values()), ragged_fallback_total=fallback,
+         ragged_wide_tile_rows_total=sum(wide.values()),
          engine_steps_by_kind=steps)
     if expect_fallback_empty and any(fallback.values()):
         raise SystemExit(f"ragged fallbacks counted: {fallback}")

@@ -195,6 +195,11 @@ class AsyncJaxEngine:
             cfg, mesh, args.use_pallas_attention, self._kv_quant,
             nb * args.block_size, args.block_size)
         self.ragged_fallback_total: dict = {}
+        #: rows dispatched with q_len above the kernel's small query tile
+        #: (prompt chunks, long verify rows): how often its wide tile
+        #: engages — dynamo_ragged_wide_tile_rows_total, and per step in
+        #: the flight record
+        self.wide_tile_rows_total = 0
         if self.ragged_fallback_reason is not None:
             logger.warning(
                 "ragged Pallas kernel unavailable (reason=%s): steps take "
@@ -1587,7 +1592,8 @@ class AsyncJaxEngine:
         sched = self.scheduler
         cur = {"ps": sched.preempt_swap_total,
                "pr": sched.preempt_recompute_total,
-               "so": self.swap_out_blocks, "si": self.swap_in_blocks}
+               "so": self.swap_out_blocks, "si": self.swap_in_blocks,
+               "wt": self.wide_tile_rows_total}
         last = self._flight_last
         delta = {k: cur[k] - last.get(k, 0) for k in cur}
         self._flight_last = cur
@@ -1611,6 +1617,7 @@ class AsyncJaxEngine:
             compile_s=compile_s, compile_sig=compile_sig,
             preempt_swap=delta["ps"], preempt_recompute=delta["pr"],
             swap_out_blocks=delta["so"], swap_in_blocks=delta["si"],
+            wide_tile_rows=delta["wt"],
             waiting=sched.num_waiting(), swapped=len(sched.swapped),
             running=len(sched.running),
             starved_decode=(sched.last_starved_decode
@@ -1625,6 +1632,14 @@ class AsyncJaxEngine:
             rec.tags.append("ragged_fallback:" + fb)
         if self.anomaly_profiler is not None:
             self.anomaly_profiler.on_record(rec)
+
+    def _count_wide_rows(self, rows3) -> None:
+        """Called where a step's ``rows3`` is built: the rows whose q_len
+        takes the ragged kernel's wide query tile."""
+        from dynamo_tpu.ops.ragged_attention import NARROW_TILE
+
+        self.wide_tile_rows_total += int(
+            np.count_nonzero(rows3[..., 1] > NARROW_TILE))
 
     @staticmethod
     def _ctx_ids(seqs) -> list:
@@ -1876,6 +1891,7 @@ class AsyncJaxEngine:
             t += chunk
         assert tile <= C, f"chunk grid overflow: {tile} > {C}"
 
+        self._count_wide_rows(rows3)
         operands = {"ints5": ints5, "rows3": rows3, "grid_rows": grid_rows,
                     "block_tables": bt}
         if mm_vec is not None:
@@ -2017,6 +2033,7 @@ class AsyncJaxEngine:
                 t += chunk
             assert tile <= C, f"chunk grid overflow: {tile} > {C}"
 
+        self._count_wide_rows(rows3)
         operands = {"ints5": ints5, "rows3": rows3, "grid_rows": grid_rows,
                     "block_tables": bt}
         new_sig = ("pp", T, Mmb) not in self.compiled_signatures
@@ -2254,6 +2271,7 @@ class AsyncJaxEngine:
             self.compiled_signatures.add(
                 ("verify_fsm" if use_fsm else "verify", T))
             self.padded_tokens_total += T - n * S
+            self._count_wide_rows(rows3)
             operands = {"ints5": ints5, "rows3": rows3,
                         "grid_rows": grid_rows, "block_tables": bt}
             if use_fsm:

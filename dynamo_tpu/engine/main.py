@@ -527,6 +527,12 @@ async def amain():
         "ragged kernel, by reason").add_callback(
         lambda: {(("reason", r),): v
                  for r, v in engine.ragged_fallback_total.items()})
+    runtime.metrics.counter(
+        "ragged_wide_tile_rows_total",
+        "rows dispatched with more query tokens than the ragged kernel's "
+        "small tile (prompt chunks): how often its wide query tile "
+        "engages").add_callback(
+        lambda: {None: engine.wide_tile_rows_total})
     runtime.metrics.gauge(
         "engine_warmup_skipped",
         "1 = requested AOT warmup could not run (multi-host step "

@@ -141,6 +141,9 @@ class StepRecord:
     #: rows this step sampled under a structured-decoding constraint
     #: (device FSM or host oracle) — docs/structured.md
     constrained_rows: int = 0
+    #: rows of this step with more query tokens than the ragged kernel's
+    #: small tile (prompt chunks, long verify rows): its wide tile's work
+    wide_tile_rows: int = 0
     kv_tiers: dict = field(default_factory=dict)  # {g1..g4: blocks}
     onboard_inflight: int = 0
     restore_inflight: int = 0
@@ -185,7 +188,8 @@ class StepRecord:
             d["compile_sig"] = self.compile_sig
         for k in ("preempt_swap", "preempt_recompute", "swap_out_blocks",
                   "swap_in_blocks", "starved_decode", "onboard_inflight",
-                  "restore_inflight", "constrained_rows", "profile_path"):
+                  "restore_inflight", "constrained_rows", "wide_tile_rows",
+                  "profile_path"):
             v = getattr(self, k)
             if v:
                 d[k] = v

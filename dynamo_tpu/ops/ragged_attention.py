@@ -25,36 +25,65 @@ pages are read in the cache's OWN layout. ``[slots, KV, hd]`` is viewed as
 ``[pages, bs·KV, hd]`` — the same bytes, so XLA hands the pool to the kernel
 without a relayout copy (a flattened ``[slots, KV·hd]`` view is a different
 tiling and costs a copy of the whole pool per call) — and a page is one
-index on the leading, untiled dim. Pages stream HBM→VMEM once per query
-tile through a D-deep rotating DMA pipeline as ``[bs·KV, hd]`` tiles; scores
-come from one MXU matmul of the query tile ``[TQ·Hp, hd]`` against ALL KV
-heads' keys of the page (column = slot·KV + kv head), a static mask keeps
-each head's own KV group, and an online softmax folds pages as they land;
-``P @ V`` over the same columns lands directly in ``[TQ·Hp, hd]``, so
-neither q nor the output is ever expanded. Query and output tiles DMA at
-dynamic offsets on the LEADING token dim of 3-D ``[T, Hp, hd]`` operands
-(q_start is data; Mosaic takes a dynamic offset on a tiled dim only when it
-can prove it tile-aligned), so T never enters VMEM whole and the compiled
-signature depends ONLY on (T, R, W) — one program per token budget, not per
-(chunk × batch × width) bucket. Heads pad to the sublane packing (Hp).
+index on the leading, untiled dim.
+
+One grid step is one row, and a row's work is done once:
+
+- **A query tile chosen from the row.** A row of at most ``NARROW_TILE``
+  (8) tokens — decode, speculative verify — is one 8-token tile; a longer
+  row takes 128-token tiles, the last one moved back so it ENDS at the
+  row's end (a row shorter than a tile overruns into the next rows' region
+  of the output, which their own, later grid steps overwrite). The choice
+  is made in the kernel from ``rows3``, so the compiled signature depends
+  ONLY on (T, R, W) — one program per token budget. A 1,024-token chunk
+  streams its prefix 8 times, not 128.
+- **Keys stream in 512-key blocks**, double-buffered: a block's pages DMA
+  HBM→VMEM as ``[bs·KV, hd]`` tiles into one ``[512·KV, hd]`` buffer while
+  the block before it is scored. Only a row's own pages are fetched; the
+  rest of a buffer is masked (and zeroed once, so it is finite).
+- **Each head against its own KV head only.** Rows of a page interleave
+  the KV heads (row = slot·KV + kv), so KV head k's keys are a
+  sublane-STRIDED read of the block buffer; Mosaic strides 32-bit sublanes
+  only, so bf16 / int8 rows are read as 32-bit words and shifted apart
+  (``_load_rows``). The query tile arrives ``[TQ·Hp, hd]`` (row = token·Hp
+  + head; Hp = heads padded to the sublane packing, which makes a tile's
+  data-dependent DMA offset provably aligned) and is regrouped once per
+  tile, by the same strided read, into ``[KV, G·TQ, hd]``: per KV head one
+  ``[G·TQ, hd] x [hd, 512]`` score matmul and one ``[G·TQ, 512] x [512,
+  hd]`` P·V, no group mask and no column another head owns. G is whatever
+  ``H // KV`` is (4 Mistral, 7 Qwen2, 1 MHA); it pads only until the small
+  tile's rows fill a packed sublane tile.
+- **MXU inputs in the stored dtype, f32 accumulation.** bf16 pages meet a
+  bf16 q as they are, int8 pages enter as q's dtype (exact for int8), f32
+  pages (the CPU tests) stay f32. The online-softmax state (m, l, acc)
+  is f32 in VMEM scratch, per KV head, the row statistics lane-replicated.
 
 Sliding windows and attention sinks match the decode kernel. int8 KV pages
 dequantize IN the kernel: the per-(slot, head) f32 scales of one layer ride
-as constant-block VMEM operands, one ``[bs·KV]`` row per page (exactly the
-score columns' order), indexed on the sublane dim and rebased per layer via
-``scale_slot_base``. k-scales multiply the scores, v-scales fold into p
-before the PV matmul, so int8 pages cost the same two DMAs per page as bf16
-at half the bytes. The only degrades to :func:`ragged_attention_xla` are a
-head dim that is not a lane multiple and scale tables past the VMEM budget
-— both static shape facts the engine counts and logs
-(``dynamo_ragged_fallback_total``), never a silent data-dependent branch.
-``DYN_RAGGED_ORACLE=1`` routes to the XLA oracle explicitly (bench/test
-A/B arms only).
+as constant-block VMEM operands ``[rows, KV, 128]`` (a row = 128 // bs
+consecutive pages' scales per KV head along the lanes), rebased per layer
+via ``scale_slot_base``; as a block's pages are fetched, each page's scales
+are rotated to the lanes of its keys' score columns. k-scales multiply the
+scores, v-scales fold into p before the PV matmul, so int8 pages cost the
+same two DMAs per page as bf16 at half the bytes. The only degrades to
+:func:`ragged_attention_xla` are a head dim that is not a lane multiple and
+scale tables past the VMEM budget — both static shape facts the engine
+counts and logs (``dynamo_ragged_fallback_total``), never a silent
+data-dependent branch. ``DYN_RAGGED_ORACLE=1`` routes to the XLA oracle
+explicitly (bench/test A/B arms only).
+
+Trace + lowering of a step program is paid on every start (sixteen
+programs in the warm-up), so the kernel's trace is held small: loops over
+tiles, blocks and KV heads are ``fori_loop``s (strided reads take a traced
+start), index arithmetic is one equation an op (``_div`` / ``_rem``), and
+the launch is jitted so programs that share a (T, R, W) trace it once
+(tests/test_ragged.py bounds the equation count).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -69,6 +98,13 @@ from dynamo_tpu.ops.paged_attention import (
 #: default scope). Pages, tiles and f32 temporaries take ~2 MB; the rest is
 #: room for the VMEM-resident int8 scale tables.
 _VMEM_LIMIT_BYTES = 64 << 20
+#: query tile of a short row (decode, speculative verify); a row with more
+#: tokens than this takes the wide tile (the engine counts those rows)
+NARROW_TILE = 8
+#: query tokens of one tile of a row longer than the small tile
+_WIDE_TILE = 128
+#: keys of one streamed, double-buffered block
+_KEY_BLOCK = 512
 
 
 def ragged_pallas_supported(num_kv_heads: int, head_dim: int) -> bool:
@@ -79,185 +115,310 @@ def ragged_pallas_supported(num_kv_heads: int, head_dim: int) -> bool:
 
 
 def _scale_table_shape(num_kv_heads: int, sc_slots: int, block_size: int):
-    """VMEM shape of one int8 scale table: one row per page holding its
-    [bs, KV] scales flattened, rows padded to the f32 sublane tile and
-    lanes to 128."""
+    """VMEM shape of one int8 scale table: [rows, KV (padded to the f32
+    sublane tile), 128], a row holding the scales of ``128 // bs``
+    consecutive pages, per KV head, along its lanes."""
     pages = -(-sc_slots // block_size)
-    return (-(-pages // 8) * 8,
-            -(-(block_size * num_kv_heads) // _LANE) * _LANE)
+    return (-(-pages // max(1, _LANE // block_size)),
+            -(-num_kv_heads // 8) * 8, _LANE)
 
 
 def ragged_int8_kernel_supported(num_kv_heads: int, sc_slots: int,
                                  block_size: int = 16) -> bool:
     """True when the per-layer k/v scale tables fit the VMEM-resident
     budget: two tables, each double-buffered by the Pallas pipeline (a
-    constant block index is fetched once but still gets two buffers).
-    ``sc_slots`` is the PER-LAYER slot count (the layer-stacked caller
-    passes one layer's slice + scale_slot_base)."""
-    pages, lanes = _scale_table_shape(num_kv_heads, sc_slots, block_size)
-    scale_bytes = 2 * 2 * pages * lanes * 4
+    constant block index is fetched once but still gets two buffers), and a
+    page's scales fill a whole fraction of a lane row. ``sc_slots`` is the
+    PER-LAYER slot count (the layer-stacked caller passes one layer's slice
+    + scale_slot_base)."""
+    if _LANE % block_size:
+        return False
+    scale_bytes = 2 * 2 * 4 * math.prod(
+        _scale_table_shape(num_kv_heads, sc_slots, block_size))
     return scale_bytes <= int(os.environ.get("DYN_KV_SCALE_VMEM_BYTES",
                                              40 << 20))
 
 
+def _div(x, n: int):
+    """``x // n`` and ``x % n`` of a traced NON-NEGATIVE int in one equation
+    each (the Python operators trace a dozen for the sign they guard)."""
+    return jax.lax.div(x, jnp.asarray(n, x.dtype))
+
+
+def _rem(x, n: int):
+    return jax.lax.rem(x, jnp.asarray(n, x.dtype))
+
+
+def _load_rows(ref, start, count: int, stride: int):
+    """Rows ``start, start+stride, ...`` (``count`` of them) of a 2-D VMEM
+    ref — the sublane-strided read that takes ONE head's rows out of a
+    buffer whose rows interleave heads. Mosaic strides 32-bit sublanes only,
+    so a packed dtype (bf16: 2 rows a word, int8: 4) is read as uint32 words
+    and the wanted row shifted out of each; those come back float32, exact
+    (a bf16 is the top half of an f32). ``stride`` is a multiple of the
+    packing; ``start`` may be traced."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    pack = 4 // ref.dtype.itemsize
+    if pack == 1:
+        return ref[pl.ds(start, count, stride=stride), :]
+    words = ref.bitcast(jnp.uint32)[
+        pl.ds(_div(start, pack), count, stride=stride // pack), :]
+    sub = _rem(start, pack).astype(jnp.uint32)
+    if pack == 2:
+        return pltpu.bitcast((words >> (16 * sub)) << 16, jnp.float32)
+    return (pltpu.bitcast(words << (24 - 8 * sub), jnp.int32)
+            >> 24).astype(jnp.float32)
+
+
 def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
                    sbase_ref,  # scalar pf; sbase = scale-table page base
-                   sink_ref,   # [1, Hp, 1] VMEM (zeros when has_sink=False)
-                   q_ref,      # [Tpad, Hp, hd] HBM (softmax scale folded in)
+                   sink_ref,   # [KV, Gp, 128] f32 VMEM, lane-replicated
+                   q_ref,      # [Tpad·Hp, hd] HBM (softmax scale folded in)
                    kcache_ref, vcache_ref,  # [pages, bs·KV, hd] HBM
-                   *rest,  # [ksc_ref, vsc_ref ([sc_pages, lanes] VMEM),]
-                           # out_ref, qbuf, obuf, kbuf, vbuf, qo_sem, dma_sem
-                   bs: int, tq: int, KV: int, G: int, has_sink: bool,
-                   quant: bool):
+                   *rest,  # [ksc_ref, vsc_ref ([rows, KVp, 128] VMEM),]
+                           # out_ref, scratch...
+                   bs: int, tiles: tuple, KV: int, G: int, Gp: int, Hp: int,
+                   PB: int, has_sink: bool, quant: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if quant:
-        (ksc_ref, vsc_ref, out_ref, qbuf, obuf, kbuf, vbuf,
-         qo_sem, dma_sem) = rest
+        ksc_ref, vsc_ref, out_ref, *scratch = rest
+        *scratch, ksb, vsb = scratch
     else:
-        out_ref, qbuf, obuf, kbuf, vbuf, qo_sem, dma_sem = rest
-        ksc_ref = vsc_ref = None
+        out_ref, *scratch = rest
+    (qbuf, qg, m_s, l_s, acc_s, o32, obuf, kbuf, vbuf, qo_sem,
+     dma_sem) = scratch
 
     r = pl.program_id(0)
     q_start = rows3_ref[r, 0]
     q_len = rows3_ref[r, 1]
     kv_len = rows3_ref[r, 2]
     win = win_ref[0]
-    _, Hp, hd = qbuf.shape
-    D = kbuf.shape[0]
-    N = bs * KV  # keys of one page, all KV heads: column c = slot·KV + kv
+    hd = qbuf.shape[1]
+    mm = qg.dtype        # MXU input dtype (module docstring)
+    N = bs * KV          # rows of one page: row = slot·KV + kv head
+    BK = PB * bs         # keys of one streamed block
+    # a KV head's keys are every KV-th row; where KV is not a multiple of
+    # the page dtype's packing they come as ``pieces`` interleaved reads,
+    # and the block's keys are scored in that (piece, page, slot) order
+    pack = 4 // kbuf.dtype.itemsize
+    pieces = pack // math.gcd(KV, pack)
+    ppr = max(1, _LANE // bs)  # pages per lane row of a scale table
 
-    def start_page_dma(w):
-        blk = block_tables_ref[r, w]
-        slot = w % D
-        pltpu.make_async_copy(kcache_ref.at[blk], kbuf.at[slot],
-                              dma_sem.at[slot, 0]).start()
-        pltpu.make_async_copy(vcache_ref.at[blk], vbuf.at[slot],
-                              dma_sem.at[slot, 1]).start()
-
-    def wait_page_dma(w):
-        slot = w % D
-        pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot],
-                              dma_sem.at[slot, 0]).wait()
-        pltpu.make_async_copy(vbuf.at[slot], vbuf.at[slot],
-                              dma_sem.at[slot, 1]).wait()
-
-    n_tiles = (q_len + tq - 1) // tq
-
-    # score layout [TQ·Hp, bs·KV]: row = token·Hp + head, column = page
-    # slot·KV + kv head. A head only reads the columns of its own KV group.
-    rows = jax.lax.broadcasted_iota(jnp.int32, (tq * Hp, N), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (tq * Hp, N), 1)
-    tok_in_tile = rows // Hp
-    key_in_page = cols // KV
-    own_group = (cols % KV) == (rows % Hp) // G
-
-    def tile_body(t, _carry):
-        tok0 = q_start + t * tq
-        # query tile in: tokens ride the LEADING (untiled) dim, so the
-        # data-dependent row offset needs no alignment proof; the packed
-        # array is padded by TQ rows, so the fixed-size copy cannot overrun
-        pltpu.make_async_copy(q_ref.at[pl.ds(tok0, tq)], qbuf,
-                              qo_sem.at[0]).start()
-        pltpu.make_async_copy(qbuf, qbuf, qo_sem.at[0]).wait()
-
-        # positions of this tile: pos0 .. pos0+tq-1 (chunk tokens occupy
-        # the tail of the kv range — the engine's packing contract)
-        pos0 = kv_len - q_len + t * tq
-        hi_pos = jnp.minimum(pos0 + tq - 1, kv_len - 1)
-        num_pages = jnp.minimum((hi_pos + bs) // bs, (kv_len + bs - 1) // bs)
-        # sliding window: the EARLIEST key any tile position can see is
-        # pos0 - win + 1; pages wholly before it are never fetched
-        first_key = jnp.where(win > 0, jnp.maximum(pos0 - win + 1, 0), 0)
-        start_page = first_key // bs
-
-        prefill_n = jnp.minimum(num_pages, start_page + D)
-        jax.lax.fori_loop(start_page, prefill_n,
-                          lambda w, c: (start_page_dma(w), c)[1], 0)
-
-        qt = qbuf[...].astype(jnp.float32).reshape(tq * Hp, hd)
-        q_pos = pos0 + tok_in_tile
-
-        def page_body(w, carry):
-            m, l, acc = carry  # [TQ·Hp,1] f32 ×2, [TQ·Hp,hd] f32
-            wait_page_dma(w)
-            kpage = kbuf[w % D].astype(jnp.float32)  # [bs·KV, hd]
-            vpage = vbuf[w % D].astype(jnp.float32)
-
-            # every head against every KV head's keys in one MXU matmul;
-            # the own_group mask keeps each head's own columns
-            s = jax.lax.dot_general(
-                qt, kpage, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [TQ·Hp, bs·KV]
-            if quant:
-                # int8 pages: one [1, bs·KV] scale row per page, already in
-                # column order (the [slots, KV] tables flatten to it)
-                page = block_tables_ref[r, w] - sbase_ref[0]
-                s = s * ksc_ref[pl.ds(page, 1), :][:, :N]
-
-            key_pos = w * bs + key_in_page
-            mask = own_group & (key_pos <= q_pos) & (key_pos < kv_len)
-            mask = mask & ((win <= 0) | (key_pos > q_pos - win))
-            s = jnp.where(mask, s, _NEG)
-
-            chunk_max = jnp.max(s, axis=1, keepdims=True)
-            new_m = jnp.maximum(m, chunk_max)
-            corr = jnp.exp(m - new_m)
-            # masked columns must contribute exactly 0 — a fully-masked
-            # page leaves new_m at _NEG, where exp(s - new_m) would be 1
-            p = jnp.where(mask, jnp.exp(s - new_m), 0.0)
-            new_l = l * corr + jnp.sum(p, axis=1, keepdims=True)
-            if quant:
-                p = p * vsc_ref[pl.ds(page, 1), :][:, :N]
-            pv = jax.lax.dot_general(
-                p, vpage, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # [TQ·Hp, hd]
-
-            @pl.when(w + D < num_pages)
-            def _():
-                start_page_dma(w + D)
-
-            return new_m, new_l, acc * corr + pv
-
-        if has_sink:
-            # sink slot: seeds the online softmax, contributes no value
-            sk = sink_ref[0].astype(jnp.float32)  # [Hp, 1]
-            m0 = jnp.broadcast_to(sk[None], (tq, Hp, 1)).reshape(tq * Hp, 1)
-            l0 = jnp.ones((tq * Hp, 1), jnp.float32)
-        else:
-            m0 = jnp.full((tq * Hp, 1), _NEG, jnp.float32)
-            l0 = jnp.zeros((tq * Hp, 1), jnp.float32)
-        acc0 = jnp.zeros((tq * Hp, hd), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(start_page, num_pages, page_body,
-                                      (m0, l0, acc0))
-
-        obuf[...] = (acc / jnp.maximum(l, 1e-30)).reshape(
-            tq, Hp, hd).astype(obuf.dtype)
-        # tile out: overruns past q_len land in the NEXT row's region,
-        # which that row's own (later, sequential) grid step overwrites;
-        # the last row's overrun lands in the TQ-row output padding
-        pltpu.make_async_copy(obuf, out_ref.at[pl.ds(tok0, tq)],
-                              qo_sem.at[1]).start()
-        pltpu.make_async_copy(obuf, obuf, qo_sem.at[1]).wait()
-        return 0
-
-    @pl.when(q_len > 0)
+    @pl.when(r == 0)
     def _():
-        jax.lax.fori_loop(0, n_tiles, tile_body, 0)
+        # pages past a row's end are never fetched; what the buffers hold
+        # there is masked, but must be finite (0 · NaN = NaN in P·V)
+        kbuf[...] = jnp.zeros(kbuf.shape, kbuf.dtype)
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        if quant:
+            ksb[...] = jnp.zeros(ksb.shape, ksb.dtype)
+            vsb[...] = jnp.zeros(vsb.shape, vsb.dtype)
+
+    def page_copies(w, b):
+        blk = block_tables_ref[r, w]
+        slot = _rem(b, 2)
+        dst = pl.ds(pl.multiple_of((w - b * PB) * N, N), N)
+        return (pltpu.make_async_copy(kcache_ref.at[blk], kbuf.at[slot, dst],
+                                      dma_sem.at[slot, 0]),
+                pltpu.make_async_copy(vcache_ref.at[blk], vbuf.at[slot, dst],
+                                      dma_sem.at[slot, 1]))
+
+    def place_scales(w, b):
+        # this page's scales go where its keys' score columns will be: one
+        # lane rotation of the table row that holds them, per piece
+        page = block_tables_ref[r, w] - sbase_ref[0]
+        width = bs // pieces
+        lane = jax.lax.broadcasted_iota(jnp.int32, ksb.shape[2:], 1)
+        for j in range(pieces):
+            dest = j * (BK // pieces) + (w - b * PB) * width
+            src = _rem(page, ppr) * bs + j * width
+            at = _rem(dest, _LANE)
+            here = (lane >= at) & (lane < at + width)
+            for table, buf in ((ksc_ref, ksb), (vsc_ref, vsb)):
+                moved = pltpu.roll(table[_div(page, ppr)],
+                                   _rem(at - src + _LANE, _LANE), 1)
+                row = (_rem(b, 2), _div(dest, _LANE))
+                buf[row] = jnp.where(here, moved, buf[row])
+
+    def scale_row(buf, b, k):
+        rows = [buf[_rem(b, 2), i, pl.ds(k, 1), :]
+                for i in range(buf.shape[1])]
+        return (rows[0] if len(rows) == 1
+                else jnp.concatenate(rows, axis=1))[:, :BK]
+
+    def head_rows(buf, b, k):
+        """[BK, hd]: KV head ``k``'s rows of the block in ``buf``."""
+        got = [_load_rows(buf.at[_rem(b, 2)], j * KV + k, BK // pieces,
+                          KV * pieces) for j in range(pieces)]
+        return (got[0] if pieces == 1
+                else jnp.concatenate(got, axis=0)).astype(mm)
+
+    def lanes(x, n):  # a lane-replicated [M, 128] statistic, n lanes wide
+        return x if n == _LANE else jnp.tile(x, (1, n // _LANE))
+
+    def run_tiles(TQ: int):
+        M = Gp * TQ          # score rows of one KV head: row = g·TQ + token
+        span = TQ * Hp       # rows of the tile in q / out: token·Hp + head
+        tok_of_row = _rem(jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0), TQ)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, BK), 1)
+        if pieces == 1:
+            key_of_col = col
+        else:
+            per, width = BK // pieces, bs // pieces
+            key_of_col = (_div(_rem(col, per), width) * bs
+                          + _rem(col, width) * pieces + _div(col, per))
+
+        def tile_body(t, carry):
+            # the last tile is moved back to END at the row's end, so a row
+            # of at least TQ tokens never computes past itself
+            off = jnp.minimum(t * TQ, jnp.maximum(q_len - TQ, 0))
+            at = pl.ds(pl.multiple_of((q_start + off) * Hp, Hp), span)
+            fetch = pltpu.make_async_copy(q_ref.at[at], qbuf.at[pl.ds(0, span)],
+                                          qo_sem.at[0])
+            fetch.start()
+
+            # positions of this tile: pos0 .. pos0+TQ-1 (chunk tokens occupy
+            # the tail of the kv range — the engine's packing contract)
+            pos0 = kv_len - q_len + off
+            hi_pos = jnp.minimum(pos0 + TQ - 1, kv_len - 1)
+            num_pages = _div(hi_pos, bs) + 1
+            # sliding window: the EARLIEST key any tile position can see is
+            # pos0 - win + 1; pages wholly before it are never fetched
+            first_key = jnp.where(win > 0, jnp.maximum(pos0 - win + 1, 0), 0)
+            start_page = _div(first_key, bs)
+            b0, nb = _div(start_page, PB), _div(num_pages + PB - 1, PB)
+
+            def for_pages(b, fn):
+                jax.lax.fori_loop(jnp.maximum(b * PB, start_page),
+                                  jnp.minimum((b + 1) * PB, num_pages),
+                                  lambda w, c: (fn(w), c)[1], 0)
+
+            def start_block(b):
+                def one(w):
+                    for cp in page_copies(w, b):
+                        cp.start()
+                    if quant:
+                        place_scales(w, b)
+                for_pages(b, one)
+
+            start_block(b0)
+            fetch.wait()
+
+            def regroup(k, c):
+                # the G heads of KV head k, each a strided read of the tile
+                heads = [_load_rows(qbuf, k * G + g, TQ, Hp)
+                         for g in range(G)]
+                heads += [jnp.zeros_like(heads[0])] * (Gp - G)
+                qg[k, pl.ds(0, M)] = jnp.concatenate(heads).astype(mm)
+                if has_sink:
+                    # sink slot: seeds the online softmax, adds no value
+                    sk = sink_ref[k]
+                    m_s[k, pl.ds(0, M)] = jnp.concatenate(
+                        [jnp.broadcast_to(sk[g:g + 1], (TQ, _LANE))
+                         for g in range(Gp)])
+                    l_s[k, pl.ds(0, M)] = jnp.ones((M, _LANE), jnp.float32)
+                else:
+                    m_s[k, pl.ds(0, M)] = jnp.full((M, _LANE), _NEG,
+                                                   jnp.float32)
+                    l_s[k, pl.ds(0, M)] = jnp.zeros((M, _LANE), jnp.float32)
+                acc_s[k, pl.ds(0, M)] = jnp.zeros((M, hd), jnp.float32)
+                return c
+
+            jax.lax.fori_loop(0, KV, regroup, 0)
+            q_pos = pos0 + tok_of_row
+
+            def block_body(b, c):
+                for_pages(b, lambda w: [cp.wait() for cp in page_copies(w, b)])
+
+                @pl.when(b + 1 < nb)
+                def _():
+                    start_block(b + 1)
+
+                key_pos = b * BK + key_of_col
+                mask = (key_pos <= q_pos) & (key_pos < kv_len) & (
+                    (win <= 0) | (key_pos > q_pos - win))
+
+                def head_body(k, c2):
+                    rows = (k, pl.ds(0, M))
+                    s = jax.lax.dot_general(
+                        qg[rows], head_rows(kbuf, b, k),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # [M, BK]
+                    if quant:
+                        s = s * scale_row(ksb, b, k)
+                    s = jnp.where(mask, s, _NEG)
+                    m_old = m_s[rows]
+                    m_new = jnp.maximum(m_old,
+                                        jnp.max(s, axis=1, keepdims=True))
+                    corr = jnp.exp(m_old - m_new)
+                    # a row with no key yet holds m = _NEG and p = 1 here;
+                    # its first real block's corr = 0 wipes that
+                    p = jnp.exp(s - m_new[:, :1])
+                    l_s[rows] = l_s[rows] * corr + jnp.sum(
+                        p, axis=1, keepdims=True)
+                    m_s[rows] = m_new
+                    if quant:
+                        p = p * scale_row(vsb, b, k)
+                    pv = jax.lax.dot_general(
+                        p.astype(mm), head_rows(vbuf, b, k),
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)  # [M, hd]
+                    acc_s[rows] = acc_s[rows] * lanes(corr, hd) + pv
+                    return c2
+
+                jax.lax.fori_loop(0, KV, head_body, 0)
+                return c
+
+            jax.lax.fori_loop(b0, nb, block_body, 0)
+
+            def scatter(k, c):
+                rows = (k, pl.ds(0, M))
+                o = acc_s[rows] / lanes(jnp.maximum(l_s[rows], 1e-30), hd)
+                for g in range(G):
+                    o32[pl.ds(k * G + g, TQ, stride=Hp), :] = (
+                        o[g * TQ:(g + 1) * TQ])
+                return c
+
+            jax.lax.fori_loop(0, KV, scatter, 0)
+            obuf[pl.ds(0, span)] = o32[pl.ds(0, span)].astype(obuf.dtype)
+            # tile out: a row shorter than its tile overruns into the NEXT
+            # rows' region, which their own (later, sequential) grid steps
+            # overwrite; the last row's overrun lands in the output padding
+            put = pltpu.make_async_copy(obuf.at[pl.ds(0, span)],
+                                        out_ref.at[at], qo_sem.at[1])
+            put.start()
+            put.wait()
+            return carry
+
+        jax.lax.fori_loop(0, _div(q_len + TQ - 1, TQ), tile_body, 0)
+
+    # the tile is chosen from the row: a decode / verify row keeps the small
+    # tile, a prompt chunk fills the MXU's rows and streams its prefix once
+    # per 128 tokens
+    narrow, *wide = tiles
+    if wide:
+        pl.when(q_len > narrow)(lambda: run_tiles(wide[0]))
+    pl.when((q_len > 0) & (q_len <= narrow))(lambda: run_tiles(narrow))
 
 
 def ragged_paged_attention(q, k_cache, v_cache, block_tables, rows3, *,
                            block_size: int, interpret: bool = False,
-                           window=None, sinks=None, tq: int = 8,
+                           window=None, sinks=None, tq: int = NARROW_TILE,
                            k_scales=None, v_scales=None,
                            scale_slot_base=None):
     """Ragged paged attention over a packed token batch. See module
     docstring for the contract.
 
+    ``tq`` is the query tile of a short row (``q_len <= tq``: decode,
+    speculative verify); longer rows take ``_WIDE_TILE`` tokens a tile.
+
     ``k_scales``/``v_scales`` [sc_slots, KV] f32 (int8 caches): pages are
-    int8 and dequantize IN the kernel — scales go VMEM-resident, one
-    [bs·KV] row per page, fetched once for the whole grid.
+    int8 and dequantize IN the kernel — scales go VMEM-resident, fetched
+    once for the whole grid.
     ``scale_slot_base`` (traced scalar, default 0): slot offset of the
     scale tables relative to the page cache — layer-stacked callers pass
     one layer's scale slice plus ``lidx·slots`` so the VMEM budget is
@@ -266,52 +427,75 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, rows3, *,
     Routes to :func:`ragged_attention_xla` only for a head dim off the
     lane multiple, scale tables past the VMEM budget, or the explicit
     ``DYN_RAGGED_ORACLE=1`` bench/test oracle switch."""
+    KV, hd = k_cache.shape[1:]
+    bs = block_size
+    quant = k_scales is not None
+    if (not ragged_pallas_supported(KV, hd)
+            or (quant and not ragged_int8_kernel_supported(
+                KV, k_scales.shape[0], bs))
+            or os.environ.get("DYN_RAGGED_ORACLE") == "1"):
+        return ragged_attention_xla(
+            q, k_cache, v_cache, block_tables, rows3, block_size=bs,
+            window=window, sinks=sinks, k_scales=k_scales,
+            v_scales=v_scales, scale_slot_base=scale_slot_base)
+    return _ragged_call(
+        q, k_cache, v_cache, block_tables, rows3,
+        jnp.asarray(0 if window is None else window, jnp.int32),
+        jnp.asarray(0 if scale_slot_base is None else scale_slot_base,
+                    jnp.int32),
+        sinks, k_scales, v_scales, bs=bs, tq=tq,
+        interpret=interpret or kernel_interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("bs", "tq", "interpret"))
+def _ragged_call(q, k_cache, v_cache, block_tables, rows3, window,
+                 scale_slot_base, sinks, k_scales, v_scales, *, bs: int,
+                 tq: int, interpret: bool):
+    """The kernel's launch. Jitted, so the step programs that share a
+    (T, R, W) — the mixed and the decode-only variant of one token bucket —
+    trace the kernel once between them."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     T, H, hd = q.shape
     slots, KV, _ = k_cache.shape
     G = H // KV
-    bs = block_size
-    quant = k_scales is not None
-    sc_slots = k_scales.shape[0] if quant else 0
-    if (not ragged_pallas_supported(KV, hd)
-            or (quant and not ragged_int8_kernel_supported(
-                KV, sc_slots, bs))
-            or os.environ.get("DYN_RAGGED_ORACLE") == "1"):
-        return ragged_attention_xla(
-            q, k_cache, v_cache, block_tables, rows3, block_size=bs,
-            window=window, sinks=sinks, k_scales=k_scales,
-            v_scales=v_scales, scale_slot_base=scale_slot_base)
-    interpret = interpret or kernel_interpret_mode()
     R, W = block_tables.shape
     has_sink = sinks is not None
-    win_arr = jnp.asarray([0 if window is None else window],
-                          jnp.int32).reshape(1)
-    sbase_arr = (jnp.asarray([0 if scale_slot_base is None
-                              else scale_slot_base], jnp.int32) // bs
-                 ).reshape(1)
-
+    quant = k_scales is not None
+    # MXU inputs in the stored dtype: bf16 pages meet a bf16 q as they are,
+    # int8 pages enter as q's dtype (exact), f32 pages (CPU tests) stay f32
+    mm = (jnp.promote_types(q.dtype, k_cache.dtype)
+          if jnp.issubdtype(k_cache.dtype, jnp.floating) else q.dtype)
+    # no row of a batch of <= tq tokens is longer than the small tile
+    tiles = (tq, _WIDE_TILE) if T > tq else (tq,)
+    TQ = tiles[-1]
     # heads pad to the sublane packing of q's dtype (8 rows of 32 bits) so
-    # the in-kernel [TQ, Hp, hd] <-> [TQ·Hp, hd] reshapes are layout-
-    # trivial; padded heads match no KV group and come out zero
+    # a tile's rows start on a packed row; a KV head's group pads until the
+    # small tile's score rows do
     sub = 8 * max(1, 4 // q.dtype.itemsize)
     Hp = -(-H // sub) * sub
-    sink_in = jnp.pad(
-        jnp.zeros((H,), q.dtype) if not has_sink else sinks.astype(q.dtype),
-        (0, Hp - H)).reshape(1, Hp, 1)
-    # fold the softmax scale; pad by one tile so fixed-size tile DMAs never
-    # overrun. Tokens stay on the leading dim of a 3-D operand: q_start is
-    # data, and Mosaic takes a dynamic DMA offset on a tiled dim only when
-    # it can prove it tile-aligned.
-    qs = q * jnp.asarray(1.0 / np.sqrt(hd), q.dtype)
-    qs = jnp.pad(qs, ((0, tq), (0, Hp - H), (0, 0)))
+    Gp = G
+    while Gp * tq % (8 * max(1, 4 // jnp.dtype(mm).itemsize)):
+        Gp += 1
+    PB = max(1, min(_KEY_BLOCK // bs, W))  # pages of one streamed block
 
-    D = min(W, 8)  # page-pipeline depth (VMEM: 2·D·bs·KV·hd·dtype bytes)
-    kernel = functools.partial(_ragged_kernel, bs=bs, tq=tq, KV=KV, G=G,
-                               has_sink=has_sink, quant=quant)
+    # fold the softmax scale; pad by one tile so fixed-size tile DMAs never
+    # overrun. Rows are (token, head): a tile starts on a multiple of Hp,
+    # which is all Mosaic needs to take a data-dependent DMA offset.
+    qs = q * jnp.asarray(1.0 / np.sqrt(hd), q.dtype)
+    qs = jnp.pad(qs, ((0, TQ), (0, Hp - H), (0, 0))).reshape(-1, hd)
+    sink_in = jnp.zeros((KV, Gp), jnp.float32)
+    if has_sink:
+        sink_in = jnp.pad(sinks.astype(jnp.float32).reshape(KV, G),
+                          ((0, 0), (0, Gp - G)))
+    sink_in = jnp.broadcast_to(sink_in[..., None], (KV, Gp, _LANE))
+
+    kernel = functools.partial(
+        _ragged_kernel, bs=bs, tiles=tiles, KV=KV, G=G, Gp=Gp, Hp=Hp, PB=PB,
+        has_sink=has_sink, quant=quant)
     in_specs = [
-        pl.BlockSpec((1, Hp, 1), lambda r, *_: (0, 0, 0)),
+        pl.BlockSpec((KV, Gp, _LANE), lambda r, *_: (0, 0, 0)),
         pl.BlockSpec(memory_space=pltpu.HBM),  # q
         pl.BlockSpec(memory_space=pltpu.HBM),  # k pages
         pl.BlockSpec(memory_space=pltpu.HBM),  # v pages
@@ -321,45 +505,58 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, rows3, *,
     # without a relayout copy, and a page is one leading-dim index
     operands = [sink_in, qs, k_cache.reshape(slots // bs, bs * KV, hd),
                 v_cache.reshape(slots // bs, bs * KV, hd)]
+    scratch = [
+        pltpu.VMEM((TQ * Hp, hd), q.dtype),           # qbuf: the query tile
+        pltpu.VMEM((KV, Gp * TQ, hd), mm),            # qg: it, by KV head
+        pltpu.VMEM((KV, Gp * TQ, _LANE), jnp.float32),  # m (lane-replicated)
+        pltpu.VMEM((KV, Gp * TQ, _LANE), jnp.float32),  # l
+        pltpu.VMEM((KV, Gp * TQ, hd), jnp.float32),   # acc
+        pltpu.VMEM((TQ * Hp, hd), jnp.float32),       # o32: the output tile
+        pltpu.VMEM((TQ * Hp, hd), q.dtype),           # obuf: it, as stored
+        pltpu.VMEM((2, PB * bs * KV, hd), k_cache.dtype),  # kbuf: 2 blocks
+        pltpu.VMEM((2, PB * bs * KV, hd), v_cache.dtype),  # vbuf
+        pltpu.SemaphoreType.DMA((2,)),                # q-in / out tiles
+        pltpu.SemaphoreType.DMA((2, 2)),              # block pipeline
+    ]
     if quant:
         # constant block index → Pallas fetches the scale tables once and
-        # keeps them resident across the whole (R,) grid; page p's row is
-        # the [bs, KV] scales of its slots flattened in column order
-        sc_pages, lanes = _scale_table_shape(KV, sc_slots, bs)
+        # keeps them resident across the whole (R,) grid. A table row holds
+        # 128 // bs consecutive pages' scales per KV head along its lanes,
+        # a page's in the order its keys are scored (piece, slot)
+        sc_slots = k_scales.shape[0]
+        rows, KVp, _ = shape = _scale_table_shape(KV, sc_slots, bs)
+        pack = 4 // k_cache.dtype.itemsize
+        pieces = pack // math.gcd(KV, pack)
 
-        def page_rows(s):
-            s = s.astype(jnp.float32).reshape(sc_slots // bs, bs * KV)
-            return jnp.pad(s, ((0, sc_pages - sc_slots // bs),
-                               (0, lanes - bs * KV)))
+        def table(s):
+            s = s.astype(jnp.float32).reshape(sc_slots // bs, bs, KV)
+            s = jnp.pad(s, ((0, rows * (_LANE // bs) - sc_slots // bs),
+                            (0, 0), (0, KVp - KV)))
+            s = s.reshape(rows, _LANE // bs, bs // pieces, pieces, KVp)
+            return s.transpose(0, 4, 1, 3, 2).reshape(shape)
 
-        in_specs += [pl.BlockSpec((sc_pages, lanes), lambda r, *_: (0, 0)),
-                     pl.BlockSpec((sc_pages, lanes), lambda r, *_: (0, 0))]
-        operands += [page_rows(k_scales), page_rows(v_scales)]
+        in_specs += [pl.BlockSpec(shape, lambda r, *_: (0, 0, 0))] * 2
+        operands += [table(k_scales), table(v_scales)]
+        scratch += [pltpu.VMEM((2, -(-PB * bs // _LANE), KVp, _LANE),
+                               jnp.float32)] * 2      # ksb, vsb: 2 blocks'
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(R,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec(memory_space=pltpu.HBM),
-        scratch_shapes=[
-            pltpu.VMEM((tq, Hp, hd), q.dtype),            # qbuf
-            pltpu.VMEM((tq, Hp, hd), q.dtype),            # obuf
-            pltpu.VMEM((D, bs * KV, hd), k_cache.dtype),  # kbuf
-            pltpu.VMEM((D, bs * KV, hd), v_cache.dtype),  # vbuf
-            pltpu.SemaphoreType.DMA((2,)),                # q-in / out tiles
-            pltpu.SemaphoreType.DMA((D, 2)),              # page pipeline
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T + tq, Hp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qs.shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="ragged_paged_attention",
-    )(rows3.astype(jnp.int32), block_tables.astype(jnp.int32), win_arr,
-      sbase_arr, *operands)
-    return out[:T, :H]
+    )(rows3.astype(jnp.int32), block_tables.astype(jnp.int32),
+      window.reshape(1), _div(scale_slot_base, bs).reshape(1), *operands)
+    return out.reshape(T + TQ, Hp, hd)[:T, :H]
 
 
 def ragged_attention_xla(q, k_cache, v_cache, block_tables, rows3, *,

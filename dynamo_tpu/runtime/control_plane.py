@@ -51,6 +51,22 @@ STREAM_MAX_LEN = 65536  # per-stream ring buffer cap
 EPOCH_MARKER_SEQ = -1
 
 
+#: handler tasks spawned by a connection's read loop, held until they finish
+_kept_tasks: set = set()
+
+
+def _spawn_kept(coro) -> asyncio.Task:
+    """``create_task`` that keeps the task alive. The event loop holds only a
+    weak reference to a task, so one whose caller drops the result can be
+    garbage-collected while it waits: a handler that never answers (a
+    dispatch ack lost, its request waiting out the 10 s ``request_timeout``).
+    The task is released when it is done."""
+    task = asyncio.get_running_loop().create_task(coro)
+    _kept_tasks.add(task)
+    task.add_done_callback(_kept_tasks.discard)
+    return task
+
+
 class NoRespondersError(Exception):
     """No service instance is listening on the requested subject."""
 
@@ -947,7 +963,7 @@ class _ServerConn:
                     break
                 t = msg.get("t")
                 if t == "req":
-                    asyncio.get_running_loop().create_task(self._handle_req(msg))
+                    _spawn_kept(self._handle_req(msg))
                 elif t == "svc_res":
                     fut = self._pending_svc.pop(msg["rid"], None)
                     if fut and not fut.done():
@@ -1261,7 +1277,7 @@ class RemoteControlPlane(ControlPlane):
                             self._sub_meta[sid] = ("stream", meta[1], msg["seq"])
                         q.put_nowait((msg["seq"], msg["payload"]))
                 elif t == "svc_req":
-                    asyncio.get_running_loop().create_task(self._handle_svc(msg))
+                    _spawn_kept(self._handle_svc(msg))
         except (asyncio.IncompleteReadError, ConnectionError, asyncio.CancelledError):
             pass
         finally:

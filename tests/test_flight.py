@@ -457,6 +457,45 @@ async def test_engine_flight_records_and_compile_visibility(tiny_engine_cfg):
         await eng.close()
 
 
+async def test_flight_wide_tile_rows_counts_rows_above_the_small_tile(
+        tiny_engine_cfg):
+    """``wide_tile_rows`` of a step's record is the number of its rows with
+    more than 8 query tokens (the ragged kernel's wide query tile), counted
+    where the step's rows3 is built; decode steps carry none."""
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.protocols import (PreprocessedRequest, SamplingOptions,
+                                      StopConditions)
+
+    cfg, base = tiny_engine_cfg
+    eng = AsyncJaxEngine(cfg, EngineArgs(**base))
+    seen = []
+    count = eng._count_wide_rows
+
+    def spy(rows3):
+        seen.append(int((rows3[..., 1] > 8).sum()))
+        count(rows3)
+
+    eng._count_wide_rows = spy
+    try:
+        for n_prompt in (29, 8, 9, 100):  # 100 = chunks of 64 + 36 tokens
+            req = PreprocessedRequest(
+                model="m", token_ids=list(range(1, n_prompt + 1)),
+                stop_conditions=StopConditions(max_tokens=3, ignore_eos=True),
+                sampling_options=SamplingOptions(temperature=0.0))
+            async for _ in eng.generate(req):
+                pass
+        snap = eng.flight.snapshot()
+        per_step = [r.get("wide_tile_rows", 0) for r in snap
+                    if r["kind"] == "ragged"]
+        assert per_step == seen and sum(seen) == 4, (per_step, seen)
+        assert eng.wide_tile_rows_total == 4
+        assert all(r.get("wide_tile_rows", 0) <= r["prefill_chunks"]
+                   for r in snap)
+    finally:
+        await eng.close()
+
+
 async def test_engine_flight_disabled_is_pure_observation(tiny_engine_cfg):
     """DYN_FLIGHT=0 arm: identical token stream, zero records (the bench
     A/B contract in miniature)."""

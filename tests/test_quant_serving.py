@@ -108,6 +108,40 @@ def test_ragged_int8_scale_slot_base_rebases_layer_slice():
     np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
+#: rows of every q_len class of the kernel's two query tiles (8 and 128
+#: tokens), packed in one batch
+TILE_ROWS = [(1, 20), (8, 30), (9, 9), (40, 75), (1, 300), (128, 200),
+             (129, 140), (200, 260)]
+
+
+@pytest.mark.parametrize("H,KV,window,sinks", [
+    (8, 2, None, False), (8, 8, None, False), (14, 2, 50, True),
+    (8, 1, 7, False), (8, 4, None, True)],
+    ids=["g4_kv2", "g1_kv8", "g7_window_sinks", "g8_mqa_window", "g2_kv4"])
+def test_ragged_int8_kernel_tiles_with_layer_base(H, KV, window, sinks):
+    """int8 pages through both query tiles, as the layer scan calls the
+    kernel: layer 1's scale slice, block tables and ``scale_slot_base``
+    shifted past a junk layer 0 that must never be read. KV = 1, 2 (a word
+    of int8 rows holds several slots of one head: keys scored in piece
+    order), 4 and 8."""
+    need = sum(-(-kl // 8) for _, kl in TILE_ROWS) + 2
+    q, kq, vq, ksc, vsc, bt, rows3, t = make_int8_case(
+        jax.random.key(4), TILE_ROWS, H=H, KV=KV, num_blocks=need,
+        W=-(-300 // 8), pad_rows=3, pad_tokens=5)
+    sk = (jax.random.normal(jax.random.key(5), (H,), jnp.float32)
+          if sinks else None)
+    kw = dict(block_size=8, window=window, sinks=sk, k_scales=ksc,
+              v_scales=vsc)
+    want = ragged_attention_xla(q, kq, vq, bt, rows3, **kw)
+    slots = kq.shape[0]
+    junk = jnp.full_like(kq, 7)
+    got = ragged_paged_attention(
+        q, jnp.concatenate([junk, kq]), jnp.concatenate([junk, vq]),
+        bt + slots // 8, rows3, interpret=True, scale_slot_base=slots, **kw)
+    np.testing.assert_allclose(np.asarray(got)[:t], np.asarray(want)[:t],
+                               atol=2e-5, rtol=2e-5)
+
+
 def test_ragged_int8_scale_budget_degrades_to_oracle(monkeypatch):
     """Scale tables past the VMEM budget degrade to the XLA oracle —
     bit-equal to calling the oracle directly (it IS the oracle), and the

@@ -60,25 +60,132 @@ def make_ragged_case(key, rows, H=8, KV=4, hd=128, bs=8, num_blocks=64, W=6,
     return q, kc, vc, jnp.asarray(bt), jnp.asarray(rows3), t
 
 
-@pytest.mark.parametrize("window,sinks", [(None, False), (7, False),
-                                          (None, True)])
-def test_ragged_kernel_matches_xla(window, sinks):
-    """Interpret-mode Pallas ragged kernel == XLA oracle for a mixed batch
-    of decode rows and prefill chunks, with window/sink parity."""
-    key = jax.random.key(0)
-    rows = [(1, 20), (6, 24), (1, 9), (11, 11)]
-    # several trailing padding rows: regression for the oracle's
-    # searchsorted row mapping (zero-filled padding rows must not
-    # capture real tokens)
-    q, kc, vc, bt, rows3, t = make_ragged_case(key, rows, pad_rows=4)
-    sk = (jax.random.normal(jax.random.key(5), (8,), jnp.float32)
+def _oracle_by_pieces(q, kc, vc, bt, rows3, piece=256, **kw):
+    """The XLA oracle gathers [T, W·bs] keys per token: a long row is asked
+    of it in ``piece``-token sub-rows (a row's tokens END at its kv_len, so a
+    prefix of the row is a row of its own)."""
+    out = np.zeros(q.shape, np.float32)
+    for (t0, ql, kl), tab in zip(np.asarray(rows3), np.asarray(bt)):
+        for off in range(0, ql, piece):
+            n = min(piece, ql - off)
+            r3 = jnp.asarray([[0, n, kl - ql + off + n]], jnp.int32)
+            out[t0 + off:t0 + off + n] = ragged_attention_xla(
+                q[t0 + off:t0 + off + n], kc, vc, jnp.asarray(tab[None]), r3,
+                **kw)
+    return out
+
+
+#: every q_len class of the kernel's two query tiles (8 and 128 tokens): the
+#: small tile's edge, one wide tile that overruns its row, a last tile moved
+#: back onto the tile before it, exact tiles, and decode rows in between
+TILE_ROWS = [(1, 20), (8, 30), (9, 9), (40, 75), (127, 127), (1, 300),
+             (128, 200), (129, 140), (200, 260)]
+STAGGERED = [(1, 20), (6, 24), (1, 9), (11, 11)]
+KERNEL_CASES = {
+    # name: (H, KV, rows, window, sinks)
+    "staggered": (8, 4, STAGGERED, None, False),
+    "staggered_window": (8, 4, STAGGERED, 7, False),
+    "staggered_sinks": (8, 4, STAGGERED, None, True),
+    "g1_mha": (4, 4, TILE_ROWS, None, False),
+    "g4": (8, 2, TILE_ROWS, None, False),
+    "g7_qwen": (14, 2, TILE_ROWS, None, False),
+    "g8_mqa": (8, 1, TILE_ROWS, None, False),
+    "window_below_a_tile": (8, 2, TILE_ROWS, 7, False),
+    "window_across_blocks": (8, 2, [(200, 900), (1, 700), (9, 600)], 300,
+                             False),
+    "sinks": (8, 2, TILE_ROWS, None, True),
+    "sinks_window_g7": (14, 2, TILE_ROWS, 50, True),
+    "chunk_1024_on_a_prefix": (2, 1, [(1024, 1100), (1, 40), (3, 3)], None,
+                               False),
+    "moved_back_tile_overlaps": (4, 2, [(130, 130), (255, 400)], None,
+                                 False),
+}
+
+
+@pytest.mark.parametrize("name", list(KERNEL_CASES))
+def test_ragged_kernel_matches_xla(name):
+    """Interpret-mode Pallas ragged kernel == XLA oracle, both query tiles
+    and every head grouping: a packed batch mixing decode rows, short
+    chunks (one wide tile overrunning into the next row), exact and
+    moved-back tiles, with window/sink parity. Several trailing q_len = 0
+    padding rows and padding tokens: regression for the oracle's
+    searchsorted row mapping (zero-filled padding rows must not capture
+    real tokens)."""
+    H, KV, rows, window, sinks = KERNEL_CASES[name]
+    kv_max = max(kl for _, kl in rows)
+    W = -(-kv_max // 8)
+    need = sum(-(-kl // 8) for _, kl in rows) + 2
+    q, kc, vc, bt, rows3, t = make_ragged_case(
+        jax.random.key(3), rows, H=H, KV=KV, num_blocks=need, W=W,
+        pad_rows=4, pad_tokens=5)
+    sk = (jax.random.normal(jax.random.key(5), (H,), jnp.float32)
           if sinks else None)
-    want = ragged_attention_xla(q, kc, vc, bt, rows3, block_size=8,
-                                window=window, sinks=sk)
-    got = ragged_paged_attention(q, kc, vc, bt, rows3, block_size=8,
-                                 interpret=True, window=window, sinks=sk)
-    np.testing.assert_allclose(np.asarray(got)[:t], np.asarray(want)[:t],
+    kw = dict(block_size=8, window=window, sinks=sk)
+    want = _oracle_by_pieces(q, kc, vc, bt, rows3, **kw)
+    got = ragged_paged_attention(q, kc, vc, bt, rows3, interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(got)[:t], want[:t],
                                atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("KV", [1, 2, 8])
+def test_ragged_kernel_bf16_pages_read_as_words(KV):
+    """bf16 pages and queries: a head's rows come out of 32-bit words (two
+    bf16 rows a word; with one KV head, both rows of a word are its own),
+    the matmuls take bf16 and accumulate in f32. Against the f32 oracle on
+    the same bf16 values, at the chip check's tolerance."""
+    rows = [(1, 20), (8, 30), (9, 9), (40, 75), (129, 140), (1, 300)]
+    q, kc, vc, bt, rows3, t = make_ragged_case(
+        jax.random.key(6), rows, H=8, KV=KV, num_blocks=80, W=38, pad_rows=2)
+    q, kc, vc = (x.astype(jnp.bfloat16) for x in (q, kc, vc))
+    want = ragged_attention_xla(q, kc, vc, bt, rows3, block_size=8)
+    got = ragged_paged_attention(q, kc, vc, bt, rows3, block_size=8,
+                                 interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(
+        np.asarray(got[:t], np.float32), np.asarray(want[:t], np.float32),
+        atol=2e-2, rtol=2e-2)
+
+
+def _count_equations(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_equations(sub)
+    return n
+
+
+def test_ragged_step_program_trace_size_is_bounded():
+    """Trace + lowering of every step program is paid on every start, warm
+    compile cache or not: sixteen programs in the warm-up. The kernel's
+    first wide-tile form traced 2.4x the equations of its final form and
+    cost the fleet's start 33 s (PERF.md section 6, PR 26 / PR 28). One
+    mixed step program at Mistral-7B widths, int8 weights (the layer scan
+    makes the count independent of depth): 739 equations with this kernel,
+    542 with the 8-token-tile kernel before it. A count, not a time."""
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.models import mistral_7b
+
+    cfg = dataclasses.replace(mistral_7b(), num_layers=2)
+    args = EngineArgs(max_num_seqs=64, max_num_batched_tokens=1024,
+                      max_model_len=8192)
+    T, bs = 1024, args.block_size
+    R, W = args.ragged_rows(T), args.max_blocks_per_seq
+    C, _ = M.ragged_grid_shape(T)
+    spec = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: M.init_params(
+        cfg, jax.random.key(0), quantization="int8"))
+    cache = spec((cfg.num_layers, 256 * bs, cfg.num_kv_heads, cfg.head_dim),
+                 jnp.bfloat16)
+    step = M.make_ragged_step_fn(cfg, bs, None, use_pallas=True)
+    jaxpr = jax.make_jaxpr(step)(
+        params, spec((5, T), jnp.int32), spec((R, 3), jnp.int32),
+        spec((C,), jnp.int32), spec((R, W), jnp.int32), cache, cache)
+    assert "ragged_paged_attention" in str(jaxpr)
+    assert _count_equations(jaxpr.jaxpr) <= 800
 
 
 def test_ragged_decode_rows_match_decode_kernel_xla():

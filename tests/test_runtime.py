@@ -162,6 +162,39 @@ async def test_shutdown_with_silent_sender(local_rt):
         writer.close()
 
 
+async def test_spawned_handler_survives_gc_until_done():
+    """The read loops drop the task they spawn per request. The event loop
+    holds a task weakly, so one that waits on something nothing else
+    references (an ack future) is a collectable cycle: it vanished mid-wait
+    and its request sat out the 10 s timeout. ``_spawn_kept`` holds it until
+    it is done, and not after."""
+    import gc
+    import weakref
+
+    from dynamo_tpu.runtime import control_plane as cp
+
+    waits, done = [], []
+
+    async def handler():
+        fut = asyncio.get_running_loop().create_future()
+        waits.append(weakref.ref(fut))  # only the task itself holds fut
+        await fut
+        done.append(True)
+
+    task = weakref.ref(cp._spawn_kept(handler()))
+    await asyncio.sleep(0)
+    gc.collect()
+    assert task() is not None and task() in cp._kept_tasks
+    assert waits[0]() is not None, "the waiting handler was collected"
+    waits[0]().set_result(None)
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    assert done == [True]
+    assert all(t is not task() for t in cp._kept_tasks)
+    gc.collect()
+    assert task() is None, "a finished handler is still held"
+
+
 async def test_instance_discovery_follows_lease(cluster):
     worker_rt, client_rt = cluster
     ep_w = worker_rt.namespace("ns").component("c").endpoint("d")
