@@ -436,7 +436,7 @@ def kernel_checks(arch: str, quantization):
     # once: they may differ by a rounding step of an O(1) output
     tol = 2e-2 if dt == jnp.bfloat16 else 1e-4
     emit(phase="kernel_vs_xla", H=H, KV=KV, hd=hd, tokens=t,
-         kernel_on_path=ragged_pallas_supported(KV, hd),
+         kernel_on_path=ragged_pallas_supported(KV, hd, hd),
          max_abs_diff=diff, tolerance=tol)
     if not np.isfinite(diff) or diff > tol:
         raise SystemExit(f"kernel vs oracle: max abs diff {diff} > {tol}")

@@ -1,9 +1,14 @@
 """Paged KV cache: device arrays + host-side block pool with prefix cache.
 
-Device side: two arrays [L, num_slots, KV, hd] (num_slots = num_blocks *
-block_size), flat slot addressing; block 0 is the reserved NULL block —
-padding slot-maps and block-tables point at it and its contents are garbage
-by design (attention masks it out).
+Device side: per cache GROUP (``ModelConfig.kv_cache_spec``: the layers
+that share a page shape) a K array [L_g, num_slots, KV_g, kd] and a V array
+[L_g, num_slots, KV_g, vd] (num_slots = num_blocks * block_size), flat slot
+addressing. Every group has the same slots and ONE block table indexes them
+all: page i of a sequence exists in each group. A model with one group —
+all but those with layer kinds — holds the two arrays themselves, one with
+more holds a tuple of arrays a stream (``is_multi_group``). Block 0 is the
+reserved NULL block — padding slot-maps and block-tables point at it and
+its contents are garbage by design (attention masks it out).
 
 Host side: ``BlockPool`` mirrors the reference's block lifecycle (ref:
 lib/llm/src/block_manager/pool/managed.rs — active refcounted registry +
@@ -48,6 +53,13 @@ NULL_BLOCK = 0
 
 def is_quant_cache(cache) -> bool:
     return isinstance(cache, dict) and "q" in cache and "s" in cache
+
+
+def is_multi_group(cache) -> bool:
+    """A stream of more than one cache group (a tuple of arrays). The
+    block movers — KVBM tiers, disagg bundles, swap, the KV audit — know
+    one group; the engine refuses them at build for such a cache."""
+    return isinstance(cache, tuple)
 
 
 def cache_shape(cache) -> tuple:
@@ -408,7 +420,9 @@ class BlockPool:
 
 def allocate_device_cache(cfg, num_blocks: int, block_size: int, mesh=None,
                           dtype=None):
-    """Allocate the [L, num_slots, KV, hd] k/v cache arrays (zeros).
+    """Allocate the k/v cache arrays (zeros): per group of
+    ``cfg.kv_cache_spec`` a [L_g, num_slots, KV_g, width] array a stream;
+    one group returns the two arrays, more return two tuples of them.
 
     ``dtype="int8"`` returns quantized caches ({"q": int8, "s": f32 scales}
     pytrees — see module int8 notes); any other dtype (or None = model
@@ -425,9 +439,12 @@ def allocate_device_cache(cfg, num_blocks: int, block_size: int, mesh=None,
     quant = dtype == "int8" or (dtype is not None
                                 and jnp.dtype(dtype) == jnp.int8)
     dtype = jnp.dtype(cfg.dtype) if (dtype is None or quant) else dtype
-    (kh, kd), (vh, vd) = cfg.kv_cache_spec
-    k_shape = (cfg.num_layers, num_blocks * block_size, kh, kd)
-    v_shape = (cfg.num_layers, num_blocks * block_size, vh, vd)
+    groups = cfg.kv_cache_spec
+    if len(groups) > 1 and (mesh is not None or quant):
+        raise NotImplementedError(
+            f"a cache of {len(groups)} groups is neither sharded over a "
+            "mesh nor quantized yet (one chip, model-dtype pages)")
+    slots = num_blocks * block_size
 
     def alloc(shape, dt, sh):
         if sh is None:
@@ -444,7 +461,10 @@ def allocate_device_cache(cfg, num_blocks: int, block_size: int, mesh=None,
         return {"q": alloc(shape, jnp.int8, sh["q"] if sh else None),
                 "s": alloc(shape[:-1], jnp.float32, sh["s"] if sh else None)}
 
-    return one(k_shape), one(v_shape)
+    ks = tuple(one((len(g.layers), slots, *g.k_shape)) for g in groups)
+    vs = tuple(one((len(g.layers), slots, g.kv_heads, g.v_dim))
+               for g in groups)
+    return (ks[0], vs[0]) if len(groups) == 1 else (ks, vs)
 
 
 def tree_nbytes(params) -> int:
@@ -482,15 +502,23 @@ def hbm_sized_num_blocks(cfg, block_size: int, fraction: float,
                 "memory_stats(): cannot size the KV pool (pass num_blocks)")
         return default
     free = stats["bytes_limit"] - stats["bytes_in_use"]
-    (kh, kd), (vh, vd) = cfg.kv_cache_spec
-    # MLA's single-latent-head cache is not TP-shardable (replicated)
-    k_heads = kh // max(1, tp_size) if kh % max(1, tp_size) == 0 else kh
-    v_heads = vh // max(1, tp_size) if vh % max(1, tp_size) == 0 else vh
-    if kv_cache_dtype == "int8":
-        per_slot = k_heads * (kd + 4) + v_heads * (vd + 4)
-    else:
-        per_slot = (k_heads * kd + v_heads * vd) * (
-            2 if cfg.dtype == "bfloat16" else 4)
-    bytes_per_block = cfg.num_layers * block_size * per_slot
+    bytes_per_block = block_size * sum(
+        slot_bytes(cfg, g, tp_size, kv_cache_dtype)
+        for g in cfg.kv_cache_spec)
     n = int(free * fraction / max(1, bytes_per_block))
     return max(16, n)
+
+
+def slot_bytes(cfg, group, tp_size: int = 1,
+               kv_cache_dtype: Optional[str] = None) -> int:
+    """Bytes one slot (one token) takes in one cache group, on one device:
+    K and V rows of every layer of the group."""
+    tp = max(1, tp_size)
+    # MLA's single-latent-head cache is not TP-shardable (replicated)
+    heads = group.kv_heads // tp if group.kv_heads % tp == 0 \
+        else group.kv_heads
+    if kv_cache_dtype == "int8":
+        return len(group.layers) * heads * (group.k_dim + 4
+                                            + group.v_dim + 4)
+    return group.bytes_per_slot(2 if cfg.dtype == "bfloat16" else 4) \
+        // group.kv_heads * heads

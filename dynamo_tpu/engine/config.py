@@ -14,7 +14,7 @@ import json
 import math
 import os
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional
 
 
 #: static cap on prefill chunks co-scheduled into one ragged step (the
@@ -23,10 +23,51 @@ from typing import Optional
 RAGGED_MAX_CHUNKS = 4
 
 
+class LayerKind(NamedTuple):
+    """One kind of attention layer of a model whose layers differ (MiMo-V2:
+    window layers and full layers with their own KV-head counts)."""
+
+    num_kv_heads: int
+    rope_theta: float
+    window: int  # 0 = full attention
+    sink: bool   # learned per-head sink logit joins the softmax
+
+
+class CacheGroup(NamedTuple):
+    """The layers that share one paged-cache shape. Every group has the
+    same slots and is indexed by the same block table (page i exists in
+    each); a model with one group keeps one ``[L, slots, KV, hd]`` array a
+    stream, a model with more keeps a tuple of them."""
+
+    layers: tuple   # model layer indices, in order
+    kv_heads: int
+    k_dim: int      # stored width of a K head (ModelConfig.k_cache_dim)
+    v_dim: int
+    window: int     # 0 = full attention
+    #: 128-lane rows a stored K head takes: the K array is
+    #: [L, slots, kv_heads·k_rows, k_dim // k_rows]
+    k_rows: int = 1
+
+    @property
+    def k_shape(self) -> tuple:
+        """(rows, width) of one slot of the K array."""
+        return self.kv_heads * self.k_rows, self.k_dim // self.k_rows
+
+    def bytes_per_slot(self, itemsize: int) -> int:
+        return (len(self.layers) * self.kv_heads
+                * (self.k_dim + self.v_dim) * itemsize)
+
+
 @dataclass
 class ModelConfig:
-    """Llama-family decoder architecture (covers Llama 2/3, Mistral, Qwen2,
-    TinyLlama; MoE via n_routed_experts for Mixtral/DeepSeek-style models)."""
+    """Pre-norm decoder-only transformer: RoPE + RMSNorm + GQA paged
+    attention with a SwiGLU or token-choice expert MLP. Covers the Llama
+    family (Llama 2/3, Mistral, Qwen2/3, Phi-3), Gemma 1/2, gpt-oss
+    (per-layer windows, sinks), DeepSeek V2/V3 (MLA, shared experts,
+    sigmoid routing) and MiMo-V2 (layer KINDS with their own KV-head
+    count, rope base, window and sink; K/Q heads wider than V heads;
+    partial rotary; an expert layer that holds a share of the experts it
+    routes over)."""
 
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -118,11 +159,50 @@ class ModelConfig:
     q_lora_rank: Optional[int] = None  # None = full q projection (V2-Lite)
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
-    v_head_dim: int = 128
+    #: width of a V head: MLA's published one (default 128); elsewhere None
+    #: = ``head_dim``, set where V heads are narrower than K/Q heads
+    #: (MiMo-V2: 192 | 128). Read it as ``v_dim``.
+    v_head_dim: Optional[int] = None
+    # --- layer kinds (MiMo-V2) -------------------------------------------
+    #: kinds of attention layer and, per layer, which kind it is. Each kind
+    #: has its own parameter stack (Wk/Wv shapes differ) and its own cache
+    #: group; ``num_kv_heads`` / ``rope_theta`` / ``sliding_window`` /
+    #: ``attention_sinks`` above are then unused
+    layer_kinds: Optional[tuple] = None
+    layer_pattern: Optional[tuple] = None
+    #: leading dims of a head that RoPE turns (rotate-half pairing inside
+    #: them); None = all of head_dim
+    rotary_dim: Optional[int] = None
+    #: attention output = P·(value_scale·v)
+    value_scale: float = 1.0
+    #: (first, count): the experts THIS chip holds of the ``num_experts``
+    #: the router scores — one rank's share of an expert-parallel
+    #: deployment. The layer computes its own experts' part of the result,
+    #: dropless; what the absent experts would add is left out. None =
+    #: every expert is held.
+    experts_held: Optional[tuple] = None
 
     def __post_init__(self):
         if self.head_dim is None:
             self.head_dim = self.hidden_size // self.num_heads
+        if self.v_head_dim is None and self.kv_lora_rank > 0:
+            self.v_head_dim = 128
+        if self.layer_kinds is not None:
+            self.layer_kinds = tuple(LayerKind(*k) for k in self.layer_kinds)
+            self.layer_pattern = tuple(int(i) for i in self.layer_pattern)
+            if (len(self.layer_pattern) != self.num_layers
+                    or not all(0 <= i < len(self.layer_kinds)
+                               for i in self.layer_pattern)):
+                raise ValueError(
+                    f"layer_pattern {self.layer_pattern} does not name one "
+                    f"of {len(self.layer_kinds)} kinds for each of "
+                    f"{self.num_layers} layers")
+        if self.experts_held is not None:
+            first, count = self.experts_held = tuple(self.experts_held)
+            if not 0 <= first < first + count <= self.num_experts:
+                raise ValueError(
+                    f"experts_held {self.experts_held} outside the "
+                    f"{self.num_experts} routed experts")
         if self.layer_windows is not None:
             self.layer_windows = tuple(int(w or 0) for w in self.layer_windows)
             if len(self.layer_windows) != self.num_layers:
@@ -160,21 +240,62 @@ class ModelConfig:
         return self.first_k_dense_replace if self.is_moe else 0
 
     @property
-    def kv_cache_spec(self) -> tuple[tuple[int, int], tuple[int, int]]:
-        """((heads, dim) of k_cache, (heads, dim) of v_cache) per slot.
+    def v_dim(self) -> int:
+        return self.v_head_dim or self.head_dim
 
-        MHA/GQA: both caches hold [num_kv_heads, head_dim]. MLA stores the
-        compressed latent instead — k_cache [1, kv_lora_rank] (normalized
-        c_kv) and v_cache [1, rope_pad] (the shared post-RoPE k_rot, zero-
-        padded to a 128-lane multiple so the Pallas decode kernel can DMA
-        cache pages tile-aligned) — the memory win that makes DeepSeek-class
-        models servable (ref behavior delegated to engines; e.g. vLLM's MLA
-        cache does the same).
+    @property
+    def num_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.num_experts
+
+    @property
+    def k_cache_dim(self) -> int:
+        """Stored width of a K head. A head wider than a lane row that is
+        not a lane multiple (MiMo-V2's 192) is stored zero-padded to the
+        next one, as that many whole 128-lane rows (``k_lane_rows``): the
+        ragged kernel DMAs lane rows and strides their sublanes, q is
+        padded alike, and the zeros add nothing to a score."""
+        return 128 * self.k_lane_rows if self.k_lane_rows > 1 \
+            else self.head_dim
+
+    @property
+    def k_lane_rows(self) -> int:
+        hd = self.head_dim
+        return 1 if hd < 128 or hd % 128 == 0 else -(-hd // 128)
+
+    def layer_kind(self, layer: int) -> LayerKind:
+        """The kind of ``layer``; a model without kinds has one, made of
+        its global fields (``layer_windows`` still says each window)."""
+        if self.layer_kinds is not None:
+            return self.layer_kinds[self.layer_pattern[layer]]
+        window = (self.layer_windows[layer] if self.layer_windows is not None
+                  else self.sliding_window or 0)
+        return LayerKind(self.num_kv_heads, self.rope_theta, window,
+                         self.attention_sinks)
+
+    @property
+    def kv_cache_spec(self) -> tuple:
+        """The cache groups (:class:`CacheGroup`), one per layer kind.
+
+        MHA/GQA: K and V rows of ``[kv_heads, k_cache_dim | v_head_dim]``.
+        MLA is one group that stores the compressed latent instead — K
+        rows [1, kv_lora_rank] (normalized c_kv) and V rows [1, rope_pad]
+        (the shared post-RoPE k_rot, zero-padded to a 128-lane multiple so
+        the Pallas decode kernel can DMA cache pages tile-aligned) — the
+        memory win that makes DeepSeek-class models servable.
         """
+        every = tuple(range(self.num_layers))
         if self.is_mla:
-            return ((1, self.kv_lora_rank), (1, self.rope_cache_dim))
-        return ((self.num_kv_heads, self.head_dim),
-                (self.num_kv_heads, self.head_dim))
+            return (CacheGroup(every, 1, self.kv_lora_rank,
+                               self.rope_cache_dim, 0),)
+        if self.layer_kinds is None:
+            return (CacheGroup(every, self.num_kv_heads, self.k_cache_dim,
+                               self.v_dim, self.sliding_window or 0,
+                               self.k_lane_rows),)
+        return tuple(
+            CacheGroup(tuple(i for i in every if self.layer_pattern[i] == g),
+                       k.num_kv_heads, self.k_cache_dim, self.v_dim, k.window,
+                       self.k_lane_rows)
+            for g, k in enumerate(self.layer_kinds))
 
     @staticmethod
     def from_hf_config(d: dict) -> "ModelConfig":
@@ -221,6 +342,14 @@ class ModelConfig:
                     "stack, which the stacked-layer forward does not support")
         mla = is_deepseek and d.get("kv_lora_rank") is not None
         layer_windows = None
+        kinds = {}
+        if d.get("model_type") == "mimo_v2" or "mimov2" in arch:
+            kinds = _mimo_v2_fields(d)
+            d = {**d, "first_k_dense_replace": kinds.pop("k_dense")}
+            if "experts_held" in kinds:
+                # one rank's share: the key that counts the experts gives
+                # how many are held, the router keeps its published width
+                d["n_routed_experts"] = d["n_routed_experts_published"]
         if is_gemma2:
             # HF Gemma2: sliding attention on EVEN layer indices
             # (Gemma2DecoderLayer: is_sliding = not bool(layer_idx % 2))
@@ -255,7 +384,8 @@ class ModelConfig:
                                    if is_gemma2 else None),
             rope_theta=d.get("rope_theta", 10000.0),
             rope_scaling=d.get("rope_scaling"),
-            rms_norm_eps=d.get("rms_norm_eps", 1e-5),
+            rms_norm_eps=d.get("rms_norm_eps",
+                               d.get("layernorm_epsilon", 1e-5)),
             max_position_embeddings=d.get("max_position_embeddings", 8192),
             tie_word_embeddings=d.get("tie_word_embeddings", False),
             num_experts=(d.get("num_local_experts")       # mixtral
@@ -272,14 +402,16 @@ class ModelConfig:
             # configs have no such key); DeepSeek carries the flag explicitly
             norm_topk_prob=d.get("norm_topk_prob",
                                  "mixtral" in arch or is_gpt_oss),
-            routed_scaling_factor=d.get("routed_scaling_factor", 1.0),
+            routed_scaling_factor=d.get("routed_scaling_factor") or 1.0,
             n_group=d.get("n_group", 1) or 1,
             topk_group=d.get("topk_group", 1) or 1,
             kv_lora_rank=d.get("kv_lora_rank", 0) if mla else 0,
             q_lora_rank=d.get("q_lora_rank") if mla else None,
             qk_nope_head_dim=d.get("qk_nope_head_dim", 128),
             qk_rope_head_dim=d.get("qk_rope_head_dim", 64),
-            v_head_dim=d.get("v_head_dim", 128),
+            v_head_dim=(d.get("v_head_dim", 128) if mla
+                        else d.get("v_head_dim") if kinds else None),
+            **kinds,
             qkv_bias=("qwen2" in arch
                       or (is_gpt_oss and d.get("attention_bias", True))),
             qk_norm="qwen3" in arch,
@@ -293,7 +425,7 @@ class ModelConfig:
             # configs apply the window unconditionally; gpt-oss windows are
             # per-layer (layer_windows above)
             sliding_window=(d.get("sliding_window")
-                            if not is_gpt_oss
+                            if not is_gpt_oss and not kinds
                             and d.get("use_sliding_window",
                                       "qwen2" not in arch) else None),
         )
@@ -338,6 +470,51 @@ class ModelConfig:
             rope_theta=500000.0, max_position_embeddings=8192,
             tie_word_embeddings=True,
         )
+
+
+def _mimo_v2_fields(d: dict) -> dict:
+    """MiMo-V2's published keys → the ModelConfig fields they set:
+    ``hybrid_layer_pattern`` (0 full, 1 window) with the ``swa_*`` twins of
+    the attention sizes, ``add_*_sink_bias``, ``moe_layer_freq``,
+    ``partial_rotary_factor``, ``attention_value_scale``. ``experts_held``
+    is not a published key: a configuration that stands for one rank of an
+    expert-parallel deployment states it beside them."""
+    hd, vd = d["head_dim"], d.get("v_head_dim", d["head_dim"])
+    if (d.get("swa_head_dim", hd), d.get("swa_v_head_dim", vd),
+            d.get("swa_num_attention_heads", d["num_attention_heads"])) != (
+            hd, vd, d["num_attention_heads"]):
+        raise NotImplementedError(
+            "window layers whose head count or head widths differ from the "
+            "full layers' are not supported (only their KV-head count, rope "
+            "base, window and sink may)")
+    moe = [bool(x) for x in d["moe_layer_freq"]]
+    k_dense = moe.index(True) if True in moe else len(moe)
+    if not all(moe[k_dense:]):
+        raise NotImplementedError(
+            "dense layers after the first expert layer (moe_layer_freq) "
+            "are not supported: only a dense prefix is")
+    window = d.get("sliding_window", d.get("sliding_window_size"))
+    out = dict(
+        layer_kinds=(
+            (d["num_key_value_heads"], float(d["rope_theta"]), 0,
+             bool(d.get("add_full_attention_sink_bias", False))),
+            (d.get("swa_num_key_value_heads", d["num_key_value_heads"]),
+             float(d.get("swa_rope_theta", d["rope_theta"])), window,
+             bool(d.get("add_swa_attention_sink_bias", False)))),
+        layer_pattern=tuple(d["hybrid_layer_pattern"]),
+        # HF rounds the rotated width down to an even count of dims
+        rotary_dim=int(hd * float(d.get("partial_rotary_factor", 1.0)))
+        // 2 * 2,
+        value_scale=float(d.get("attention_value_scale") or 1.0),
+        k_dense=k_dense,
+    )
+    if d.get("experts_held") is not None:
+        out["experts_held"] = tuple(d["experts_held"])
+        if out["experts_held"][1] != d["n_routed_experts"]:
+            raise ValueError(
+                f"experts_held {d['experts_held']} does not hold the "
+                f"n_routed_experts = {d['n_routed_experts']} the file states")
+    return out
 
 
 @dataclass

@@ -31,7 +31,7 @@ import numpy as np
 
 from dynamo_tpu.engine.cache import (
     BlockPool, NULL_BLOCK, SwapStore, allocate_device_cache,
-    hbm_sized_num_blocks, tree_nbytes,
+    hbm_sized_num_blocks, slot_bytes, tree_nbytes,
 )
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.engine.scheduler import Scheduler, SeqState, StepPlan
@@ -69,6 +69,16 @@ class _SwapEntry:
         self.failed = False
         self.freed = False
         self.dropped = False
+
+
+def _layers_by_kind(cfg: ModelConfig) -> dict:
+    """Layer counts for the ``engine built:`` line: by attention kind
+    (full / window), and dense / experts."""
+    kinds = [cfg.layer_kind(i) for i in range(cfg.num_layers)]
+    n_moe = cfg.num_layers - cfg.num_dense_prefix_layers if cfg.is_moe else 0
+    return {"full": sum(not k.window for k in kinds),
+            "window": sum(bool(k.window) for k in kinds),
+            "dense": cfg.num_layers - n_moe, "experts": n_moe}
 
 
 def _has_penalties(s) -> bool:
@@ -177,6 +187,19 @@ class AsyncJaxEngine:
                     "speculative_tokens=%d is not supported under pipeline "
                     "parallelism (pp_size=%d); set speculative_tokens=0"
                     % (args.speculative_tokens, self._pp))
+        groups = cfg.kv_cache_spec
+        if len(groups) > 1:
+            # the block movers know one cache group (cache.is_multi_group)
+            unmet = [name for name, on in (
+                ("--kvbm-host-gb / KVBM tiers", args.kvbm_host_bytes > 0),
+                ("preempt-to-swap (pass --no-preempt-swap)",
+                 args.preempt_swap),
+                ("int8 KV pages", self._kv_quant),
+                ("a device mesh", mesh is not None)) if on]
+            if unmet:
+                raise ValueError(
+                    f"a cache of {len(groups)} groups (one per layer kind) "
+                    "does not support: " + "; ".join(unmet))
         nb = args.num_blocks or hbm_sized_num_blocks(
             cfg, args.block_size, args.kv_cache_memory_fraction, args.tp_size,
             kv_cache_dtype="int8" if self._kv_quant else None)
@@ -224,6 +247,17 @@ class AsyncJaxEngine:
             attention = ("pallas: ragged kernel (interpreted)"
                          if kernel_interpret_mode()
                          else "pallas: ragged kernel (Mosaic)")
+        #: held-experts layer counters (model.moe_stats_width), read with
+        #: the step's other outputs: dynamo_moe_assignments_total{to} and
+        #: dynamo_moe_expert_tokens_total{expert}
+        self._moe_held = cfg.is_moe and cfg.experts_held is not None
+        self.moe_assignments_total = (
+            {"all": 0, "held": 0} if self._moe_held else {})
+        self.moe_expert_tokens_total = np.zeros(
+            (cfg.num_experts_held if self._moe_held else 0,), np.int64)
+        self._moe_pending: collections.deque = collections.deque()
+        #: (pairs, experts touched) a cache group: the next record's
+        self._moe_step = np.zeros((len(groups), 2), np.int64)
         mem_after = dev.memory_stats()
         state = (self.params, self.k_cache, self.v_cache)
         #: what was built, as one line an operator (or chip_smoke.py) reads
@@ -234,6 +268,19 @@ class AsyncJaxEngine:
             "kv_blocks": nb,
             "kv_bytes": tree_nbytes((self.k_cache, self.v_cache)),
             "attention": attention,
+            "layers": _layers_by_kind(cfg),
+            "experts_held": list(cfg.experts_held or ()) or None,
+            "experts_routed": cfg.num_experts,
+            "experts_per_tok": cfg.num_experts_per_tok,
+            "expert_ffn": cfg.moe_ffn_size if cfg.is_moe else None,
+            "hidden_size": cfg.hidden_size,
+            "cache_groups": [
+                {"layers": len(g.layers), "kv_heads": g.kv_heads,
+                 "k_dim": g.k_dim, "v_dim": g.v_dim, "window": g.window,
+                 "page_bytes": args.block_size * slot_bytes(
+                     cfg, g, args.tp_size,
+                     "int8" if self._kv_quant else None)}
+                for g in groups],
             "bytes_in_use_before": mem_before and mem_before["bytes_in_use"],
             "bytes_in_use_after": mem_after and mem_after["bytes_in_use"],
             "bytes_limit": mem_after and mem_after["bytes_limit"],
@@ -363,6 +410,9 @@ class AsyncJaxEngine:
                 use_pallas=args.use_pallas_attention,
                 replicate_logits=self._multihost,
                 kv_quant=self._kv_quant, chunks=False)
+            if self._moe_held:
+                self.ragged_fn = self._keep_moe_stats(self.ragged_fn)
+                self.ragged_dec_fn = self._keep_moe_stats(self.ragged_dec_fn)
             if args.multi_step_decode > 1:
                 self.multi_fn = M.make_multi_decode_fn(
                     cfg, args.block_size, args.multi_step_decode, mesh,
@@ -1568,6 +1618,42 @@ class AsyncJaxEngine:
                 out[tier] = {"blocks": 0, "bytes": 0}
         return out
 
+    def _keep_moe_stats(self, fn):
+        """``fn`` minus its fourth output (the held-experts layer's
+        counters), which waits on the device until a later flight record
+        finds it ready: no step blocks on its own counters."""
+        def step(*operands):
+            logits, k_cache, v_cache, stats = fn(*operands)
+            self._moe_pending.append(stats)
+            return logits, k_cache, v_cache
+        return step
+
+    def _drain_moe_stats(self) -> None:
+        """Called once a flight record: takes the counters of the oldest
+        step still pending — the one the record is for; its tokens are on
+        the host already — and of any that piled up beyond the pipeline's
+        depth."""
+        first = True
+        while self._moe_pending and (first or len(self._moe_pending) > 2):
+            first = False
+            stats = np.asarray(self._moe_pending.popleft())  # [groups, ·]
+            self.moe_assignments_total["all"] += int(stats[:, 0].sum())
+            self.moe_assignments_total["held"] += int(stats[:, 1].sum())
+            self.moe_expert_tokens_total += stats[:, 3:].sum(0)
+            self._moe_step += stats[:, 1:3]
+
+    def _dead_window_pages(self) -> int:
+        """Pages of window cache groups wholly behind their sequence's
+        window, summed over groups: page p of a sequence of n tokens is
+        dead when its last slot is at or before n − window. (A page shared
+        through the prefix cache counts once per holder.)"""
+        bs, dead = self.args.block_size, 0
+        for g in self.cfg.kv_cache_spec:
+            if g.window:
+                dead += sum(max(0, (len(s.tokens) - g.window + 1) // bs)
+                            for s in self.scheduler.running)
+        return dead
+
     def _flight_record(self, kind: str, wall_ms: float, decode_rows: int,
                        prefill_chunks: int, chunk_tokens: int,
                        padded: int = 0, dispatch_ms: float = 0.0,
@@ -1587,6 +1673,10 @@ class AsyncJaxEngine:
             # the counter runs even with the flight recorder disabled
             self.ragged_fallback_total[fb] = (
                 self.ragged_fallback_total.get(fb, 0) + 1)
+        if self._moe_held:
+            self._drain_moe_stats()
+        moe_step = self._moe_step.tolist()
+        self._moe_step[:] = 0
         if not self.flight.enabled:
             return
         sched = self.scheduler
@@ -1618,6 +1708,10 @@ class AsyncJaxEngine:
             preempt_swap=delta["ps"], preempt_recompute=delta["pr"],
             swap_out_blocks=delta["so"], swap_in_blocks=delta["si"],
             wide_tile_rows=delta["wt"],
+            moe_pairs=sum(p for p, _ in moe_step),
+            moe_experts_touched=sum(t for _, t in moe_step),
+            moe_by_group=moe_step if self._moe_held else [],
+            dead_window_pages=self._dead_window_pages(),
             waiting=sched.num_waiting(), swapped=len(sched.swapped),
             running=len(sched.running),
             starved_decode=(sched.last_starved_decode
@@ -1766,6 +1860,7 @@ class AsyncJaxEngine:
             return report
 
         report = await asyncio.to_thread(run_ragged)
+        self._moe_pending.clear()  # warm-up routed nothing anyone sent
         report["seconds"] = round(time.perf_counter() - t_start, 2)
         logger.info("ragged warmup: %d token-bucket signatures in %.1fs",
                     len(report["ragged"]), report["seconds"])

@@ -16,15 +16,59 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import gc
 import logging
 import os
 import signal
+import time
 
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.llm.model_card import ModelDeploymentCard, register_llm
 from dynamo_tpu.router.publisher import KvEventPublisher, WorkerMetricsPublisher
 from dynamo_tpu.runtime import DistributedRuntime
 from dynamo_tpu.runtime.config import place_compile_cache, setup_logging
+
+
+#: gen-1 collections between two of the oldest generation, once the worker
+#: is built (Python's own figure is 10). What is allocated while serving —
+#: flight records, request state, the sampler's programs — grows the oldest
+#: generation again, and a collection of it every ~10 s of load walked all
+#: of it: ~120 ms with every stream stalled (PERF.md section 6, PR 30).
+#: Young collections, which reclaim what a request leaves behind, go on.
+FULL_COLLECTION_EVERY = 1000
+#: a collection that stops the worker this long is logged
+SLOW_COLLECTION_S = 0.02
+_collection_t0 = [0.0]
+
+
+def _note_collection(phase: str, info: dict) -> None:
+    if phase == "start":
+        _collection_t0[0] = time.perf_counter()
+        return
+    took = time.perf_counter() - _collection_t0[0]
+    if took >= SLOW_COLLECTION_S:
+        logging.getLogger("dynamo.engine.main").warning(
+            "garbage collection of generation %d stopped the worker for "
+            "%.0f ms (%d collected)", info["generation"], took * 1e3,
+            info["collected"])
+
+
+def settle_heap() -> int:
+    """Move everything alive now out of the garbage collector's reach,
+    once the worker is built and warmed up, and collect the oldest
+    generation rarely from here on. The step programs' jaxprs and
+    executables are some hundred thousand tracked objects that live as long
+    as the process, and a full collection walks them all: ~250 ms in which
+    no step is dispatched, a few times in the first minute of traffic (one
+    in two runs of a 48 s window, every stream stalled at once; PERF.md
+    section 6, PR 30). Returns how many objects were set aside."""
+    gc.collect()
+    gc.freeze()
+    young, middle, _ = gc.get_threshold()
+    gc.set_threshold(young, middle, FULL_COLLECTION_EVERY)
+    if _note_collection not in gc.callbacks:
+        gc.callbacks.append(_note_collection)
+    return gc.get_freeze_count()
 
 
 def build_engine(cli, cfg: ModelConfig, args: EngineArgs):
@@ -533,6 +577,21 @@ async def amain():
         "small tile (prompt chunks): how often its wide query tile "
         "engages").add_callback(
         lambda: {None: engine.wide_tile_rows_total})
+    # held-experts layer (one rank's share of an expert-parallel layer):
+    # how much of the routing lands here, and on which experts
+    runtime.metrics.counter(
+        "moe_assignments_total",
+        "(token, expert) assignments the routers made, to=\"all\", and "
+        "those whose expert this worker holds, to=\"held\" (summed over "
+        "expert layers)").add_callback(
+        lambda: {(("to", k),): v
+                 for k, v in engine.moe_assignments_total.items()})
+    runtime.metrics.counter(
+        "moe_expert_tokens_total",
+        "tokens routed to each held expert (summed over expert "
+        "layers)").add_callback(
+        lambda: {(("expert", str(e)),): int(v)
+                 for e, v in enumerate(engine.moe_expert_tokens_total)})
     runtime.metrics.gauge(
         "engine_warmup_skipped",
         "1 = requested AOT warmup could not run (multi-host step "
@@ -851,6 +910,8 @@ async def amain():
     if register:  # prefill fleet is internal, not a model server
         card = ModelDeploymentCard(
             display_name=cli.model,
+            # what the frontend admits is what the engine can hold
+            context_length=args.max_model_len,
             kv_cache_block_size=args.block_size,
             eos_token_ids=eos,
             tokenizer_ref=tokenizer_ref or "test",
@@ -874,6 +935,8 @@ async def amain():
             card.mm_placeholder_tokens = mm_encoder.tokens_per_image
         await register_llm(runtime, ep, card, lease_id=lease)
 
+    logging.getLogger("dynamo.engine.main").info(
+        "heap settled: %d objects set aside from collection", settle_heap())
     print("WORKER_READY", flush=True)
     profile_task = None
     if cli.profile_dir:

@@ -27,7 +27,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -116,10 +116,15 @@ def _const_leaf(value, shape, dtype) -> _LazyLeaf:
 
 
 def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
-                      dtype) -> dict:
-    """Random-init one stacked layer group (n layers, dense or MoE MLP)."""
-    D, hd = cfg.hidden_size, cfg.head_dim
-    H, KV = cfg.num_heads, cfg.num_kv_heads
+                      dtype, kind=None) -> dict:
+    """Random-init one stacked layer group (n layers, dense or MoE MLP).
+    ``kind``: the stack's :class:`LayerKind` where the model has kinds —
+    its KV-head count sizes Wk/Wv, and the sink logits and the router's
+    correction bias start small and random, not zero, so that a path which
+    forgets either computes something else."""
+    D, hd, vd = cfg.hidden_size, cfg.head_dim, cfg.v_dim
+    H = cfg.num_heads
+    KV = kind.num_kv_heads if kind else cfg.num_kv_heads
     F, E = cfg.intermediate_size, cfg.num_experts
     ks = jax.random.split(key, 16)
 
@@ -152,26 +157,29 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
     else:
         layers["wq"] = w(ks[0], (n, D, H * hd), D)
         layers["wk"] = w(ks[1], (n, D, KV * hd), D)
-        layers["wv"] = w(ks[2], (n, D, KV * hd), D)
-        layers["wo"] = w(ks[3], (n, H * hd, D), H * hd)
+        layers["wv"] = w(ks[2], (n, D, KV * vd), D)
+        layers["wo"] = w(ks[3], (n, H * vd, D), H * vd)
         if cfg.qkv_bias:
             layers["bq"] = zeros((n, H * hd))
             layers["bk"] = zeros((n, KV * hd))
-            layers["bv"] = zeros((n, KV * hd))
+            layers["bv"] = zeros((n, KV * vd))
         if cfg.qk_norm:
             layers["q_norm"] = ones((n, hd))
             layers["k_norm"] = ones((n, hd))
         if cfg.o_bias:
             layers["bo"] = zeros((n, D))
-        if cfg.attention_sinks:
+        if kind.sink if kind else cfg.attention_sinks:
             layers["sink"] = w(ks[15], (n, H), 4)  # std 0.5
     if moe:
         Fm = cfg.moe_ffn_size
+        Eh = cfg.num_experts_held  # the router scores all E
         layers["router"] = w(ks[4], (n, D, E), D)
-        layers["router_bias"] = zeros((n, E), dtype=jnp.float32)
-        layers["w_gate"] = w(ks[5], (n, E, D, Fm), D)
-        layers["w_up"] = w(ks[6], (n, E, D, Fm), D)
-        layers["w_down"] = w(ks[7], (n, E, Fm, D), Fm)
+        layers["router_bias"] = (
+            _normal_leaf(ks[8], (n, E), 100, jnp.float32)  # std 0.1
+            if kind else zeros((n, E), dtype=jnp.float32))
+        layers["w_gate"] = w(ks[5], (n, Eh, D, Fm), D)
+        layers["w_up"] = w(ks[6], (n, Eh, D, Fm), D)
+        layers["w_down"] = w(ks[7], (n, Eh, Fm, D), Fm)
         if cfg.moe_activation == "swiglu_oss":
             layers["b_gate"] = zeros((n, E, Fm))
             layers["b_up"] = zeros((n, E, Fm))
@@ -186,6 +194,43 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
         layers["w_up"] = w(ks[6], (n, D, F), D)
         layers["w_down"] = w(ks[7], (n, F, D), F)
     return layers
+
+
+class LayerStack(NamedTuple):
+    """The layers of one (kind, dense | experts) combination: they share
+    every parameter shape, so they stack on a leading axis."""
+
+    kind: int       # index into cfg.layer_kinds
+    moe: bool
+    layers: tuple   # model layer indices, in order
+
+
+def layer_stacks(cfg: ModelConfig) -> tuple:
+    """The parameter stacks of a model with layer kinds, in order of first
+    appearance. ``params["stacks"][i]`` holds ``layer_stacks(cfg)[i]``."""
+    found: dict = {}
+    for i, kind in enumerate(cfg.layer_pattern):
+        moe = cfg.is_moe and i >= cfg.first_k_dense_replace
+        found.setdefault((kind, moe), []).append(i)
+    return tuple(LayerStack(kind, moe, tuple(layers))
+                 for (kind, moe), layers in found.items())
+
+
+def _layer_runs(cfg: ModelConfig) -> list:
+    """Runs of consecutive layers of one stack, in model order: (stack,
+    offset in the stack, layers, offset in the kind's cache group)."""
+    stacks = layer_stacks(cfg)
+    where = {i: (s, st.layers.index(i))
+             for s, st in enumerate(stacks) for i in st.layers}
+    runs: list = []
+    for i in range(cfg.num_layers):
+        s, off = where[i]
+        if runs and runs[-1][0] == s and runs[-1][1] + runs[-1][2] == off:
+            runs[-1][2] += 1
+        else:
+            group = cfg.kv_cache_spec[stacks[s].kind]
+            runs.append([s, off, 1, group.layers.index(i)])
+    return [tuple(r) for r in runs]
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=None, *,
@@ -216,10 +261,20 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None, *,
 
     lazy = {
         "embed": w(ks[0], (V, D), D),
-        "layers": _init_layer_stack(cfg, ks[1], L - k_dense, cfg.is_moe, dtype),
         "final_norm": _const_leaf(1.0, (D,), dtype=dtype),
     }
-    if k_dense:
+    if cfg.layer_kinds is not None:
+        # one stack per (kind, dense | experts), run in the published order
+        # by forward (layer_stacks says which layers each one holds)
+        stacks = layer_stacks(cfg)
+        lazy["stacks"] = tuple(
+            _init_layer_stack(cfg, k, len(st.layers), st.moe, dtype,
+                              kind=cfg.layer_kinds[st.kind])
+            for k, st in zip(jax.random.split(ks[1], len(stacks)), stacks))
+    else:
+        lazy["layers"] = _init_layer_stack(cfg, ks[1], L - k_dense,
+                                           cfg.is_moe, dtype)
+    if k_dense and cfg.layer_kinds is None:
         lazy["dense_layers"] = _init_layer_stack(cfg, ks[2], k_dense, False, dtype)
     if not cfg.tie_word_embeddings:
         lazy["lm_head"] = w(ks[3], (D, V), D)
@@ -256,7 +311,7 @@ def mla_tpla_shards(cfg: Optional[ModelConfig], mesh: Optional[Mesh]) -> int:
 
 
 def _layer_stack_shardings(cfg: ModelConfig, mesh: Mesh, moe: bool,
-                           stack_axis=None) -> dict:
+                           stack_axis=None, sink=None) -> dict:
     """``stack_axis``: mesh axis for the stacked-layer leading dim — "pp"
     when pipeline stages each hold a slice of the stack (pipeline.py),
     None (replicated) otherwise."""
@@ -305,7 +360,7 @@ def _layer_stack_shardings(cfg: ModelConfig, mesh: Mesh, moe: bool,
             layers["k_norm"] = ns(None, None)
         if cfg.o_bias:
             layers["bo"] = ns(None, None)
-        if cfg.attention_sinks:
+        if cfg.attention_sinks if sink is None else sink:
             layers["sink"] = ns(None, "tp")
     if moe:
         layers["router"] = ns(None, None, None)
@@ -342,12 +397,16 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
     k_dense = cfg.num_dense_prefix_layers
     main_axis = ("pp" if pp > 1 and (cfg.num_layers - k_dense) % pp == 0
                  else None)
-    out = {
-        "embed": ns(None, None),
-        "layers": _layer_stack_shardings(cfg, mesh, cfg.is_moe, main_axis),
-        "final_norm": ns(None),
-    }
-    if k_dense:
+    out = {"embed": ns(None, None), "final_norm": ns(None)}
+    if cfg.layer_kinds is not None:
+        out["stacks"] = tuple(
+            _layer_stack_shardings(cfg, mesh, st.moe,
+                                   sink=cfg.layer_kinds[st.kind].sink)
+            for st in layer_stacks(cfg))
+    else:
+        out["layers"] = _layer_stack_shardings(cfg, mesh, cfg.is_moe,
+                                               main_axis)
+    if k_dense and cfg.layer_kinds is None:
         dense_axis = "pp" if pp > 1 and k_dense % pp == 0 else None
         out["dense_layers"] = _layer_stack_shardings(cfg, mesh, False,
                                                      dense_axis)
@@ -528,8 +587,15 @@ def mla_softmax_scale(cfg: ModelConfig) -> float:
     return float(scale)
 
 
-def _rope(x, positions, theta, scaling: Optional[dict] = None):
-    """Rotary embedding, llama convention (half-split). x: [B,S,N,hd]."""
+def _rope(x, positions, theta, scaling: Optional[dict] = None,
+          rotary_dim: Optional[int] = None):
+    """Rotary embedding, llama convention (half-split). x: [B,S,N,hd].
+    ``rotary_dim``: only the leading dims turn (partial rotary, the
+    half-split inside them); the rest pass through."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        return jnp.concatenate(
+            [_rope(x[..., :rotary_dim], positions, theta, scaling),
+             x[..., rotary_dim:]], axis=-1)
     hd = x.shape[-1]
     inv_freq, attn_scale = rope_params(theta, hd, scaling)
     angles = positions[..., None].astype(jnp.float32) * inv_freq  # [B,S,half]
@@ -555,17 +621,20 @@ def _paged_attention(q, k_cache, v_cache, lidx, block_tables, positions,
     contract.)
     """
     B, S, H, hd = q.shape
-    KV = cfg.num_kv_heads
+    from dynamo_tpu.engine.cache import cache_shape, gather_pages
+
+    KV = cache_shape(v_cache)[2]
     G = H // KV
     W = block_tables.shape[1]
     T = W * block_size
 
     slot_idx = block_tables[:, :, None] * block_size + jnp.arange(block_size)[None, None, :]
     slot_idx = slot_idx.reshape(B, T)
-    from dynamo_tpu.engine.cache import gather_pages
 
-    k = gather_pages(k_cache, lidx, slot_idx)  # [B, T, KV, hd]
-    v = gather_pages(v_cache, lidx, slot_idx)
+    # a wide K head is stored as lane rows, zero past hd (k_cache_dim)
+    k = gather_pages(k_cache, lidx, slot_idx).reshape(
+        B, T, KV, -1)[..., :hd]                          # [B, T, KV, hd]
+    v = gather_pages(v_cache, lidx, slot_idx)            # [B, T, KV, vd]
 
     qg = q.reshape(B, S, KV, G, hd)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg.astype(jnp.float32), k.astype(jnp.float32))
@@ -600,7 +669,7 @@ def _paged_attention(q, k_cache, v_cache, lidx, block_tables, positions,
     else:
         probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v.astype(jnp.float32))
-    return out.reshape(B, S, H, hd).astype(q.dtype)
+    return out.reshape(B, S, H, v.shape[-1]).astype(q.dtype)
 
 
 from dynamo_tpu.engine.config import RAGGED_MAX_CHUNKS
@@ -637,11 +706,12 @@ def _paged_attention_seg(q, k_cache, v_cache, lidx, block_tables, positions,
     paths keep their exact historical numerics.
     """
     B, S, H, hd = q.shape
-    KV = cfg.num_kv_heads
+    from dynamo_tpu.engine.cache import cache_shape, gather_pages
+
+    KV, vd = cache_shape(v_cache)[2:]
     G = H // KV
     W = block_tables.shape[1]
     bs = block_size
-    from dynamo_tpu.engine.cache import gather_pages
 
     spp = max(1, min(W, -(-seg_keys // bs)))
     SEG = spp * bs
@@ -665,7 +735,9 @@ def _paged_attention_seg(q, k_cache, v_cache, lidx, block_tables, positions,
         pages = jax.lax.dynamic_slice(bt, (0, s * spp), (B, spp))
         slot_idx = (pages[:, :, None] * bs
                     + jnp.arange(bs)[None, None, :]).reshape(B, SEG)
-        k = gather_pages(k_cache, lidx, slot_idx).astype(jnp.float32)
+        # a wide K head is stored as lane rows, zero past hd
+        k = gather_pages(k_cache, lidx, slot_idx).reshape(
+            B, SEG, KV, -1)[..., :hd].astype(jnp.float32)
         v = gather_pages(v_cache, lidx, slot_idx).astype(jnp.float32)
         sc = jnp.einsum("bskgd,btkd->bkgst", qg, k) / np.sqrt(hd)
         if cap:
@@ -689,7 +761,7 @@ def _paged_attention_seg(q, k_cache, v_cache, lidx, block_tables, positions,
 
     m0 = jnp.full((B, KV, G, S), -1e30, jnp.float32)
     l0 = jnp.zeros((B, KV, G, S), jnp.float32)
-    acc0 = jnp.zeros((B, KV, G, S, hd), jnp.float32)
+    acc0 = jnp.zeros((B, KV, G, S, vd), jnp.float32)
     _, m, l, acc = jax.lax.while_loop(
         cond, body, (0, *_carry_like(qg, m0, l0, acc0)))
     if sinks is not None:
@@ -702,7 +774,7 @@ def _paged_attention_seg(q, k_cache, v_cache, lidx, block_tables, positions,
             l * coef + jnp.exp(sk - m2))[..., None]
     else:
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, vd).astype(q.dtype)
 
 
 def ragged_grid_shape(t_bucket: int) -> tuple[int, int]:
@@ -740,7 +812,10 @@ def _ragged_attention(q, kc, vc, lidx, block_tables, positions, rows3,
     (q_start, q_len, kv_len); grid_rows None = no-chunk variant (the
     pipelined decode path) — the grid sub-call is skipped entirely.
     """
+    from dynamo_tpu.engine.cache import cache_shape
+
     T, H, hd = q.shape
+    vd = cache_shape(vc)[3]
     R = rows3.shape[0]
     q_start, q_len, kv_lens = rows3[:, 0], rows3[:, 1], rows3[:, 2]
 
@@ -766,7 +841,7 @@ def _ragged_attention(q, kc, vc, lidx, block_tables, positions, rows3,
         q_pad[dec_idx][:, None], kc, vc, lidx, block_tables,
         pos_pad[dec_idx][:, None], jnp.where(is_dec, kv_lens, 0),
         cfg, block_size, window=window, sinks=sinks)[:, 0]  # [R, H, hd]
-    out = jnp.zeros((T + 1, H, hd), q.dtype).at[dec_idx].set(
+    out = jnp.zeros((T + 1, H, vd), q.dtype).at[dec_idx].set(
         dec_out.astype(q.dtype))[:T]
 
     if grid_rows is not None:
@@ -1083,8 +1158,9 @@ def _mlp_dense(x, lp, act: str = "silu"):
     return _mm(h, lp["w_down"])
 
 
-def _router_weights(xf, router_w, router_bias, cfg: ModelConfig):
-    """Token→expert combine weights [N, E] (f32), zero for unrouted experts.
+def _router_choice(xf, router_w, router_bias, cfg: ModelConfig):
+    """Each token's experts and their combine weights: (ids [N, K] int32,
+    gates [N, K] f32).
 
     Two scoring disciplines (ref workloads: Mixtral recipes use softmax;
     DeepSeek-V3 wide-EP uses sigmoid — recipes/deepseek-r1/sglang-wideep):
@@ -1129,9 +1205,108 @@ def _router_weights(xf, router_w, router_bias, cfg: ModelConfig):
         gates = jnp.take_along_axis(probs, topi, axis=1)
     if cfg.norm_topk_prob:
         gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
-    gates = gates * cfg.routed_scaling_factor
-    return jnp.zeros((N, E), jnp.float32).at[
+    return topi, gates * cfg.routed_scaling_factor
+
+
+def _router_weights(xf, router_w, router_bias, cfg: ModelConfig):
+    """Token→expert combine weights [N, E] (f32), zero for unrouted experts
+    (:func:`_router_choice`, scattered dense)."""
+    N = xf.shape[0]
+    topi, gates = _router_choice(xf, router_w, router_bias, cfg)
+    return jnp.zeros((N, cfg.num_experts), jnp.float32).at[
         jnp.arange(N)[:, None], topi].add(gates)
+
+
+def moe_stats_width(cfg: ModelConfig) -> int:
+    """Length of the held-experts layer's counter vector: assignments
+    routed anywhere, assignments to held experts, held experts with at
+    least one token, then each held expert's tokens. A step returns one
+    such row a cache group (layer kind), summed over the group's layers."""
+    return 3 + cfg.num_experts_held
+
+
+def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
+                  tag: str = ""):
+    """The expert layer of ONE rank of an expert-parallel deployment: told
+    which experts it holds (``cfg.experts_held``; None = all), it routes
+    over all ``num_experts``, computes its own experts' part of the result
+    for the tokens routed to them, and leaves out what the absent experts
+    would add (no exchange on one chip, nothing standing in for it).
+
+    Dropless, and the work follows the routing: the (token, expert) pairs
+    whose expert is held are laid out by expert in a static buffer sized
+    for the worst case (every pair held), each expert's rows padded to
+    whole ``ROW_TILE`` tiles, and one grouped matrix product per projection
+    (ops/grouped_matmul.py) launches only the tiles in use — it reads the
+    weights of the experts somebody chose and of no other.
+
+    x [N, D]; ``valid`` [N] bool marks real tokens (a step's padding is
+    routed nowhere and counted nowhere). ``experts``: the expert matrices
+    of the layer's whole STACK ([L, E, ·, ·] each, by name) with the layer's
+    index in it as ``lp["layer_in_stack"]`` — the kernel takes its blocks
+    out of the stack, where a layer sliced out by the scan is copied whole
+    before every launch; None = ``lp`` holds the layer's own. ``tag``
+    names the three launches in the device trace
+    (``moe_grouped_matmul<tag>_{gate,up,down}``; forward's tag says the
+    cache group and the step program, so that an op's time can be held
+    against the work of exactly the steps that ran it). Returns (y [N, D], counters
+    [moe_stats_width] int32 — see :func:`moe_stats_width` — and the
+    router's choices [N, K], for the comparison with the reference).
+    """
+    from dynamo_tpu.ops.grouped_matmul import ROW_TILE as tm
+    from dynamo_tpu.ops.grouped_matmul import grouped_matmul
+
+    N, D = x.shape
+    K = cfg.num_experts_per_tok
+    first, Eh = cfg.experts_held or (0, cfg.num_experts)
+    P_ = N * K
+    tiles_max = -(-P_ // tm) + Eh   # sum_e ceil(c_e / tm) <= P/tm + Eh
+    M = tiles_max * tm
+
+    topi, gates = _router_choice(x, lp["router"], lp["router_bias"], cfg)
+    held = (topi >= first) & (topi < first + Eh) & valid[:, None]
+    e = jnp.where(held, topi - first, Eh).reshape(P_)  # Eh = "not here"
+    # Dense compares and sums, no sort and no small gather: every XLA
+    # gather (and a scatter-add's buffer) leaves a hint op of no work and
+    # no time in each step's trace, under a name of its own a program.
+    on = (e[:, None] == jnp.arange(Eh, dtype=e.dtype)[None, :]).astype(
+        jnp.int32)                                   # [P, Eh] pair x expert
+    counts = on.sum(0)
+    tiles = (counts + (tm - 1)) // tm
+    tile_end = jnp.cumsum(tiles)
+    row0 = (tile_end - tiles) * tm       # first buffer row of each expert
+    # a pair's buffer row: its expert's first row + its rank among the
+    # expert's pairs, in token order (row M is the dump)
+    rank = ((jnp.cumsum(on, axis=0) - 1) * on).sum(1)
+    row = jnp.where(on.any(1), (on * row0[None, :]).sum(1) + rank, M)
+    # rows -> tokens (token N is the zero row)
+    src = jnp.full((M + 1,), N, jnp.int32).at[row].set(
+        jnp.arange(P_, dtype=jnp.int32) // K)[:M]
+    xb = jnp.pad(x, ((0, 1), (0, 0)))[src]
+    tile_group = jnp.minimum(  # tile i belongs to the first expert whose
+        (tile_end[None, :]     # tiles end beyond it
+         <= jnp.arange(tiles_max, dtype=jnp.int32)[:, None]).sum(
+             1, dtype=jnp.int32), Eh - 1)
+    num_tiles = tile_end[-1]
+
+    ew, layer = (lp, 0) if experts is None else (
+        experts, lp["layer_in_stack"])
+
+    def gmm(a, name):
+        return grouped_matmul(a, _qmat(ew["w_" + name], a.dtype), tile_group,
+                              num_tiles, layer, tag=f"{tag}_{name}")
+
+    inter = jax.nn.silu(gmm(xb, "gate")) * gmm(xb, "up")
+    yb = gmm(inter, "down")                             # [M, D]
+    # rows of tiles that were not launched hold whatever was there: a
+    # pair is read back only from a row that was written
+    y = jnp.where((row < M)[:, None], yb[jnp.minimum(row, M - 1)], 0)
+    y = (y.reshape(N, K, D).astype(jnp.float32)
+         * gates[..., None]).sum(1).astype(x.dtype)
+    stats = jnp.pad(counts, (3, 0)).at[0].set(
+        valid.sum().astype(jnp.int32) * K).at[1].set(counts.sum()).at[2].set(
+        (counts > 0).sum().astype(jnp.int32))
+    return y, stats, topi
 
 
 def _oss_glu(gate, up, alpha: float = 1.702, limit: float = 7.0):
@@ -1365,7 +1540,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             use_pallas: bool = False, use_flash_prefill: bool = False,
             mesh: Optional[Mesh] = None, all_logits: bool = False,
             return_hidden: bool = False, mm_vec=None, mm_mask=None,
-            ragged=None):
+            ragged=None, moe_stats: bool = False, moe_routing: bool = False):
     """One engine step.
 
     Args:
@@ -1388,13 +1563,36 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     scatter, MLP/MoE) runs the exact same code as the bucketed step, so
     parity holds by construction.
 
-    Returns: (logits [B, V] f32 at last_idx, k_cache, v_cache)
+    A model with layer kinds (``cfg.layer_kinds``) has one cache group a
+    kind: k_cache/v_cache are then TUPLES of such arrays, one per group of
+    ``cfg.kv_cache_spec``, all indexed by the one block table.
+
+    Returns: (logits [B, V] f32 at last_idx, k_cache, v_cache) and, with
+    ``moe_stats`` (held-experts models only), the expert layers' counters
+    [cache groups, :func:`moe_stats_width`] as a fourth;
+    ``moe_routing`` adds every expert layer's choices [L_moe, B·S, K] as a
+    fifth (chipbench/check_reference.py tells them to the reference).
     """
     B, S = tokens.shape
-    D, hd = cfg.hidden_size, cfg.head_dim
-    H, KV = cfg.num_heads, cfg.num_kv_heads
+    D, hd, vd = cfg.hidden_size, cfg.head_dim, cfg.v_dim
+    H = cfg.num_heads
     from dynamo_tpu.engine.cache import gather_pages, is_quant_cache
     kv_quant = is_quant_cache(k_cache)
+    #: the ring, bucketed-decode and flash-prefill kernels know one KV-head
+    #: count and one head width
+    one_width = hd == vd == cfg.k_cache_dim and cfg.layer_kinds is None
+    held = cfg.is_moe and cfg.experts_held is not None
+    tok_valid = None
+    #: which step program this is, in the grouped matmuls' op names: the
+    #: decode-only variant (no chunk grid) or the mixed one, and its tokens
+    program = ("d" if ragged is not None and ragged[3] is None
+               else "m") + str(B * S)
+    if held and ragged is not None:
+        # a step's padding tokens (past the rows' last) are routed nowhere
+        n_real = jnp.max(ragged[0][:, 0] + ragged[0][:, 1])
+        tok_valid = (jnp.arange(B * S) < n_real)
+    elif held:
+        tok_valid = jnp.ones((B * S,), bool)
 
     x = params["embed"][tokens]  # [B,S,D]
     if cfg.embed_scale:
@@ -1406,17 +1604,31 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         # embeddings (llava-style placeholder substitution)
         x = jnp.where(mm_mask[..., None], mm_vec.astype(x.dtype), x)
 
-    def make_layer(moe: bool):
+    def make_layer(moe: bool, kind=None, group=None, experts=None):
+        """The scan body of a run of layers. ``kind``/``group``: the run's
+        :class:`LayerKind` and the index of its cache group, where the
+        model has kinds (``lidx`` then counts inside the group);
+        ``experts``: see :func:`_mlp_moe_held`."""
         def layer(carry, xs):
-            return _layer_body(carry, xs, moe)
+            x, kcs, vcs, st = carry
+            kc, vc = (kcs, vcs) if group is None else (kcs[group],
+                                                        vcs[group])
+            (x, kc, vc, st), ids = _layer_body(
+                (x, kc, vc, st), xs, moe, kind or cfg.layer_kind(0),
+                experts, group or 0)
+            if group is not None:
+                kc = kcs[:group] + (kc,) + kcs[group + 1:]
+                vc = vcs[:group] + (vc,) + vcs[group + 1:]
+            return (x, kc, vc, st), ids
         return layer
 
-    def _layer_body(carry, xs, moe):
+    def _layer_body(carry, xs, moe, kind, experts=None, group=0):
         # caches ride the scan CARRY with indexed in-place updates — as scan
         # xs/ys XLA materializes fresh stacked outputs, i.e. a full cache
         # copy per step (measured: burst time scaled with cache size)
-        x, kc, vc = carry
+        x, kc, vc, st = carry
         lp, lidx = xs
+        KV = kind.num_kv_heads
         h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         dp_ok = mesh is None or B % mesh.shape.get("dp", 1) == 0
         if cfg.is_mla:
@@ -1432,7 +1644,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 use_flash=use_flash_prefill and dp_ok and ragged is None,
                 mesh=mesh, ragged=ragged)
             x = x + _mm(attn_flat, lp["wo"])
-            return _mlp_epilogue(x, kc, vc, lp, moe)
+            return _mlp_epilogue(x, kc, vc, st, lp, moe, experts, group)
         q = _mm(h, lp["wq"])
         k = _mm(h, lp["wk"])
         v = _mm(h, lp["wv"])
@@ -1442,12 +1654,18 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             v = v + lp["bv"]
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, KV, hd)
-        v = v.reshape(B, S, KV, hd)
+        v = v.reshape(B, S, KV, vd)
         if cfg.qk_norm:  # Qwen3: per-head RMSNorm before RoPE
             q = _rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = _rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-        q = _rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
-        k = _rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
+        q = _rope(q, positions, kind.rope_theta, cfg.rope_scaling,
+                  cfg.rotary_dim)
+        k = _rope(k, positions, kind.rope_theta, cfg.rope_scaling,
+                  cfg.rotary_dim)
+        if cfg.k_cache_dim != hd:
+            # a wide K head is stored as whole lane rows; the zeros add
+            # nothing to a score, and every reader pads or cuts q to match
+            k = jnp.pad(k, ((0, 0),) * 3 + ((0, cfg.k_cache_dim - hd),))
         if cfg.query_pre_attn_scalar is not None:
             # Gemma-2: score scale is qpas^-0.5, not hd^-0.5; every path
             # below folds hd^-0.5, so pre-scale q by sqrt(hd/qpas)
@@ -1458,16 +1676,17 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         if kv_quant:
             from dynamo_tpu.engine.cache import quantize_kv
 
-            kq, ks = quantize_kv(k.reshape(B * S, KV, hd))
-            vq, vs = quantize_kv(v.reshape(B * S, KV, hd))
+            kq, ks = quantize_kv(k.reshape(B * S, KV, -1))
+            vq, vs = quantize_kv(v.reshape(B * S, KV, vd))
+            kq = kq.reshape(B * S, *kc["q"].shape[2:])
             kc = {"q": kc["q"].at[lidx, flat_slots].set(kq, mode="drop"),
                   "s": kc["s"].at[lidx, flat_slots].set(ks, mode="drop")}
             vc = {"q": vc["q"].at[lidx, flat_slots].set(vq, mode="drop"),
                   "s": vc["s"].at[lidx, flat_slots].set(vs, mode="drop")}
         else:
-            kc = kc.at[lidx, flat_slots].set(k.reshape(B * S, KV, hd),
-                                             mode="drop")
-            vc = vc.at[lidx, flat_slots].set(v.reshape(B * S, KV, hd),
+            kc = kc.at[lidx, flat_slots].set(
+                k.reshape(B * S, *kc.shape[2:]), mode="drop")
+            vc = vc.at[lidx, flat_slots].set(v.reshape(B * S, KV, vd),
                                              mode="drop")
 
         # shard_map needs the (static) batch divisible by the dp axis
@@ -1487,7 +1706,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         sp_n = mesh.shape.get("sp", 1) if mesh is not None else 1
         tp_n = mesh.shape.get("tp", 1) if mesh is not None else 1
         ring_want = sp_n > 1 and S > 1 and ragged is None
-        ring_ok = (ring_want and dp_ok and S % sp_n == 0
+        ring_ok = (ring_want and dp_ok and S % sp_n == 0 and one_width
                    and H % tp_n == 0 and KV % tp_n == 0
                    and (H // tp_n) % max(1, KV // tp_n) == 0
                    # per-layer windows / sink logits / score softcaps:
@@ -1504,7 +1723,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         if cfg.layer_windows is not None:
             window = jnp.asarray(cfg.layer_windows, jnp.int32)[lidx]
         else:
-            window = jnp.asarray(cfg.sliding_window or 0, jnp.int32)
+            window = jnp.asarray(kind.window, jnp.int32)
         sinks = lp.get("sink", jnp.zeros((q.shape[2],), q.dtype))
         if ragged is not None:
             rows3, grid_row, grid_col, grid_rows = ragged
@@ -1525,15 +1744,18 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             # non-aligned shapes must take the grid path below instead
             use_ragged_kernel = (use_pallas and mesh is None
                                  and not cfg.attn_logit_softcap
-                                 and ragged_pallas_supported(KV, hd))
+                                 and ragged_pallas_supported(
+                                     KV, cfg.k_cache_dim // cfg.k_lane_rows,
+                                     vd))
             if use_ragged_kernel:
                 from dynamo_tpu.engine.cache import cache_shape
 
+                # K's rows: KV heads, times the lane rows of a wide head
                 L_, slots_, KV_, hd_ = cache_shape(kc)
                 nb = slots_ // block_size
                 flat = L_ * slots_
                 if kv_quant and not ragged_int8_kernel_supported(
-                        KV_, slots_, block_size):
+                        KV, slots_, block_size):
                     use_ragged_kernel = False
             if use_ragged_kernel and kv_quant:
                 # int8 pages IN-kernel: flat int8 page view + THIS layer's
@@ -1541,7 +1763,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 # scale_slot_base so the VMEM scale budget is per-layer
                 attn = ragged_paged_attention(
                     q[0], kc["q"].reshape(flat, KV_, hd_),
-                    vc["q"].reshape(flat, KV_, hd_),
+                    vc["q"].reshape(flat, KV, vd),
                     block_tables + lidx * nb, rows3,
                     block_size=block_size, window=window,
                     sinks=lp.get("sink"),
@@ -1553,7 +1775,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             elif use_ragged_kernel:
                 attn = ragged_paged_attention(
                     q[0], kc.reshape(flat, KV_, hd_),
-                    vc.reshape(flat, KV_, hd_),
+                    vc.reshape(flat, KV, vd),
                     block_tables + lidx * nb, rows3,
                     block_size=block_size, window=window,
                     sinks=lp.get("sink"))[None]
@@ -1582,7 +1804,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                           sp["scalar"], sp["bt"], P("dp", "sp"), sp["lens"]),
                 out_specs=P("dp", "sp", "tp", None), check_vma=False)
             attn = fn(q, kc, vc, lidx, bt_ring, positions, kv_lens)
-        elif use_pallas and S == 1 and dp_ok:
+        elif use_pallas and S == 1 and dp_ok and one_width:
             # decode fast path: Pallas kernel streams pages HBM→VMEM once
             # (sliding-window layers skip out-of-window pages entirely).
             # Under a mesh the kernel runs per-shard via shard_map (heads on
@@ -1600,7 +1822,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                     out_specs=P("dp", "tp", None), check_vma=False)
             attn = fn(q[:, 0], kc, vc, lidx, block_tables, kv_lens,
                       window, sinks)[:, None]
-        elif use_flash_prefill and S > 1 and dp_ok:
+        elif use_flash_prefill and S > 1 and dp_ok and one_width:
             # prefill fast path: flash kernel, no O(S·T) HBM score tensor;
             # window is traced (per-layer for gpt-oss), sinks seed the
             # online softmax
@@ -1617,23 +1839,35 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                       window, sinks)
         else:
             window = (jnp.asarray(cfg.layer_windows, jnp.int32)[lidx]
-                      if cfg.layer_windows is not None else None)
+                      if cfg.layer_windows is not None
+                      else kind.window if cfg.layer_kinds is not None
+                      else None)
             attn = _paged_attention(q, kc, vc, lidx, block_tables, positions,
                                     kv_lens, cfg, block_size, window=window,
                                     sinks=lp.get("sink"))
-        attn_out = _mm(attn.reshape(B, S, H * hd), lp["wo"])
+        if cfg.value_scale != 1.0:  # P·(c·v) = c·(P·v)
+            attn = attn * jnp.asarray(cfg.value_scale, attn.dtype)
+        attn_out = _mm(attn.reshape(B, S, H * vd), lp["wo"])
         if "bo" in lp:
             attn_out = attn_out + lp["bo"]
         if cfg.sandwich_norms:  # Gemma-2: post-norm on the sublayer OUTPUT
             attn_out = _rms_norm(attn_out, lp["post_attn_norm"],
                                  cfg.rms_norm_eps)
         x = x + attn_out
-        return _mlp_epilogue(x, kc, vc, lp, moe)
+        return _mlp_epilogue(x, kc, vc, st, lp, moe, experts, group)
 
-    def _mlp_epilogue(x, kc, vc, lp, moe):
+    def _mlp_epilogue(x, kc, vc, st, lp, moe, experts=None, group=0):
         tp_n = mesh.shape.get("tp", 1) if mesh is not None else 1
         h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
-        if moe:
+        ids = None
+        if moe and held:
+            y, counted, ids = _mlp_moe_held(h.reshape(B * S, D), lp, cfg,
+                                            tok_valid, experts,
+                                            tag=f"_g{group}_{program}")
+            x = x + y.reshape(B, S, D)
+            st = st.at[group].add(counted)
+            ids = ids if moe_routing else None
+        elif moe:
             ep_want = mesh is not None and tp_n > 1
             n_tok_shards = 1
             if mesh is not None:
@@ -1660,27 +1894,52 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 x = x + fn(*ep_args)
             else:
                 x = x + _mlp_moe(h, lp, cfg)
-            if cfg.n_shared_experts:  # DeepSeek: dense shared experts on top
-                x = x + _mlp_dense(h, {"w_gate": lp["ws_gate"],
-                                       "w_up": lp["ws_up"],
-                                       "w_down": lp["ws_down"]})
         else:
             out = _mlp_dense(h, lp, act=cfg.hidden_activation)
             if cfg.sandwich_norms:  # Gemma-2 post-norm on the MLP output
                 out = _rms_norm(out, lp["post_mlp_norm"], cfg.rms_norm_eps)
             x = x + out
-        return (x, kc, vc), None
+        if moe and cfg.n_shared_experts:  # DeepSeek: dense shared experts
+            x = x + _mlp_dense(h, {"w_gate": lp["ws_gate"],
+                                   "w_up": lp["ws_up"],
+                                   "w_down": lp["ws_down"]})
+        return (x, kc, vc, st), ids
 
     k_dense = cfg.num_dense_prefix_layers
-    carry = (x, k_cache, v_cache)
-    if k_dense:
+    carry = (x, k_cache, v_cache,
+             jnp.zeros((len(cfg.kv_cache_spec), moe_stats_width(cfg)),
+                       jnp.int32) if held else None)
+    routing: list = []
+    if cfg.layer_kinds is not None:
+        # runs of layers of one stack, in the published order: one scan a
+        # run, its layer index counted inside the kind's cache group
+        for st_i, off, n, g_off in _layer_runs(cfg):
+            stack = layer_stacks(cfg)[st_i]
+            lps, experts = dict(params["stacks"][st_i]), None
+            if stack.moe and held:
+                # the expert matrices stay whole: the grouped matmul takes
+                # a layer's blocks out of the stack itself
+                experts = {k: lps.pop(k)
+                           for k in ("w_gate", "w_up", "w_down")}
+            if n != len(stack.layers):
+                lps = jax.tree.map(lambda a: a[off:off + n], lps)
+            lps["layer_in_stack"] = off + jnp.arange(n)
+            carry, ids = jax.lax.scan(
+                make_layer(stack.moe, cfg.layer_kinds[stack.kind],
+                           stack.kind, experts),
+                carry, (lps, g_off + jnp.arange(n)))
+            if ids is not None:
+                routing.append(ids)
+    else:
+        if k_dense:
+            carry, _ = jax.lax.scan(
+                make_layer(False), carry,
+                (params["dense_layers"], jnp.arange(k_dense)))
         carry, _ = jax.lax.scan(
-            make_layer(False), carry,
-            (params["dense_layers"], jnp.arange(k_dense)))
-    carry, _ = jax.lax.scan(
-        make_layer(cfg.is_moe), carry,
-        (params["layers"], k_dense + jnp.arange(cfg.num_layers - k_dense)))
-    (x, k_cache, v_cache) = carry
+            make_layer(cfg.is_moe), carry,
+            (params["layers"],
+             k_dense + jnp.arange(cfg.num_layers - k_dense)))
+    (x, k_cache, v_cache, stats) = carry
 
     x = _rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if return_hidden:  # embeddings: pooled downstream, no lm head
@@ -1704,6 +1963,10 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     else:
         x_last = x[jnp.arange(B), last_idx]  # [B, D]
     logits = _cap(_mm(x_last, head).astype(jnp.float32))
+    if moe_stats and held:
+        if moe_routing:
+            return logits, k_cache, v_cache, stats, jnp.concatenate(routing)
+        return logits, k_cache, v_cache, stats
     return logits, k_cache, v_cache
 
 
@@ -1977,11 +2240,13 @@ def ragged_fallback_reason(cfg: ModelConfig, mesh: Optional[Mesh],
         return "mesh"
     if cfg.attn_logit_softcap:
         return "softcap"
-    if not ragged_pallas_supported(cfg.num_kv_heads, cfg.head_dim):
-        return "lane_align"
-    if kv_quant and not ragged_int8_kernel_supported(
-            cfg.num_kv_heads, slots_per_layer, block_size):
-        return "scale_budget"
+    for group in cfg.kv_cache_spec:  # every layer kind takes the one gate
+        if not ragged_pallas_supported(group.kv_heads, group.k_shape[1],
+                                       group.v_dim):
+            return "lane_align"
+        if kv_quant and not ragged_int8_kernel_supported(
+                group.kv_heads, slots_per_layer, block_size):
+            return "scale_budget"
     return None
 
 
@@ -2009,6 +2274,11 @@ def _resolve_kernel_flags(cfg: ModelConfig, mesh: Optional[Mesh],
         if use_flash_prefill is None:
             use_flash_prefill = use_pallas or jax.default_backend() == "tpu"
         return (use_pallas and mla_ok), (bool(use_flash_prefill) and mla_ok)
+    if cfg.layer_kinds is not None or cfg.v_dim != cfg.head_dim:
+        # the bucketed decode and flash-prefill kernels know one KV-head
+        # count and one head width (forward keeps these models off them);
+        # the ragged kernel, the serving path, has its own gate in forward
+        return use_pallas, False
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
     heads_ok = (cfg.num_kv_heads % tp == 0 and cfg.num_heads % tp == 0
                 and cfg.num_heads % cfg.num_kv_heads == 0)
@@ -2154,7 +2424,7 @@ def make_ragged_step_fn(cfg: ModelConfig, block_size: int,
                         mesh: Optional[Mesh] = None, use_pallas: bool = False,
                         replicate_logits: bool = False,
                         kv_quant: bool = False, mm: bool = False,
-                        chunks: bool = True):
+                        chunks: bool = True, moe_routing: bool = False):
     """Jitted RAGGED engine step: every prefill chunk and decode row of a
     scheduler plan rides ONE packed token batch — no padding to separate
     (chunk-bucket × batch-bucket × width-bucket) signatures. The compiled
@@ -2174,7 +2444,10 @@ def make_ragged_step_fn(cfg: ModelConfig, block_size: int,
     [mm_vec [T, D], mm_mask [T],] k_cache, v_cache) ->
     (logits [R, V], k_cache, v_cache)`` (``mm=True`` adds the multimodal
     override operands; the engine compiles that variant lazily, only when
-    a request actually carries mm content).
+    a request actually carries mm content). A model whose expert layer
+    holds a share of its experts returns that layer's counters
+    (:func:`moe_stats_width`) as a fourth output and, with
+    ``moe_routing``, its routers' choices as a fifth.
     """
     decode_pallas, _ = _resolve_kernel_flags(cfg, mesh, use_pallas, False)
 
@@ -2191,8 +2464,8 @@ def make_ragged_step_fn(cfg: ModelConfig, block_size: int,
             params, ints5[0][None], ints5[1][None], ints5[2][None],
             block_tables, kv_lens, last_flat, k_cache, v_cache,
             cfg=cfg, block_size=block_size, use_pallas=decode_pallas,
-            mesh=mesh, mm_vec=mm_vec, mm_mask=mm_mask,
-            ragged=(rows3, ints5[3], ints5[4],
+            mesh=mesh, mm_vec=mm_vec, mm_mask=mm_mask, moe_stats=True,
+            moe_routing=moe_routing, ragged=(rows3, ints5[3], ints5[4],
                     grid_rows if chunks else None))
 
     kw = {}

@@ -6,7 +6,9 @@ token-choice MoE (Mixtral-style, experts shardable over "tp" = EP),
 sliding-window attention (Mistral), QKV bias (Qwen2), QK-norm (Qwen3 dense + MoE), and MLA — multi-head
 latent attention with a compressed paged cache (DeepSeek V2/V3, incl.
 sigmoid + group-limited routing, shared experts, and the dense layer
-prefix). Presets below are the shapes used by the reference's recipes (ref:
+prefix), and layer KINDS with their own KV-head count, rope base, window
+and sink, K/Q heads wider than V heads, and an expert layer that holds a
+share of the experts it routes over (MiMo-V2). Presets below are the shapes used by the reference's recipes (ref:
 recipes/llama-3-70b, recipes/deepseek-r1, recipes/gpt-oss-120b); unsupported
 architectures fail loudly rather than being approximated silently.
 """
@@ -144,6 +146,54 @@ def deepseek_v3() -> ModelConfig:
         routed_scaling_factor=2.5, n_group=8, topk_group=4)
 
 
+def _mimo_v2(*, vocab_size: int, pattern: tuple, experts_held,
+             **sizes) -> ModelConfig:
+    """MiMo-V2 (MiMo-V2-Flash / V2.5 language model): full (kind 0) and
+    window-128 (kind 1) attention layers with their own KV-head counts and
+    rope bases, a sink on the window kind only, 192-wide K/Q heads with
+    RoPE on the leading 64 beside 128-wide V heads scaled by 0.707, a dense
+    layer 0, then sigmoid-routed experts (top-8, bias-corrected choice,
+    normalised weights, no shared expert)."""
+    sizes = dict(
+        hidden_size=4096, intermediate_size=16384, num_heads=64,
+        head_dim=192, v_head_dim=128, rotary_dim=64,
+        layer_kinds=((4, 1e7, 0, False), (8, 1e4, 128, True)),
+        num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+        max_position_embeddings=1048576) | sizes
+    full = sizes["layer_kinds"][0]  # the published un-prefixed keys
+    return ModelConfig(
+        vocab_size=vocab_size, num_layers=len(pattern),
+        num_kv_heads=full[0], rope_theta=full[1],
+        layer_pattern=pattern, value_scale=0.707, rms_norm_eps=1e-5,
+        first_k_dense_replace=1, scoring_func="sigmoid",
+        norm_topk_prob=True, experts_held=experts_held, **sizes)
+
+
+def mimo_v25_ep16() -> ModelConfig:
+    """One chip's share of MiMo-V2.5 where 16 chips share each layer:
+    experts expert-parallel (16 of 256 held here), attention data-parallel,
+    the vocabulary in 8 slices (19,072 of 152,576 rows); layer 0 and one
+    whole period (5 window : 1 full) of the 48 layers, the rest lying on
+    further chips as pipeline stages (chipbench/configs/mimo-v25-ep16.json
+    has the arithmetic)."""
+    return _mimo_v2(vocab_size=19072, pattern=(0, 1, 1, 1, 1, 1, 0),
+                    experts_held=(0, 16))
+
+
+def mimo_tiny(experts_held=(0, 8)) -> ModelConfig:
+    """MiMo-V2's shape at test size with every published RATIO kept: a
+    dense layer then two periods of 5 window : 1 full, G = 8 and 16, K
+    heads (24, RoPE on 8) wider than V heads (16), a window (8) smaller
+    than a prompt chunk, 32 experts top-4 of which 8 are held."""
+    return _mimo_v2(
+        vocab_size=256, pattern=(0,) + (1, 1, 1, 1, 1, 0) * 2,
+        experts_held=experts_held, hidden_size=64, intermediate_size=128,
+        num_heads=16, head_dim=24, v_head_dim=16, rotary_dim=8,
+        layer_kinds=((1, 1e7, 0, False), (2, 1e4, 8, True)),
+        num_experts=32, num_experts_per_tok=4, moe_intermediate_size=32,
+        max_position_embeddings=512, dtype="float32")
+
+
 PRESETS = {
     "tiny": ModelConfig.tiny,
     "moe_tiny": moe_tiny,
@@ -161,6 +211,8 @@ PRESETS = {
     "gptoss_tiny": gptoss_tiny,
     "gpt_oss_20b": gpt_oss_20b,
     "gpt_oss_120b": gpt_oss_120b,
+    "mimo_tiny": mimo_tiny,
+    "mimo_v25_ep16": mimo_v25_ep16,
 }
 
 #: architectures the forward pass does NOT cover yet (listed so callers

@@ -144,6 +144,16 @@ class StepRecord:
     #: rows of this step with more query tokens than the ragged kernel's
     #: small tile (prompt chunks, long verify rows): its wide tile's work
     wide_tile_rows: int = 0
+    #: held-experts layer (engine/model._mlp_moe_held), summed over the
+    #: step's expert layers: (token, expert) pairs computed here, and held
+    #: experts with at least one token (the weight bytes the step read)
+    moe_pairs: int = 0
+    moe_experts_touched: int = 0
+    #: the same two, a cache group (layer kind): [[pairs, touched], ...]
+    moe_by_group: list = field(default_factory=list)
+    #: pages of window cache groups that lie wholly behind their
+    #: sequence's window: what releasing them would free
+    dead_window_pages: int = 0
     kv_tiers: dict = field(default_factory=dict)  # {g1..g4: blocks}
     onboard_inflight: int = 0
     restore_inflight: int = 0
@@ -189,6 +199,7 @@ class StepRecord:
         for k in ("preempt_swap", "preempt_recompute", "swap_out_blocks",
                   "swap_in_blocks", "starved_decode", "onboard_inflight",
                   "restore_inflight", "constrained_rows", "wide_tile_rows",
+                  "moe_pairs", "moe_experts_touched", "dead_window_pages",
                   "profile_path"):
             v = getattr(self, k)
             if v:
@@ -197,6 +208,8 @@ class StepRecord:
             v = getattr(self, k)
             if v:
                 d[k] = list(v)
+        if self.moe_pairs:
+            d["moe_by_group"] = [list(g) for g in self.moe_by_group]
         if self.kv_tiers:
             d["kv_tiers"] = dict(self.kv_tiers)
         if self.qos_mix:

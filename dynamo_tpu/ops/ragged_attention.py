@@ -13,11 +13,19 @@ Contract (one layer; the stacked-cache wiring lives in engine/model.py):
                                  ending at kv_len-1 (the engine's chunk
                                  layout), so per-token positions are pure
                                  index math: pos = kv_len - q_len + j
-  k/v cache    [slots, KV, hd]   flat paged layout (slot = block·bs + off)
+  k cache      [slots, KV·NB, 128]  flat paged layout (slot = block·bs +
+                                 off). A head of up to 128 dims is one row
+                                 (NB = 1); a wider one is stored as NB
+                                 whole 128-lane rows, the lanes past hd
+                                 zero (MiMo-V2's 192-wide head: NB = 2) —
+                                 Mosaic strides sublanes of 128-lane
+                                 buffers only. q is zero-padded to NB·128
+                                 here; the softmax scale is hd's
+  v cache      [slots, KV, 128]  V rows have their own width
   block_tables [R, W] int32      per ROW (0 = reserved null block)
   rows3        [R, 3] int32      (q_start, q_len, kv_len) per row; padding
                                  rows carry q_len = 0 and are skipped
-  → out        [T, H, hd]
+  → out        [T, H, vd]
 
 TPU mapping (everything here is shaped by what Mosaic will compile — see
 tests/test_chip_compile.py, which asks the chip's compiler without a chip):
@@ -46,13 +54,17 @@ One grid step is one row, and a row's work is done once:
   sublane-STRIDED read of the block buffer; Mosaic strides 32-bit sublanes
   only, so bf16 / int8 rows are read as 32-bit words and shifted apart
   (``_load_rows``). The query tile arrives ``[TQ·Hp, hd]`` (row = token·Hp
-  + head; Hp = heads padded to the sublane packing, which makes a tile's
-  data-dependent DMA offset provably aligned) and is regrouped once per
-  tile, by the same strided read, into ``[KV, G·TQ, hd]``: per KV head one
-  ``[G·TQ, hd] x [hd, 512]`` score matmul and one ``[G·TQ, 512] x [512,
-  hd]`` P·V, no group mask and no column another head owns. G is whatever
-  ``H // KV`` is (4 Mistral, 7 Qwen2, 1 MHA); it pads only until the small
-  tile's rows fill a packed sublane tile.
+  + head, times NB lane rows; Hp = heads padded to the sublane packing,
+  which makes a tile's data-dependent DMA offset provably aligned) and is
+  regrouped once per tile, by the same strided read, into ``[KV, G·TQ,
+  kd]`` (kd = NB·128): per KV head one ``[G·TQ, kd] x [kd, 512]`` score
+  matmul and one ``[G·TQ, 512] x [512, vd]`` P·V, no group mask and no column another head owns. G is whatever
+  ``H // KV`` is (4 Mistral, 7 Qwen2, 1 MHA, 8 and 16 MiMo-V2's window
+  and full layers); it pads only until the small tile's rows fill a
+  packed sublane tile. A wide tile holds at most ``_WIDE_ROWS`` score
+  rows, so its tokens are 128 up to G = 8 and 64 at G = 16: the MXU's rows
+  are as full, and the f32 score temporaries stay a size the compiler was
+  shown to place.
 - **MXU inputs in the stored dtype, f32 accumulation.** bf16 pages meet a
   bf16 q as they are, int8 pages enter as q's dtype (exact for int8), f32
   pages (the CPU tests) stay f32. The online-softmax state (m, l, acc)
@@ -66,10 +78,12 @@ via ``scale_slot_base``; as a block's pages are fetched, each page's scales
 are rotated to the lanes of its keys' score columns. k-scales multiply the
 scores, v-scales fold into p before the PV matmul, so int8 pages cost the
 same two DMAs per page as bf16 at half the bytes. The only degrades to
-:func:`ragged_attention_xla` are a head dim that is not a lane multiple and
-scale tables past the VMEM budget — both static shape facts the engine
-counts and logs (``dynamo_ragged_fallback_total``), never a silent
-data-dependent branch. ``DYN_RAGGED_ORACLE=1`` routes to the XLA oracle
+:func:`ragged_attention_xla` are page rows that are not one 128-lane row
+(hd = 64 models; a V head wider than 128; a wider K head is stored as
+lane rows, ``ModelConfig.k_cache_dim``) and scale tables past the
+VMEM budget — both static shape facts the engine counts and logs
+(``dynamo_ragged_fallback_total``, reasons ``lane_align`` and
+``scale_budget``), never a silent data-dependent branch. ``DYN_RAGGED_ORACLE=1`` routes to the XLA oracle
 explicitly (bench/test A/B arms only).
 
 Trace + lowering of a step program is paid on every start (sixteen
@@ -101,17 +115,28 @@ _VMEM_LIMIT_BYTES = 64 << 20
 #: query tile of a short row (decode, speculative verify); a row with more
 #: tokens than this takes the wide tile (the engine counts those rows)
 NARROW_TILE = 8
-#: query tokens of one tile of a row longer than the small tile
+#: the most query tokens of one tile of a row longer than the small tile
 _WIDE_TILE = 128
+#: the most score rows (G · query tokens) of one KV head's wide tile
+_WIDE_ROWS = 1024
 #: keys of one streamed, double-buffered block
 _KEY_BLOCK = 512
 
 
-def ragged_pallas_supported(num_kv_heads: int, head_dim: int) -> bool:
-    """Pages DMA as [bs·KV, hd] tiles and Mosaic takes only whole 128-lane
-    rows, so the head dim itself must be a lane multiple (hd = 64 models
-    leave the kernel under ``lane_align``)."""
-    return head_dim % _LANE == 0
+def ragged_pallas_supported(num_kv_heads: int, k_dim: int,
+                            v_dim: int) -> bool:
+    """Pages DMA as [rows, 128] tiles and a head's rows are a sublane-
+    strided read, which Mosaic takes of 128-lane buffers only: the STORED
+    row of K and of V must each be one lane row (``k_dim`` is the width of
+    a stored row: 128 for a wide head kept as lane rows). hd = 64 models
+    leave the kernel under ``lane_align``."""
+    return k_dim == _LANE and v_dim == _LANE
+
+
+def _wide_tile(G: int) -> int:
+    """Query tokens of a wide tile for a KV head shared by ``G`` heads."""
+    fit = 1 << ((_WIDE_ROWS // G).bit_length() - 1)  # a power of two
+    return max(NARROW_TILE, min(_WIDE_TILE, fit))
 
 
 def _scale_table_shape(num_kv_heads: int, sc_slots: int, block_size: int):
@@ -149,6 +174,12 @@ def _rem(x, n: int):
     return jax.lax.rem(x, jnp.asarray(n, x.dtype))
 
 
+def _lane_row(row, nb: int, c: int):
+    """Row of lane row ``c`` of the ``nb`` that a (traced) ``row`` of wide
+    heads takes; one lane row a head traces nothing."""
+    return row if nb == 1 else row * nb + c
+
+
 def _load_rows(ref, start, count: int, stride: int):
     """Rows ``start, start+stride, ...`` (``count`` of them) of a 2-D VMEM
     ref — the sublane-strided read that takes ONE head's rows out of a
@@ -175,12 +206,12 @@ def _load_rows(ref, start, count: int, stride: int):
 def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
                    sbase_ref,  # scalar pf; sbase = scale-table page base
                    sink_ref,   # [KV, Gp, 128] f32 VMEM, lane-replicated
-                   q_ref,      # [Tpad·Hp, hd] HBM (softmax scale folded in)
-                   kcache_ref, vcache_ref,  # [pages, bs·KV, hd] HBM
+                   q_ref,      # [Tpad·Hp·NB, 128] HBM (softmax scale folded)
+                   kcache_ref, vcache_ref,  # [pages, bs·KV·NB | bs·KV, 128]
                    *rest,  # [ksc_ref, vsc_ref ([rows, KVp, 128] VMEM),]
                            # out_ref, scratch...
                    bs: int, tiles: tuple, KV: int, G: int, Gp: int, Hp: int,
-                   PB: int, has_sink: bool, quant: bool):
+                   NB: int, PB: int, has_sink: bool, quant: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -197,9 +228,9 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
     q_len = rows3_ref[r, 1]
     kv_len = rows3_ref[r, 2]
     win = win_ref[0]
-    hd = qbuf.shape[1]
+    hd = vbuf.shape[2]   # width of a V row, so of the output
     mm = qg.dtype        # MXU input dtype (module docstring)
-    N = bs * KV          # rows of one page: row = slot·KV + kv head
+    N = bs * KV          # rows of one V page: row = slot·KV + kv head
     BK = PB * bs         # keys of one streamed block
     # a KV head's keys are every KV-th row; where KV is not a multiple of
     # the page dtype's packing they come as ``pieces`` interleaved reads,
@@ -222,7 +253,9 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
         blk = block_tables_ref[r, w]
         slot = _rem(b, 2)
         dst = pl.ds(pl.multiple_of((w - b * PB) * N, N), N)
-        return (pltpu.make_async_copy(kcache_ref.at[blk], kbuf.at[slot, dst],
+        kdst = dst if NB == 1 else pl.ds(
+            pl.multiple_of((w - b * PB) * N * NB, N * NB), N * NB)
+        return (pltpu.make_async_copy(kcache_ref.at[blk], kbuf.at[slot, kdst],
                                       dma_sem.at[slot, 0]),
                 pltpu.make_async_copy(vcache_ref.at[blk], vbuf.at[slot, dst],
                                       dma_sem.at[slot, 1]))
@@ -250,10 +283,13 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
         return (rows[0] if len(rows) == 1
                 else jnp.concatenate(rows, axis=1))[:, :BK]
 
-    def head_rows(buf, b, k):
-        """[BK, hd]: KV head ``k``'s rows of the block in ``buf``."""
-        got = [_load_rows(buf.at[_rem(b, 2)], j * KV + k, BK // pieces,
-                          KV * pieces) for j in range(pieces)]
+    def head_rows(buf, b, k, nb=1):
+        """[BK, nb·128]: KV head ``k``'s rows of the block in ``buf``, its
+        ``nb`` lane rows side by side."""
+        got = [[_load_rows(buf.at[_rem(b, 2)], _lane_row(j * KV + k, nb, c),
+                           BK // pieces, KV * nb * pieces)
+                for c in range(nb)] for j in range(pieces)]
+        got = [g[0] if nb == 1 else jnp.concatenate(g, axis=1) for g in got]
         return (got[0] if pieces == 1
                 else jnp.concatenate(got, axis=0)).astype(mm)
 
@@ -262,7 +298,7 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
 
     def run_tiles(TQ: int):
         M = Gp * TQ          # score rows of one KV head: row = g·TQ + token
-        span = TQ * Hp       # rows of the tile in q / out: token·Hp + head
+        span = TQ * Hp       # rows of the tile in out: token·Hp + head
         tok_of_row = _rem(jax.lax.broadcasted_iota(jnp.int32, (M, 1), 0), TQ)
         col = jax.lax.broadcasted_iota(jnp.int32, (1, BK), 1)
         if pieces == 1:
@@ -277,8 +313,11 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
             # of at least TQ tokens never computes past itself
             off = jnp.minimum(t * TQ, jnp.maximum(q_len - TQ, 0))
             at = pl.ds(pl.multiple_of((q_start + off) * Hp, Hp), span)
-            fetch = pltpu.make_async_copy(q_ref.at[at], qbuf.at[pl.ds(0, span)],
-                                          qo_sem.at[0])
+            # q: NB lane rows a (token, head)
+            q_at = at if NB == 1 else pl.ds(pl.multiple_of(
+                (q_start + off) * Hp * NB, Hp * NB), span * NB)
+            fetch = pltpu.make_async_copy(
+                q_ref.at[q_at], qbuf.at[pl.ds(0, span * NB)], qo_sem.at[0])
             fetch.start()
 
             # positions of this tile: pos0 .. pos0+TQ-1 (chunk tokens occupy
@@ -310,8 +349,11 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
 
             def regroup(k, c):
                 # the G heads of KV head k, each a strided read of the tile
-                heads = [_load_rows(qbuf, k * G + g, TQ, Hp)
-                         for g in range(G)]
+                heads = [[_load_rows(qbuf, _lane_row(k * G + g, NB, c), TQ,
+                                     Hp * NB)
+                          for c in range(NB)] for g in range(G)]
+                heads = [h[0] if NB == 1 else jnp.concatenate(h, axis=1)
+                         for h in heads]
                 heads += [jnp.zeros_like(heads[0])] * (Gp - G)
                 qg[k, pl.ds(0, M)] = jnp.concatenate(heads).astype(mm)
                 if has_sink:
@@ -345,7 +387,7 @@ def _ragged_kernel(rows3_ref, block_tables_ref, win_ref,  # scalar prefetch
                 def head_body(k, c2):
                     rows = (k, pl.ds(0, M))
                     s = jax.lax.dot_general(
-                        qg[rows], head_rows(kbuf, b, k),
+                        qg[rows], head_rows(kbuf, b, k, NB),
                         (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32)  # [M, BK]
                     if quant:
@@ -414,7 +456,7 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, rows3, *,
     docstring for the contract.
 
     ``tq`` is the query tile of a short row (``q_len <= tq``: decode,
-    speculative verify); longer rows take ``_WIDE_TILE`` tokens a tile.
+    speculative verify); longer rows take :func:`_wide_tile` tokens a tile.
 
     ``k_scales``/``v_scales`` [sc_slots, KV] f32 (int8 caches): pages are
     int8 and dequantize IN the kernel — scales go VMEM-resident, fetched
@@ -424,13 +466,13 @@ def ragged_paged_attention(q, k_cache, v_cache, block_tables, rows3, *,
     one layer's scale slice plus ``lidx·slots`` so the VMEM budget is
     per-layer, not ×L.
 
-    Routes to :func:`ragged_attention_xla` only for a head dim off the
+    Routes to :func:`ragged_attention_xla` only for page rows off the
     lane multiple, scale tables past the VMEM budget, or the explicit
     ``DYN_RAGGED_ORACLE=1`` bench/test oracle switch."""
-    KV, hd = k_cache.shape[1:]
+    KV = v_cache.shape[1]
     bs = block_size
     quant = k_scales is not None
-    if (not ragged_pallas_supported(KV, hd)
+    if (not ragged_pallas_supported(KV, k_cache.shape[2], v_cache.shape[2])
             or (quant and not ragged_int8_kernel_supported(
                 KV, k_scales.shape[0], bs))
             or os.environ.get("DYN_RAGGED_ORACLE") == "1"):
@@ -458,7 +500,9 @@ def _ragged_call(q, k_cache, v_cache, block_tables, rows3, window,
     from jax.experimental.pallas import tpu as pltpu
 
     T, H, hd = q.shape
-    slots, KV, _ = k_cache.shape
+    slots, KV, vd = v_cache.shape
+    NB = k_cache.shape[1] // KV  # lane rows of one K head
+    kd = NB * _LANE
     G = H // KV
     R, W = block_tables.shape
     has_sink = sinks is not None
@@ -468,7 +512,7 @@ def _ragged_call(q, k_cache, v_cache, block_tables, rows3, window,
     mm = (jnp.promote_types(q.dtype, k_cache.dtype)
           if jnp.issubdtype(k_cache.dtype, jnp.floating) else q.dtype)
     # no row of a batch of <= tq tokens is longer than the small tile
-    tiles = (tq, _WIDE_TILE) if T > tq else (tq,)
+    tiles = (tq, _wide_tile(G)) if T > tq else (tq,)
     TQ = tiles[-1]
     # heads pad to the sublane packing of q's dtype (8 rows of 32 bits) so
     # a tile's rows start on a packed row; a KV head's group pads until the
@@ -484,7 +528,7 @@ def _ragged_call(q, k_cache, v_cache, block_tables, rows3, window,
     # overrun. Rows are (token, head): a tile starts on a multiple of Hp,
     # which is all Mosaic needs to take a data-dependent DMA offset.
     qs = q * jnp.asarray(1.0 / np.sqrt(hd), q.dtype)
-    qs = jnp.pad(qs, ((0, TQ), (0, Hp - H), (0, 0))).reshape(-1, hd)
+    qs = jnp.pad(qs, ((0, TQ), (0, Hp - H), (0, kd - hd))).reshape(-1, _LANE)
     sink_in = jnp.zeros((KV, Gp), jnp.float32)
     if has_sink:
         sink_in = jnp.pad(sinks.astype(jnp.float32).reshape(KV, G),
@@ -492,29 +536,30 @@ def _ragged_call(q, k_cache, v_cache, block_tables, rows3, window,
     sink_in = jnp.broadcast_to(sink_in[..., None], (KV, Gp, _LANE))
 
     kernel = functools.partial(
-        _ragged_kernel, bs=bs, tiles=tiles, KV=KV, G=G, Gp=Gp, Hp=Hp, PB=PB,
-        has_sink=has_sink, quant=quant)
+        _ragged_kernel, bs=bs, tiles=tiles, KV=KV, G=G, Gp=Gp, Hp=Hp, NB=NB,
+        PB=PB, has_sink=has_sink, quant=quant)
     in_specs = [
         pl.BlockSpec((KV, Gp, _LANE), lambda r, *_: (0, 0, 0)),
         pl.BlockSpec(memory_space=pltpu.HBM),  # q
         pl.BlockSpec(memory_space=pltpu.HBM),  # k pages
         pl.BlockSpec(memory_space=pltpu.HBM),  # v pages
     ]
-    # page view [pages, bs·KV, hd]: the same bytes as [slots, KV, hd] (a
-    # page's slots are consecutive), so XLA passes the cache through
+    # page view [pages, bs·rows, 128]: the same bytes as [slots, rows, 128]
+    # (a page's slots are consecutive), so XLA passes the cache through
     # without a relayout copy, and a page is one leading-dim index
-    operands = [sink_in, qs, k_cache.reshape(slots // bs, bs * KV, hd),
-                v_cache.reshape(slots // bs, bs * KV, hd)]
+    operands = [sink_in, qs,
+                k_cache.reshape(slots // bs, bs * KV * NB, _LANE),
+                v_cache.reshape(slots // bs, bs * KV, vd)]
     scratch = [
-        pltpu.VMEM((TQ * Hp, hd), q.dtype),           # qbuf: the query tile
-        pltpu.VMEM((KV, Gp * TQ, hd), mm),            # qg: it, by KV head
+        pltpu.VMEM((TQ * Hp * NB, _LANE), q.dtype),   # qbuf: the query tile
+        pltpu.VMEM((KV, Gp * TQ, kd), mm),            # qg: it, by KV head
         pltpu.VMEM((KV, Gp * TQ, _LANE), jnp.float32),  # m (lane-replicated)
         pltpu.VMEM((KV, Gp * TQ, _LANE), jnp.float32),  # l
-        pltpu.VMEM((KV, Gp * TQ, hd), jnp.float32),   # acc
-        pltpu.VMEM((TQ * Hp, hd), jnp.float32),       # o32: the output tile
-        pltpu.VMEM((TQ * Hp, hd), q.dtype),           # obuf: it, as stored
-        pltpu.VMEM((2, PB * bs * KV, hd), k_cache.dtype),  # kbuf: 2 blocks
-        pltpu.VMEM((2, PB * bs * KV, hd), v_cache.dtype),  # vbuf
+        pltpu.VMEM((KV, Gp * TQ, vd), jnp.float32),   # acc
+        pltpu.VMEM((TQ * Hp, vd), jnp.float32),       # o32: the output tile
+        pltpu.VMEM((TQ * Hp, vd), q.dtype),           # obuf: it, as stored
+        pltpu.VMEM((2, PB * bs * KV * NB, _LANE), k_cache.dtype),  # kbuf
+        pltpu.VMEM((2, PB * bs * KV, vd), v_cache.dtype),  # vbuf
         pltpu.SemaphoreType.DMA((2,)),                # q-in / out tiles
         pltpu.SemaphoreType.DMA((2, 2)),              # block pipeline
     ]
@@ -549,14 +594,14 @@ def _ragged_call(q, k_cache, v_cache, block_tables, rows3, window,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qs.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(((T + TQ) * Hp, vd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="ragged_paged_attention",
     )(rows3.astype(jnp.int32), block_tables.astype(jnp.int32),
       window.reshape(1), _div(scale_slot_base, bs).reshape(1), *operands)
-    return out.reshape(T + TQ, Hp, hd)[:T, :H]
+    return out.reshape(T + TQ, Hp, vd)[:T, :H]
 
 
 def ragged_attention_xla(q, k_cache, v_cache, block_tables, rows3, *,
@@ -569,7 +614,7 @@ def ragged_attention_xla(q, k_cache, v_cache, block_tables, rows3, *,
     gather with the same ``k_scales``/``v_scales``/``scale_slot_base``
     contract as the kernel."""
     T, H, hd = q.shape
-    KV = k_cache.shape[1]
+    KV = v_cache.shape[1]
     G = H // KV
     R, W = block_tables.shape
     bs = block_size
@@ -591,8 +636,10 @@ def ragged_attention_xla(q, k_cache, v_cache, block_tables, rows3, *,
 
     slot_idx = (block_tables[:, :, None] * bs
                 + jnp.arange(bs)[None, None, :]).reshape(R, Tk)
-    k = k_cache[slot_idx].astype(jnp.float32)  # [R, Tk, KV, hd]
-    v = v_cache[slot_idx].astype(jnp.float32)
+    # a wide K head is stored as lane rows, zero lanes past hd
+    k = k_cache[slot_idx].reshape(R, Tk, KV, -1)[..., :hd].astype(
+        jnp.float32)                                     # [R, Tk, KV, hd]
+    v = v_cache[slot_idx].astype(jnp.float32)            # [R, Tk, KV, vd]
     if k_scales is not None:
         # int8 pages: dequant in the gather, rebasing slot ids onto the
         # caller's (possibly per-layer) scale slice
@@ -620,4 +667,4 @@ def ragged_attention_xla(q, k_cache, v_cache, block_tables, rows3, *,
     else:
         p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("tkgs,tskd->tkgd", p, v)
-    return o.reshape(T, H, hd).astype(q.dtype)
+    return o.reshape(T, H, v.shape[-1]).astype(q.dtype)

@@ -348,10 +348,23 @@ class LocalControlPlane(ControlPlane):
             self._sweeper = asyncio.get_running_loop().create_task(self._sweep_loop())
 
     async def _sweep_loop(self):
+        """Revoke leases past their deadline. Time in which this process
+        itself did not run (a frozen host: every process stops, the clock
+        does not) is not held against the holders: nobody could have
+        renewed, and their keepalives arrive right after the sweeper wakes.
+        Every lease gets a fresh TTL from the waking (as a new etcd leader
+        grants); a holder that is really gone expires one TTL later."""
         try:
+            woke = time.monotonic()
             while not self._closed:
                 await asyncio.sleep(SWEEP_INTERVAL)
                 now = time.monotonic()
+                overrun, woke = now - woke - SWEEP_INTERVAL, now
+                if overrun > SWEEP_INTERVAL:
+                    logger.warning("hub did not run for %.1f s: every lease "
+                                   "gets a fresh TTL", overrun)
+                    for lease in self._leases.values():
+                        lease.deadline = max(lease.deadline, now + lease.ttl)
                 expired = [l.id for l in self._leases.values() if l.deadline < now]
                 for lid in expired:
                     logger.info("lease %x expired", lid)

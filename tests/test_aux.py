@@ -279,3 +279,47 @@ def test_gauge_scrape_callbacks_with_labels():
     assert 'dynamo_engine_step_mean_ms{kind="prefill"} 230.0' in out
     state["decode"] = 99.0  # live: re-evaluated per scrape
     assert 'kind="decode"} 99.0' in m.render()
+
+
+def test_settle_heap_sets_the_built_worker_aside_from_collection():
+    """What lives as long as the worker (the step programs' jaxprs) is not
+    walked by later full collections: they stalled every stream ~250 ms."""
+    import gc
+
+    from dynamo_tpu.engine.main import settle_heap
+
+    from dynamo_tpu.engine import main as M
+
+    built = [[i] for i in range(1000)]
+    before = gc.get_threshold()
+    try:
+        assert settle_heap() == gc.get_freeze_count() >= 1001
+        assert not any(o is built for o in gc.get_objects())
+        later = [[0]]
+        assert any(o is later for o in gc.get_objects())
+        # young collections as before, the oldest generation rarely
+        assert gc.get_threshold() == (*before[:2], M.FULL_COLLECTION_EVERY)
+        settle_heap()
+        assert gc.callbacks.count(M._note_collection) == 1
+    finally:
+        gc.unfreeze()
+        gc.set_threshold(*before)
+        gc.callbacks.remove(M._note_collection)
+    assert any(o is built for o in gc.get_objects())
+
+
+def test_a_collection_that_stops_the_worker_is_logged(caplog, monkeypatch):
+    import types
+
+    from dynamo_tpu.engine import main as M
+
+    clock = iter([10.0, 10.005, 20.0, 20.120])
+    monkeypatch.setattr(M, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(clock)))
+    with caplog.at_level("WARNING", logger="dynamo.engine.main"):
+        for _ in range(2):
+            M._note_collection("start", {"generation": 2})
+            M._note_collection("stop", {"generation": 2, "collected": 7})
+    assert [r.getMessage() for r in caplog.records] == [
+        "garbage collection of generation 2 stopped the worker for 120 ms "
+        "(7 collected)"]

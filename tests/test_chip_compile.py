@@ -27,6 +27,10 @@ from jax.sharding import SingleDeviceSharding
 #: (H, KV, hd) of the models the dense cells will use
 MISTRAL_7B = (32, 8, 128)
 QWEN2_7B = (28, 4, 128)
+#: MiMo-V2.5's two layer kinds: (H, KV, hd of q, lane rows of a stored K
+#: head, V width)
+MIMO_WINDOW = (64, 8, 192, 2, 128)
+MIMO_FULL = (64, 4, 192, 2, 128)
 #: (T, R, W) of a decode-heavy step and of a mixed prefill+decode step at
 #: the engine's defaults (max_num_seqs 64, 2048 tokens, 4096 context)
 DECODE_HEAVY = (64, 64, 256)
@@ -125,6 +129,50 @@ def test_ragged_kernel_compiles_for_v5e(compile_for_chip, pages, widths, trw):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("trw", [(64, 64, 2176), (2048, 64, 2176)],
+                         ids=["decode_heavy", "mixed"])
+@pytest.mark.parametrize("widths", [MIMO_WINDOW, MIMO_FULL],
+                         ids=["mimo_window", "mimo_full"])
+def test_ragged_kernel_compiles_for_v5e_k_wider_than_v(compile_for_chip,
+                                                       widths, trw):
+    """Both of MiMo-V2.5's layer kinds (G = 8 and 16), 192-wide q against
+    K heads stored as two 128-lane rows and 128-wide V rows, at the cell's
+    step shapes (64 rows, 34,816-token tables: 128 rows of that width
+    do not fit the 1 MB of scalar memory the block table is prefetched
+    into), sink on."""
+    from dynamo_tpu.ops.ragged_attention import ragged_paged_attention
+
+    (H, KV, hd, k_rows, vd), (T, R, W) = widths, trw
+    slots = 4096 * BS
+    text = compile_for_chip(
+        lambda q, k, v, bt, r3, w, s: ragged_paged_attention(
+            q, k, v, bt, r3, block_size=BS, window=w, sinks=s),
+        spec((T, H, hd), jnp.bfloat16),
+        spec((slots, KV * k_rows, 128), jnp.bfloat16),
+        spec((slots, KV, vd), jnp.bfloat16), spec((R, W), jnp.int32),
+        spec((R, 3), jnp.int32), spec((), jnp.int32),
+        spec((H,), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("shape", [(4096, 2048), (2048, 4096)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmul_compiles_for_v5e(compile_for_chip, shape):
+    """The held-experts layer's product at MiMo-V2.5's widths: 16 experts,
+    the dropless buffer of a 2,048-token step, a traced count of tiles."""
+    from dynamo_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+
+    (k, n), E = shape, 16
+    tiles = 2048 * 8 // ROW_TILE + E
+    with mock.patch("dynamo_tpu.ops.grouped_matmul.kernel_interpret_mode",
+                    return_value=False):
+        text = compile_for_chip(
+            grouped_matmul, spec((tiles * ROW_TILE, k), jnp.bfloat16),
+            spec((5, E, k, n), jnp.bfloat16), spec((tiles,), jnp.int32),
+            spec((), jnp.int32), spec((), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("pages", ["bf16", "int8"])
 def test_flash_prefill_compiles_for_v5e(compile_for_chip, pages):
     from dynamo_tpu.ops.flash_prefill import flash_prefill_paged
@@ -192,6 +240,49 @@ def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv):
     copies = [ln for ln in text.splitlines()
               if " copy(" in ln and pool in ln.split(" copy(")[0]]
     assert not copies, copies[:2]
+
+
+@pytest.mark.parametrize("T", [64, 2048], ids=["decode_heavy", "mixed"])
+def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
+    """The whole jitted ragged step of the MiMo-V2.5 share the benchmark
+    runs (models.mimo_v25_ep16: all 7 layers, published widths, both cache
+    groups, the held-experts layer): the ragged kernel for both layer kinds
+    and the grouped matmul are Mosaic calls, and neither pool is copied."""
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.models import mimo_v25_ep16
+
+    cfg = mimo_v25_ep16()
+    args = EngineArgs(max_num_seqs=64, max_num_batched_tokens=2048,
+                      max_model_len=34816)
+    nb = 4096
+    R, W = args.ragged_rows(T), args.max_blocks_per_seq
+    C, _ = M.ragged_grid_shape(T)
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.key(0)))
+    groups = cfg.kv_cache_spec
+    kc = tuple(spec((len(g.layers), nb * BS, *g.k_shape), jnp.bfloat16)
+               for g in groups)
+    vc = tuple(spec((len(g.layers), nb * BS, g.kv_heads, g.v_dim),
+                    jnp.bfloat16) for g in groups)
+    step = M.make_ragged_step_fn(cfg, BS, None, use_pallas=True,
+                                 chunks=T > 64)
+    with mock.patch("dynamo_tpu.ops.grouped_matmul.kernel_interpret_mode",
+                    return_value=False):
+        text = compile_for_chip(
+            step, params, spec((5, T), jnp.int32), spec((R, 3), jnp.int32),
+            spec((C,), jnp.int32), spec((R, W), jnp.int32), kc, vc)
+    assert text.count("ragged_paged_attention") >= 2
+    assert "moe_grouped_matmul" in text
+    # no layer's experts are sliced out of their stack (16 x 4096 x 2048 =
+    # 268 MB a matrix, copied before every launch if they were)
+    sliced = [ln for ln in text.splitlines()
+              if " = bf16[16,4096,2048]" in ln or " = bf16[16,2048,4096]" in ln]
+    assert not sliced, sliced[:2]
+    for g in groups:
+        pool = f"[{len(g.layers)},{nb * BS},{g.k_shape[0]},128]"
+        copies = [ln for ln in text.splitlines()
+                  if " copy(" in ln and pool in ln.split(" copy(")[0]]
+        assert not copies, copies[:2]
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])

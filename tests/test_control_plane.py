@@ -137,6 +137,24 @@ async def test_lease_expiry_local():
     await plane.close()
 
 
+async def test_a_frozen_hub_does_not_expire_the_leases_it_could_not_renew():
+    """A host that freezes past the TTL stops holders and hub alike; the
+    sweeper wakes first, and must leave the holders time to renew."""
+    import time
+
+    plane = LocalControlPlane()
+    lease = await plane.lease_create(ttl=0.5)
+    await plane.kv_put("frozen/a", b"1", lease_id=lease)
+    await asyncio.sleep(0.1)          # the sweeper is asleep in its interval
+    time.sleep(2.5)                   # nothing runs: not the hub, not us
+    await asyncio.sleep(0.05)         # the sweeper wakes before we renew
+    assert await plane.lease_keepalive(lease) is True
+    assert await plane.kv_get("frozen/a") == b"1"
+    await asyncio.sleep(1.7)          # and a holder that stays away expires
+    assert await plane.kv_get("frozen/a") is None
+    await plane.close()
+
+
 async def test_remote_disconnect_revokes_lease():
     server = ControlPlaneServer()
     addr = await server.start()

@@ -80,7 +80,9 @@ def test_allocate_int8_cache_shapes():
 def test_hbm_sizing_int8_roughly_doubles():
     cfg = ModelConfig.llama3_1b()
     # fake free memory via the math itself: compare per-block byte formulas
-    (kh, kd), (vh, vd) = cfg.kv_cache_spec
+    group, = cfg.kv_cache_spec
+    kh = vh = group.kv_heads
+    kd, vd = group.k_dim, group.v_dim
     bf16 = cfg.num_layers * 16 * (kh * kd + vh * vd) * 2
     int8 = cfg.num_layers * 16 * (kh * (kd + 4) + vh * (vd + 4))
     assert 1.8 < bf16 / int8 < 2.0

@@ -372,9 +372,9 @@ async def test_gang_create_all_or_nothing_and_scale_down():
         await _wait(status_ready, msg="both gangs ready")
 
         # scale down 2 -> 1: the NEWEST whole gang goes, none of gang 0
-        cur = await crs.get("mh")
-        cur["spec"]["services"]["worker"]["replicas"] = 1
-        await crs.replace("mh", cur)
+        def one_gang(cur):
+            cur["spec"]["services"]["worker"]["replicas"] = 1
+        await _mutate_cr(crs, "mh", one_gang)
         items = await settled(3)
         assert {p["metadata"]["name"] for p in items} == {
             "mh-worker-0-0", "mh-worker-0-1", "mh-worker-0-2"}
@@ -523,10 +523,10 @@ async def test_single_to_multinode_migration_replaces_legacy_pods():
         await _wait(lambda: names_are(["mig-worker-0", "mig-worker-1"]),
                     msg="single-node pods")
 
-        cur = await crs.get("mig")
-        cur["spec"]["services"]["worker"] = {
-            "replicas": 1, "multinode": 2, "command": ["w"]}
-        await crs.replace("mig", cur)
+        def to_multinode(cur):
+            cur["spec"]["services"]["worker"] = {
+                "replicas": 1, "multinode": 2, "command": ["w"]}
+        await _mutate_cr(crs, "mig", to_multinode)
         await _wait(lambda: names_are(["mig-worker-0-0", "mig-worker-0-1"]),
                     timeout=10.0, msg="gangs replace legacy pods")
     finally:

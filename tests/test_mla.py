@@ -197,7 +197,7 @@ def test_deepseek_presets_resolve():
     assert v3.is_mla and v3.num_experts == 256 and v3.first_k_dense_replace == 3
     lite = get_model_config("deepseek_v2_lite")
     assert lite.is_mla and lite.q_lora_rank is None
-    assert lite.kv_cache_spec == ((1, 512), (1, 128))  # rope 64 lane-padded
+    assert lite.kv_cache_spec[0][1:4] == (1, 512, 128)  # rope 64 lane-padded
 
 
 def test_mla_ragged_packed_matches_bucketed():

@@ -127,6 +127,36 @@ def test_ragged_kernel_matches_xla(name):
                                atol=2e-5, rtol=2e-5)
 
 
+@pytest.mark.parametrize("kv,window,sinks,dtype", [
+    (2, 128, True, jnp.float32), (1, None, False, jnp.float32),
+    (2, 128, True, jnp.bfloat16)], ids=["g8_window_sink", "g16_full",
+                                        "g8_bf16"])
+def test_ragged_kernel_wide_k_heads_as_lane_rows(kv, window, sinks, dtype):
+    """MiMo-V2's widths: a 192-wide q against K heads stored as two
+    128-lane rows (zeros past 192) and 128-wide V heads, G = 8 and 16 (the
+    wide tile is 128 and 64 tokens)."""
+    H, hd, rows = 16, 192, [(1, 300), (200, 260), (9, 9), (1, 40)]
+    need = sum(-(-kl // 8) for _, kl in rows) + 2
+    q, kc, vc, bt, rows3, t = make_ragged_case(
+        jax.random.key(4), rows, H=H, KV=kv, num_blocks=need, W=38,
+        pad_rows=2, pad_tokens=3)
+    ks = jax.random.split(jax.random.key(6), 2)
+    q = jax.random.normal(ks[0], (q.shape[0], H, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (kc.shape[0], kv, hd), jnp.float32)
+    kc = jnp.pad(k, ((0, 0), (0, 0), (0, 256 - hd))).reshape(-1, 2 * kv, 128)
+    q, kc, vc = (a.astype(dtype) for a in (q, kc, vc))
+    sk = (jax.random.normal(jax.random.key(5), (H,), jnp.float32)
+          if sinks else None)
+    kw = dict(block_size=8, window=window, sinks=sk)
+    want = ragged_attention_xla(q, kc, vc, bt, rows3, **kw)
+    got = ragged_paged_attention(q, kc, vc, bt, rows3, interpret=True, **kw)
+    assert got.shape == (q.shape[0], H, 128)
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:t],
+                               np.asarray(want, np.float32)[:t],
+                               atol=tol, rtol=tol)
+
+
 @pytest.mark.parametrize("KV", [1, 2, 8])
 def test_ragged_kernel_bf16_pages_read_as_words(KV):
     """bf16 pages and queries: a head's rows come out of 32-bit words (two
