@@ -3,8 +3,10 @@
 The reference resolves model artifacts from the HF hub into its engines
 (ref: lib/llm/src/local_model.rs:1-456, hub.rs); here the weights land
 directly in the JAX param layout of model.py (layers stacked on a leading L
-axis for lax.scan; projection matrices stored [in, out] so the forward pass
-is x @ W with no transposes at trace time).
+axis for lax.scan; matrices stored [in, out] so the forward pass is x @ W
+with no transposes at trace time — but the attention projections wq/wk/wv,
+which keep HF's [out, in] cut into heads, [heads, width, in]: the layout
+their dot reads, model.py's pytree comment).
 
 Supported families: llama/mistral/qwen2 (dense), mixtral (MoE,
 block_sparse_moe names), deepseek V2/V3 (MLA + MoE with shared experts and
@@ -152,6 +154,9 @@ def load_hf_params(cfg: ModelConfig, path: str, dtype=None) -> dict:
     def proj(name):  # HF stores [out, in] → we want [in, out]
         return get(name).T
 
+    def by_heads(w, heads):  # HF's [heads·width, in] → [heads, width, in]
+        return w.reshape(heads, -1, w.shape[-1])
+
     L = cfg.num_layers
     from dynamo_tpu.engine.quant import stack_layers as stack
 
@@ -161,22 +166,18 @@ def load_hf_params(cfg: ModelConfig, path: str, dtype=None) -> dict:
             if f"{pre}.qkv_proj.weight" in t:
                 # Phi-3/Phi-4 fuse q|k|v rows into one projection; split at
                 # the head boundaries (rows are [H·hd | KV·hd | KV·hd])
-                qkv = proj(f"{pre}.qkv_proj.weight")  # [D, (H+2KV)·hd]
+                qkv = get(f"{pre}.qkv_proj.weight")  # [(H+2KV)·hd, D]
                 nq = cfg.num_heads * cfg.head_dim
                 nkv = cfg.num_kv_heads * cfg.head_dim
-                out = {
-                    "wq": qkv[:, :nq],
-                    "wk": qkv[:, nq:nq + nkv],
-                    "wv": qkv[:, nq + nkv:nq + 2 * nkv],
-                    "wo": proj(f"{pre}.o_proj.weight"),
-                }
+                qkv = (qkv[:nq], qkv[nq:nq + nkv], qkv[nq + nkv:nq + 2 * nkv])
             else:
-                out = {
-                    "wq": proj(f"{pre}.q_proj.weight"),
-                    "wk": proj(f"{pre}.k_proj.weight"),
-                    "wv": proj(f"{pre}.v_proj.weight"),
-                    "wo": proj(f"{pre}.o_proj.weight"),
-                }
+                qkv = [get(f"{pre}.{n}_proj.weight") for n in "qkv"]
+            out = {
+                "wq": by_heads(qkv[0], cfg.num_heads),
+                "wk": by_heads(qkv[1], cfg.num_kv_heads),
+                "wv": by_heads(qkv[2], cfg.num_kv_heads),
+                "wo": proj(f"{pre}.o_proj.weight"),
+            }
             if cfg.qkv_bias:
                 out["bq"] = get(f"{pre}.q_proj.bias")
                 out["bk"] = get(f"{pre}.k_proj.bias")
@@ -221,7 +222,7 @@ def load_hf_params(cfg: ModelConfig, path: str, dtype=None) -> dict:
             out["q_a_norm"] = get(f"{pre}.q_a_layernorm.weight")
             out["q_b"] = jnp.asarray(q_w, dtype=dtype).T
         else:
-            out["wq"] = jnp.asarray(q_w, dtype=dtype).T
+            out["wq"] = by_heads(jnp.asarray(q_w, dtype=dtype), H)
         return out
 
     def dense_mlp_layer(i: int) -> dict:

@@ -38,6 +38,7 @@ from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.quant import is_qtensor as _is_q
 from dynamo_tpu.engine.quant import materialize as _qmat
 from dynamo_tpu.engine.quant import qmm as _mm
+from dynamo_tpu.engine.quant import qmm_heads as _mm_heads
 
 # ---------------------------------------------------------------------------
 # Parameter init / pytree layout
@@ -47,8 +48,8 @@ from dynamo_tpu.engine.quant import qmm as _mm
 #   "embed":    [V, D]
 #   "layers": {                       (stacked on leading L axis)
 #     "attn_norm": [L, D], "mlp_norm": [L, D],
-#     "wq": [L, D, H*hd], "wk": [L, D, KV*hd], "wv": [L, D, KV*hd],
-#     "wo": [L, H*hd, D],
+#     "wq": [L, H, hd, D], "wk": [L, KV, hd, D], "wv": [L, KV, vd, D],
+#     "wo": [L, H*vd, D],
 #     dense:  "w_gate": [L, D, F], "w_up": [L, D, F], "w_down": [L, F, D]
 #     moe:    "router": [L, D, E], "w_gate": [L, E, D, F], "w_up": [L, E, D, F],
 #             "w_down": [L, E, F, D]
@@ -56,6 +57,16 @@ from dynamo_tpu.engine.quant import qmm as _mm
 #   },
 #   "final_norm": [D], "lm_head": [D, V] (absent when tied)
 # }
+#
+# Every matmul weight lies [..., in, out] and is read as x @ W, but the
+# attention projections wq/wk/wv (MLA's wq too): they lie head-major with the
+# contraction LAST and are read by _mm_heads. A projection that produces
+# [T, heads, hd] is compiled to a dot that wants its weight [heads, hd, D]
+# with D minor; stored [D, heads*hd], every layer of every step sliced its
+# matrix out of the stack and transposed it in HBM before the dot ran
+# (16% of Mistral-7B's device time, 44% of MiMo-V2.5's: PERF.md section 6,
+# PR 32). Stored as the dot reads it, the scan's slice is an operand of the
+# dot, as wo's and the MLP's are (tests/test_chip_compile.py asserts it).
 
 
 @dataclasses.dataclass(frozen=True)
@@ -68,8 +79,25 @@ class _LazyLeaf:
     key: Optional[jax.Array]  # None: a constant leaf filled with ``scale``
     scale: float  # normal leaves: the std divisor sqrt(fan_in)
     quant: Optional[tuple] = None  # (bits, group) → built as a QTensor
+    #: an attention projection, ``shape`` [n, heads, width, D] with the
+    #: contraction last: built as the matrix [n, D, heads·width] of x @ W
+    #: by the program that builds any other leaf, then turned by a second
+    #: one (:func:`_turn_program`). The values of an init, and a quantized
+    #: leaf's rounding, so depend on the key alone and not on the stored
+    #: layout: one program that draws, quantizes and turns may be fused
+    #: otherwise and round otherwise, and the benchmark's probe, greedy over
+    #: the nearly flat logits of random weights, then takes another path
+    #: (PERF.md section 7)
+    head_major: bool = False
 
     def build(self, sharding=None):
+        if self.head_major:
+            n, heads, width, d = self.shape
+            drawn = None if sharding is None else NamedSharding(
+                sharding.mesh, P(sharding.spec[0], None, sharding.spec[1]))
+            flat = dataclasses.replace(self, shape=(n, d, heads * width),
+                                       head_major=False)
+            return _turn_program(heads, sharding)(flat.build(drawn))
         if self.quant is not None and sharding is not None:
             from dynamo_tpu.engine.quant import qtensor_shardings
 
@@ -107,6 +135,20 @@ def _leaf_program(shape, dtype, normal: bool, quant, sharding):
     return jax.jit(make, out_shardings=sharding)
 
 
+@functools.lru_cache(maxsize=None)
+def _turn_program(heads: int, sharding):
+    """[n, D, heads·width] (a QTensor: its scales [n, G, heads·width] too)
+    → the head-major [n, heads, width, D]: the same numbers, moved. One
+    sharding places a QTensor's fields alike (the scales' grouped axis,
+    now the last, is replicated in it)."""
+    def turn(w):
+        return jax.tree.map(
+            lambda a: a.swapaxes(1, 2).reshape(a.shape[0], heads, -1,
+                                               a.shape[1]), w)
+
+    return jax.jit(turn, out_shardings=sharding)
+
+
 def _normal_leaf(key, shape, fan_in, dtype) -> _LazyLeaf:
     return _LazyLeaf(shape, jnp.dtype(dtype), key, np.sqrt(fan_in))
 
@@ -132,6 +174,10 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
     ones = functools.partial(_const_leaf, 1.0, dtype=dtype)
     zeros = functools.partial(_const_leaf, 0.0, dtype=dtype)
 
+    def by_heads(key, heads, width):
+        return dataclasses.replace(w(key, (n, heads, width, D), D),
+                                   head_major=True)
+
     layers = {
         "attn_norm": ones((n, D)),
         "mlp_norm": ones((n, D)),
@@ -148,16 +194,16 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
             layers["q_a_norm"] = ones((n, qr))
             layers["q_b"] = w(ks[10], (n, qr, H * (dn + dr)), qr)
         else:
-            layers["wq"] = w(ks[0], (n, D, H * (dn + dr)), D)
+            layers["wq"] = by_heads(ks[0], H, dn + dr)
         layers["kv_a"] = w(ks[1], (n, D, r + dr), D)
         layers["kv_a_norm"] = ones((n, r))
         layers["w_uk"] = w(ks[2], (n, r, H * dn), r)
         layers["w_uv"] = w(ks[11], (n, r, H * dv), r)
         layers["wo"] = w(ks[3], (n, H * dv, D), H * dv)
     else:
-        layers["wq"] = w(ks[0], (n, D, H * hd), D)
-        layers["wk"] = w(ks[1], (n, D, KV * hd), D)
-        layers["wv"] = w(ks[2], (n, D, KV * vd), D)
+        layers["wq"] = by_heads(ks[0], H, hd)
+        layers["wk"] = by_heads(ks[1], KV, hd)
+        layers["wv"] = by_heads(ks[2], KV, vd)
         layers["wo"] = w(ks[3], (n, H * vd, D), H * vd)
         if cfg.qkv_bias:
             layers["bq"] = zeros((n, H * hd))
@@ -282,7 +328,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None, *,
         bits, group = parse_spec(quantization)
         lazy = quant_walk(
             lazy, bits, group,
-            lambda v, g: dataclasses.replace(v, quant=(bits, g)))
+            lambda v, g, _axis: dataclasses.replace(v, quant=(bits, g)))
 
     is_lazy = lambda x: isinstance(x, _LazyLeaf)  # noqa: E731
     if mesh is None:
@@ -311,12 +357,21 @@ def mla_tpla_shards(cfg: Optional[ModelConfig], mesh: Optional[Mesh]) -> int:
 
 
 def _layer_stack_shardings(cfg: ModelConfig, mesh: Mesh, moe: bool,
-                           stack_axis=None, sink=None) -> dict:
+                           stack_axis=None, kind=None) -> dict:
     """``stack_axis``: mesh axis for the stacked-layer leading dim — "pp"
     when pipeline stages each hold a slice of the stack (pipeline.py),
-    None (replicated) otherwise."""
+    None (replicated) otherwise. ``kind``: the stack's :class:`LayerKind`
+    where the model has kinds."""
+    kind = kind or cfg.layer_kind(0)
+    tp = mesh.shape.get("tp", 1)
+
     def ns(*spec):
         return NamedSharding(mesh, P(stack_axis, *spec[1:]))
+
+    def by_heads(n):
+        # a head-major projection [L, heads, width, D] shards whole heads
+        # (fewer heads than tp ranks, tiny test models: replicated)
+        return ns(None, "tp" if n % tp == 0 else None, None, None)
 
     layers = {
         "attn_norm": ns(None, None),
@@ -333,7 +388,7 @@ def _layer_stack_shardings(cfg: ModelConfig, mesh: Mesh, moe: bool,
             layers["q_a_norm"] = ns(None, None)
             layers["q_b"] = ns(None, None, "tp")
         else:
-            layers["wq"] = ns(None, None, "tp")
+            layers["wq"] = by_heads(cfg.num_heads)
         layers["kv_a"] = ns(None, None, None)
         layers["kv_a_norm"] = ns(None, None)
         if mla_tpla_shards(cfg, mesh) > 1:
@@ -347,9 +402,9 @@ def _layer_stack_shardings(cfg: ModelConfig, mesh: Mesh, moe: bool,
             layers["w_uv"] = ns(None, None, "tp")
         layers["wo"] = ns(None, "tp", None)
     else:
-        layers["wq"] = ns(None, None, "tp")
-        layers["wk"] = ns(None, None, "tp")
-        layers["wv"] = ns(None, None, "tp")
+        layers["wq"] = by_heads(cfg.num_heads)
+        layers["wk"] = by_heads(kind.num_kv_heads)
+        layers["wv"] = by_heads(kind.num_kv_heads)
         layers["wo"] = ns(None, "tp", None)
         if cfg.qkv_bias:
             layers["bq"] = ns(None, "tp")
@@ -360,7 +415,7 @@ def _layer_stack_shardings(cfg: ModelConfig, mesh: Mesh, moe: bool,
             layers["k_norm"] = ns(None, None)
         if cfg.o_bias:
             layers["bo"] = ns(None, None)
-        if cfg.attention_sinks if sink is None else sink:
+        if kind.sink:
             layers["sink"] = ns(None, "tp")
     if moe:
         layers["router"] = ns(None, None, None)
@@ -401,7 +456,7 @@ def param_shardings(cfg: ModelConfig, mesh: Mesh) -> dict:
     if cfg.layer_kinds is not None:
         out["stacks"] = tuple(
             _layer_stack_shardings(cfg, mesh, st.moe,
-                                   sink=cfg.layer_kinds[st.kind].sink)
+                                   kind=cfg.layer_kinds[st.kind])
             for st in layer_stacks(cfg))
     else:
         out["layers"] = _layer_stack_shardings(cfg, mesh, cfg.is_moe,
@@ -481,6 +536,20 @@ def _rms_norm(x, w, eps):
         return (xn * w).astype(x.dtype)
     # HF Llama-style: x̂ cast back, then a same-dtype weight multiply
     return xn.astype(x.dtype) * w
+
+
+def _qkv_heads(h, lp) -> list:
+    """The q, k and v of a layer's tokens ``h[..., D]``, each ``[..., heads,
+    width]``: a projection contracts ``h`` with the last axis of its
+    head-major weight (the pytree comment at the top says why), and a bias,
+    stored flat, is added by heads."""
+    out = []
+    for w, b in (("wq", "bq"), ("wk", "bk"), ("wv", "bv")):
+        y = _mm_heads(h, lp[w])
+        if b in lp:
+            y = y + lp[b].reshape(y.shape[-2:])
+        out.append(y)
+    return out
 
 
 def rope_params(theta: float, hd: int, scaling: Optional[dict]):
@@ -999,7 +1068,7 @@ def _mla_attention(h, lp, lidx, kc, vc, slot_map, block_tables, positions,
         q = _mm(_rms_norm(_mm(h, lp["q_a"]), lp["q_a_norm"],
                           cfg.rms_norm_eps), lp["q_b"])
     else:
-        q = _mm(h, lp["wq"])
+        q = _mm_heads(h, lp["wq"])
     q = q.reshape(B, S, H, dn + dr)
     q_nope, q_rot = q[..., :dn], q[..., dn:]
     q_rot = _rope(q_rot, positions, cfg.rope_theta, cfg.rope_scaling)
@@ -1645,16 +1714,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 mesh=mesh, ragged=ragged)
             x = x + _mm(attn_flat, lp["wo"])
             return _mlp_epilogue(x, kc, vc, st, lp, moe, experts, group)
-        q = _mm(h, lp["wq"])
-        k = _mm(h, lp["wk"])
-        v = _mm(h, lp["wv"])
-        if "bq" in lp:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = q.reshape(B, S, H, hd)
-        k = k.reshape(B, S, KV, hd)
-        v = v.reshape(B, S, KV, vd)
+        q, k, v = _qkv_heads(h, lp)  # [B, S, H | KV, hd | vd]
         if cfg.qk_norm:  # Qwen3: per-head RMSNorm before RoPE
             q = _rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = _rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)

@@ -405,14 +405,16 @@ class GGUFFile:
         buf = self._read(f, info.offset, count * dtype.itemsize)
         return np.frombuffer(buf, dtype=dtype).reshape(info.shape)
 
-    def load_tensor_q8_native(self, name: str,
-                              f: Optional[BinaryIO] = None) -> Optional[dict]:
+    def load_tensor_q8_native(self, name: str, f: Optional[BinaryIO] = None,
+                              transpose: bool = True) -> Optional[dict]:
         """Q8_0 tensor as a grouped-int8 QTensor (engine/quant.py layout) —
         the weights NEVER widen past 1 B each: ggml's per-32 blocks map
         exactly onto {"q": int8 [in, out], "s": f32 [in/32, out]} (the
         stored layout is [out, in] row-major with blocks along the row, so
-        one transpose lands groups on the contraction dim). Returns None
-        for any other ggml type — callers fall back to ``load_tensor``."""
+        one transpose lands groups on the contraction dim;
+        ``transpose=False`` keeps [out, in] and [out, in/32], the
+        contraction last). Returns None for any other ggml type — callers
+        fall back to ``load_tensor``."""
         info = self.tensors[name]
         if info.ggml_type != GGML_Q8_0 or len(info.shape) != 2:
             return None
@@ -425,8 +427,10 @@ class GGUFFile:
             np.uint8).reshape(R * C // 32, 34)
         s = raw[:, :2].copy().view(np.float16).astype(np.float32)
         q = raw[:, 2:].view(np.int8)
-        return {"q": np.ascontiguousarray(q.reshape(R, C).T),
-                "s": np.ascontiguousarray(s.reshape(R, C // 32).T)}
+        q, s = q.reshape(R, C), s.reshape(R, C // 32)
+        if transpose:
+            q, s = q.T, s.T
+        return {"q": np.ascontiguousarray(q), "s": np.ascontiguousarray(s)}
 
     def _read(self, f: Optional[BinaryIO], offset: int, n: int) -> bytes:
         if f is None:
@@ -560,17 +564,22 @@ def load_gguf_params(g: GGUFFile, cfg, dtype=None) -> dict:
         def proj(name):  # stored [out, in] like HF → transpose to [in, out]
             return get(name).T
 
-        def proj_w(name):
+        def proj_w(name, heads=None):
             """Matmul weight: Q8_0 tensors stay QUANTIZED in HBM (grouped-
             int8 QTensor, bit-identical numerics via the f32 dequant chain
             in engine/quant.materialize); everything else dequantizes as
-            before. DYN_GGUF_DEQUANT=1 forces the legacy bf16 load."""
+            before. DYN_GGUF_DEQUANT=1 forces the legacy bf16 load.
+            ``heads``: an attention projection, which keeps the file's own
+            [out, in] cut into heads, [heads, width, in] (model.py's pytree
+            comment), the scales with it."""
+            def cut(a):
+                return a.reshape(heads, -1, a.shape[-1]) if heads else a
+
             if not os.environ.get("DYN_GGUF_DEQUANT"):
-                qt = g.load_tensor_q8_native(name, fh)
+                qt = g.load_tensor_q8_native(name, fh, transpose=not heads)
                 if qt is not None:
-                    return {"q": jnp.asarray(qt["q"]),
-                            "s": jnp.asarray(qt["s"])}
-            return proj(name)
+                    return {k: cut(jnp.asarray(v)) for k, v in qt.items()}
+            return cut(get(name) if heads else proj(name))
 
         L = cfg.num_layers
         from dynamo_tpu.engine.quant import stack_layers as stack
@@ -578,9 +587,12 @@ def load_gguf_params(g: GGUFFile, cfg, dtype=None) -> dict:
         layers = {
             "attn_norm": stack([get(f"blk.{i}.attn_norm.weight") for i in range(L)]),
             "mlp_norm": stack([get(f"blk.{i}.ffn_norm.weight") for i in range(L)]),
-            "wq": stack([proj_w(f"blk.{i}.attn_q.weight") for i in range(L)]),
-            "wk": stack([proj_w(f"blk.{i}.attn_k.weight") for i in range(L)]),
-            "wv": stack([proj_w(f"blk.{i}.attn_v.weight") for i in range(L)]),
+            "wq": stack([proj_w(f"blk.{i}.attn_q.weight", cfg.num_heads)
+                         for i in range(L)]),
+            "wk": stack([proj_w(f"blk.{i}.attn_k.weight", cfg.num_kv_heads)
+                         for i in range(L)]),
+            "wv": stack([proj_w(f"blk.{i}.attn_v.weight", cfg.num_kv_heads)
+                         for i in range(L)]),
             "wo": stack([proj_w(f"blk.{i}.attn_output.weight") for i in range(L)]),
             "w_gate": stack([proj_w(f"blk.{i}.ffn_gate.weight") for i in range(L)]),
             "w_up": stack([proj_w(f"blk.{i}.ffn_up.weight") for i in range(L)]),

@@ -17,11 +17,17 @@ def mimo_v2_inputs(cfg, params) -> tuple[dict, dict]:
     import jax
 
     from dynamo_tpu.engine.model import layer_stacks
+    from dynamo_tpu.engine.quant import HEAD_MAJOR_KEYS
 
     layers: list = [None] * cfg.num_layers
     for stack, lps in zip(layer_stacks(cfg), params["stacks"]):
         for j, i in enumerate(stack.layers):
             layers[i] = jax.tree.map(lambda a: a[j], lps)
+            for k in HEAD_MAJOR_KEYS:
+                # the engine keeps [heads, width, D]; the reference reads
+                # the published x @ W orientation, [D, heads·width]
+                w = layers[i][k]
+                layers[i][k] = w.reshape(-1, w.shape[-1]).T
     full, swa = cfg.layer_kinds
     hp = {
         "hidden_size": cfg.hidden_size,

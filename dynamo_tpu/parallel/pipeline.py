@@ -42,7 +42,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.model import (
-    _mlp_dense, _mm, _paged_attention, _ragged_attention, _rms_norm, _rope,
+    _mlp_dense, _mm, _paged_attention, _qkv_heads, _ragged_attention,
+    _rms_norm, _rope,
 )
 
 AXIS = "pp"
@@ -91,16 +92,7 @@ def _dense_layer(x, lp, lidx, glidx, kc, vc, slot_map, block_tables,
     B, S = positions.shape
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q = _mm(h, lp["wq"])
-    k = _mm(h, lp["wk"])
-    v = _mm(h, lp["wv"])
-    if "bq" in lp:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(B, S, H, hd)
-    k = k.reshape(B, S, KV, hd)
-    v = v.reshape(B, S, KV, hd)
+    q, k, v = _qkv_heads(h, lp)  # [B, S, H | KV, hd]
     if cfg.qk_norm:
         q = _rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
@@ -260,16 +252,7 @@ def _ragged_dense_layer(x, lp, lidx, glidx, kc, vc, slot_map, block_tables,
     T = x.shape[0]
     H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-    q = _mm(h, lp["wq"])
-    k = _mm(h, lp["wk"])
-    v = _mm(h, lp["wv"])
-    if "bq" in lp:
-        q = q + lp["bq"]
-        k = k + lp["bk"]
-        v = v + lp["bv"]
-    q = q.reshape(1, T, H, hd)
-    k = k.reshape(1, T, KV, hd)
-    v = v.reshape(1, T, KV, hd)
+    q, k, v = (y[None] for y in _qkv_heads(h, lp))  # [1, T, H | KV, hd]
     if cfg.qk_norm:
         q = _rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
         k = _rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)

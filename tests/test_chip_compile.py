@@ -17,6 +17,7 @@ one file. The persistent compilation cache is off around these compiles (an
 entry written for a described chip cannot be read back without one).
 """
 
+import re
 from unittest import mock
 
 import jax
@@ -94,6 +95,34 @@ def compile_for_chip(one_chip, no_compile_cache):
 
 def spec(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def lone_projection_weight_ops(text, dtype, projections):
+    """The ops of a compiled step that are launched on their own (they lie
+    outside every fused computation) and do nothing but move one layer's
+    attention projection weight: a ``copy`` or a ``fusion`` whose result is
+    a ``[1, ...]`` slice of a stack with the dims of one of ``projections``
+    (heads, width, D) in any order, heads and width merged or apart. Stored
+    ``[L, D, heads·width]`` every layer of every step paid a slice and a
+    transposing copy of each before its dot (PERF.md, PR 32); stored as the
+    dot reads them there is none, the slice being an operand of the dot."""
+    want = set()
+    for heads, width, d in projections:
+        want |= {tuple(sorted((heads, width, d))),
+                 tuple(sorted((heads * width, d)))}
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    found, inside = [], False
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            inside = head.group(1) in fused
+            continue
+        op = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = (\w+)\[1,([\d,]+)\]\S* "
+                      r"(?:copy|fusion)\(", ln)
+        if (op and not inside and op.group(1) == dtype
+                and tuple(sorted(map(int, op.group(2).split(",")))) in want):
+            found.append(ln.strip()[:120])
+    return found
 
 
 @pytest.mark.parametrize("trw", [DECODE_HEAVY, MIXED],
@@ -206,12 +235,14 @@ def test_gqa_decode_kernel_compiles_for_v5e(compile_for_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("T", [8, 256], ids=["decode_only", "mixed"])
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv):
+def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv, T):
     """The whole jitted ragged step — layer scan, int8 weights, the kernel
     inside — at Mistral-7B widths (depth cut to 2: the scan makes the
-    program the same modulo the leading L), and the cache must pass into
-    the kernel without a relayout copy of the pool."""
+    program the same modulo the leading L): the cache must pass into the
+    kernel without a relayout copy of the pool, and no op but its own dot
+    reads a layer's q, k or v projection weight."""
     import dataclasses
 
     from dynamo_tpu.engine import model as M
@@ -220,7 +251,7 @@ def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv):
 
     cfg = dataclasses.replace(mistral_7b(), num_layers=2)
     args = EngineArgs()
-    T, nb = 256, 2048
+    nb = 2048
     R, W = args.ragged_rows(T), args.max_blocks_per_seq
     C, _ = M.ragged_grid_shape(T)
     params = jax.eval_shape(lambda: M.init_params(
@@ -229,7 +260,7 @@ def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv):
     cache = (spec(shape, jnp.bfloat16) if kv == "bf16" else
              {"q": spec(shape, jnp.int8), "s": spec(shape[:-1], jnp.float32)})
     step = M.make_ragged_step_fn(cfg, BS, None, use_pallas=True,
-                                 kv_quant=kv == "int8")
+                                 kv_quant=kv == "int8", chunks=T > 8)
     text = compile_for_chip(
         step, params, spec((5, T), jnp.int32), spec((R, 3), jnp.int32),
         spec((C,), jnp.int32), spec((R, W), jnp.int32), cache, cache)
@@ -240,6 +271,10 @@ def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv):
     copies = [ln for ln in text.splitlines()
               if " copy(" in ln and pool in ln.split(" copy(")[0]]
     assert not copies, copies[:2]
+    H, KV, hd = MISTRAL_7B
+    moved = lone_projection_weight_ops(
+        text, "s8", [(H, hd, cfg.hidden_size), (KV, hd, cfg.hidden_size)])
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("T", [64, 2048], ids=["decode_heavy", "mixed"])
@@ -247,7 +282,9 @@ def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
     """The whole jitted ragged step of the MiMo-V2.5 share the benchmark
     runs (models.mimo_v25_ep16: all 7 layers, published widths, both cache
     groups, the held-experts layer): the ragged kernel for both layer kinds
-    and the grouped matmul are Mosaic calls, and neither pool is copied."""
+    and the grouped matmul are Mosaic calls, neither pool is copied, and no
+    op but its own dot reads a layer's q, k or v projection weight (the two
+    one-layer stacks' included)."""
     from dynamo_tpu.engine import model as M
     from dynamo_tpu.engine.config import EngineArgs
     from dynamo_tpu.models import mimo_v25_ep16
@@ -283,6 +320,13 @@ def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
         copies = [ln for ln in text.splitlines()
                   if " copy(" in ln and pool in ln.split(" copy(")[0]]
         assert not copies, copies[:2]
+    D = cfg.hidden_size
+    moved = lone_projection_weight_ops(
+        text, "bf16",
+        [(cfg.num_heads, cfg.head_dim, D)]
+        + [(k.num_kv_heads, w, D) for k in cfg.layer_kinds
+           for w in (cfg.head_dim, cfg.v_dim)])
+    assert not moved, moved
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])

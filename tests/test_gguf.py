@@ -54,11 +54,13 @@ _TOKENS = ["<unk>", "<s>", "</s>", "a", "b", "c", "h", "i", "Ġ", "hi", "Ġhi",
 _MERGES = ["h i", "Ġ hi", "a b", "ab c"]
 
 
-def write_tiny_gguf(path: str, seed: int = 0) -> dict:
+def write_tiny_gguf(path: str, seed: int = 0, D: int = 16) -> dict:
     """Valid GGUF v3 file: llama arch metadata + gpt2 tokenizer + f32
-    weights in llama.cpp tensor naming. Returns the tensor dict."""
+    weights in llama.cpp tensor naming. Returns the tensor dict. ``D``:
+    the hidden size (a multiple of 32 lets q8_0 take the attention
+    projections too)."""
     rng = np.random.default_rng(seed)
-    D, F, L, H, KV, V = 16, 32, 2, 4, 2, len(_TOKENS)
+    F, L, H, KV, V = 32, 2, 4, 2, len(_TOKENS)
     hd = D // H
 
     tensors: dict[str, np.ndarray] = {
@@ -400,6 +402,71 @@ def test_quantized_gguf_serves(tmp_path):
     finally:
         del os.environ["DYN_GGUF_DEQUANT"]
     np.testing.assert_array_equal(w, np.asarray(legacy["layers"]["w_down"][0]))
+
+
+@pytest.mark.parametrize("legacy", [False, True],
+                         ids=["q8_native", "dequantized"])
+def test_gguf_attention_projections_load_head_major(tmp_path, monkeypatch,
+                                                    legacy):
+    """wq/wk/wv keep the file's own [out, in] rows cut into heads, [L,
+    heads, hd, D] — as resident Q8_0 QTensors (ggml's blocks of 32 along D:
+    scales [L, heads, hd, D/32], the contraction last) and through the
+    dequantize-at-load path — bit-identical to each other, and the logits
+    of a prompt are the same either way."""
+    import jax.numpy as jnp
+
+    from dynamo_tpu.engine import quant as Q
+    from dynamo_tpu.engine.model import forward
+    from dynamo_tpu.llm.gguf import (
+        GGUFFile, config_from_gguf, load_gguf_params,
+    )
+
+    f32 = str(tmp_path / "f32.gguf")
+    tensors = write_tiny_gguf(f32, D=64)
+    qpath = str(tmp_path / "q8.gguf")
+    write_q8_gguf(f32, qpath, tensors)
+    g = GGUFFile.parse(qpath)
+    cfg = config_from_gguf(g)
+    cfg.dtype = "float32"
+    monkeypatch.delenv("DYN_GGUF_DEQUANT", raising=False)
+    native = load_gguf_params(g, cfg, dtype=jnp.float32)
+    monkeypatch.setenv("DYN_GGUF_DEQUANT", "1")
+    dequant = load_gguf_params(g, cfg, dtype=jnp.float32)
+    params = dequant if legacy else native
+    L, H, KV, hd, D = 2, 4, 2, 16, 64
+    for name, heads, gg in (("wq", H, "attn_q"), ("wk", KV, "attn_k"),
+                            ("wv", KV, "attn_v")):
+        node = params["layers"][name]
+        if legacy:
+            assert node.shape == (L, heads, hd, D)
+        else:
+            assert node["q"].dtype == jnp.int8
+            assert node["q"].shape == (L, heads, hd, D)
+            assert node["s"].shape == (L, heads, hd, D // 32)
+        w = np.asarray(Q.dequantize(node, jnp.float32, axis=-1)
+                       if Q.is_qtensor(node) else node)
+        np.testing.assert_array_equal(
+            w, np.asarray(dequant["layers"][name]))
+        for i in range(L):
+            ref = tensors[f"blk.{i}.{gg}.weight"].reshape(heads, hd, D)
+            np.testing.assert_allclose(w[i], ref, atol=0.01)
+    wo = native["layers"]["wo"]  # every other weight: [in, out] as before
+    assert wo["q"].shape == (L, D, D) and wo["s"].shape == (L, D // 32, D)
+
+    def logits(p):
+        n, bs = 6, 4
+        kc = jnp.zeros((L, 4 * bs, KV, hd), jnp.float32)
+        bt = jnp.arange(1, 4)[None, :]
+        pos = np.arange(n)
+        out, _, _ = forward(
+            p, jnp.asarray([[3, 5, 7, 9, 11, 13]]), jnp.asarray([pos]),
+            (bt[0, pos // bs] * bs + pos % bs)[None, :], bt,
+            jnp.asarray([n]), jnp.asarray([n - 1]), kc, kc, cfg=cfg,
+            block_size=bs)
+        return np.asarray(out)
+
+    np.testing.assert_allclose(logits(native), logits(dequant),
+                               rtol=1e-5, atol=1e-6)
 
 
 def g0_meta_end(path):

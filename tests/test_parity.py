@@ -273,3 +273,43 @@ def test_phi3_sliding_window_parity(tmp_path):
         sliding_window=8, pad_token_id=0, tie_word_embeddings=False,
         attn_implementation="eager")
     _check_parity(transformers.Phi3ForCausalLM, hf_cfg, tmp_path)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["split", "fused_qkv"])
+def test_loader_keeps_projection_rows_cut_into_heads(tmp_path, fused):
+    """wq/wk/wv keep HF's [out, in] rows, cut into heads — [L, heads, hd,
+    D], bit for bit, from separate q/k/v tensors and from Phi-3's fused
+    qkv_proj alike — while wo is transposed to [in, out] as before (the
+    parity tests above hold the logits to HF's own)."""
+    common = dict(
+        vocab_size=128, hidden_size=64, intermediate_size=128,
+        num_hidden_layers=2, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=256, tie_word_embeddings=False,
+        attn_implementation="eager")
+    if fused:
+        m = _save_hf(transformers.Phi3ForCausalLM,
+                     transformers.Phi3Config(pad_token_id=0, **common),
+                     tmp_path)
+    else:
+        m = _save_hf(transformers.LlamaForCausalLM,
+                     transformers.LlamaConfig(**common), tmp_path)
+    cfg = ModelConfig.from_pretrained(str(tmp_path))
+    lay = load_hf_params(cfg, str(tmp_path), dtype=jnp.float32)["layers"]
+    H, KV, hd, D = 4, 2, 16, 64
+    assert lay["wq"].shape == (2, H, hd, D)
+    assert lay["wk"].shape == lay["wv"].shape == (2, KV, hd, D)
+    assert lay["wo"].shape == (2, H * hd, D)
+    sd = {k: v.float().numpy() for k, v in m.state_dict().items()}
+    for i in range(2):
+        pre = f"model.layers.{i}.self_attn"
+        if fused:
+            q, k, v = np.split(sd[f"{pre}.qkv_proj.weight"],
+                               [H * hd, (H + KV) * hd])
+        else:
+            q, k, v = (sd[f"{pre}.{n}_proj.weight"] for n in "qkv")
+        np.testing.assert_array_equal(lay["wq"][i], q.reshape(H, hd, D))
+        np.testing.assert_array_equal(lay["wk"][i], k.reshape(KV, hd, D))
+        np.testing.assert_array_equal(lay["wv"][i], v.reshape(KV, hd, D))
+        np.testing.assert_array_equal(lay["wo"][i],
+                                      sd[f"{pre}.o_proj.weight"].T)
+

@@ -42,9 +42,15 @@ def test_init_params_builds_every_leaf_on_its_own_devices(tp4_mesh,
         assert len(x.sharding.device_set) == 4, path
         np.testing.assert_array_equal(np.asarray(x.astype(jnp.float32)),
                                       np.asarray(y.astype(jnp.float32)))
-    wq = sharded["layers"]["wq"]
-    wq = wq["q"] if quantization else wq
-    assert wq.addressable_shards[0].data.shape[-1] * 4 == wq.shape[-1]
+    # head-major projections [L, heads, width, D] shard whole heads: a
+    # quarter of the 4 q heads a device, the 2 KV heads on every device
+    wq, wk = (sharded["layers"][k]["q"] if quantization
+              else sharded["layers"][k] for k in ("wq", "wk"))
+    assert wq.addressable_shards[0].data.shape[1] * 4 == wq.shape[1]
+    assert wk.addressable_shards[0].data.shape == wk.shape
+    wo = sharded["layers"]["wo"]
+    wo = wo["q"] if quantization else wo
+    assert wo.addressable_shards[0].data.shape[-2] * 4 == wo.shape[-2]
 
 
 def test_compile_cache_env_set_means_code_sets_nothing(monkeypatch):
