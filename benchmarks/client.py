@@ -353,7 +353,7 @@ async def run_session_trace(session: aiohttp.ClientSession, urls: list[str],
     user item + ``previous_response_id``. ``delta=False`` is the
     sessionless control: the full transcript rides every turn. Both arms
     produce byte-identical conversations under greedy sampling, which is
-    exactly the bench's bit-identity gate."""
+    what ``tests/test_sessions.py`` holds the two arms to."""
     out = SessionResult(sid=sid)
     transcript: list[dict] = []  # client-side mirror of the conversation
     prev_id: Optional[str] = None

@@ -33,7 +33,7 @@ Run standalone::
     python -m benchmarks.flagship_drive [--duration 40] [--scale 1.0] \
         [--json out.json]
 
-or as the ``flagship`` bench phase (``bench.py --flagship``). The tier-1
+The tier-1
 smoke (tests/test_scorecard.py) runs a scaled-down bounded cycle.
 """
 

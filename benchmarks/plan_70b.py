@@ -158,7 +158,8 @@ def placement(combo: str = PLACEMENT_COMBO) -> dict:
 #: (scales stored wide, a leaf fallen back to full width, ...)
 QUANT_HBM_UTIL_CEILING = 1.25
 
-#: the materialization guard (the §2 risk in docs/PERF_NOTES.md): a
+#: the materialization guard (held by tests/test_quant_serving.py::
+#: test_quant_compile_proof_never_materializes_full_width): a
 #: grouped dequant chain that materializes full-width weight copies would
 #: ADD gigabytes of temp to the 2-layer TP8 step (w_down alone is 0.94 GB
 #: f32) — so the quantized program's temp bytes must stay BELOW the bf16

@@ -495,7 +495,7 @@ class AsyncJaxEngine:
         #: with device compute)
         self.pipelined_steps = 0
         #: jitted full-model forward passes (each reads every weight once
-        #: from HBM) — the denominator for roofline/MFU accounting in bench.py
+        #: from HBM) — the denominator for roofline/MFU accounting
         self.param_reads = 0
         #: padded-dispatch waste: tokens (and decode batch rows) dispatched
         #: beyond the plan's REAL work because static shapes bucket up —

@@ -251,7 +251,7 @@ COMPILE_CACHE_DIR = os.path.join(
 
 def place_compile_cache() -> str:
     """Point JAX's persistent compilation cache at its directory; every
-    process entry that compiles (run.py, engine/main.py, bench.py,
+    process entry that compiles (run.py, engine/main.py,
     chip_smoke.py) calls this before its first compile, never at import.
     Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already honours it and
     nothing is set here. Returns the directory in use."""

@@ -81,3 +81,19 @@ def make_tiny_llama_checkpoint(path: str, *, num_layers: int = 2,
             "tokenizer_class": "PreTrainedTokenizerFast",
         }, f)
     return path
+
+
+def write_wordlevel_tokenizer_dir(path: str, vocab_size: int) -> None:
+    """WordLevel tokenizer whose vocab covers the model's sampled ids, so
+    random-weight outputs detokenize through the production DecodeStream."""
+    from tokenizers import Tokenizer
+    from tokenizers.models import WordLevel
+    from tokenizers.pre_tokenizers import Whitespace
+
+    vocab = {f"w{i}": i for i in range(vocab_size)}
+    tk = Tokenizer(WordLevel(vocab, unk_token="w0"))
+    tk.pre_tokenizer = Whitespace()
+    tk.save(os.path.join(path, "tokenizer.json"))
+    with open(os.path.join(path, "tokenizer_config.json"), "w") as f:
+        json.dump({"chat_template": "{% for m in messages %}{{ m['content'] }}"
+                                    "{% endfor %}"}, f)

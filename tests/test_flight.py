@@ -497,8 +497,8 @@ async def test_flight_wide_tile_rows_counts_rows_above_the_small_tile(
 
 
 async def test_engine_flight_disabled_is_pure_observation(tiny_engine_cfg):
-    """DYN_FLIGHT=0 arm: identical token stream, zero records (the bench
-    A/B contract in miniature)."""
+    """DYN_FLIGHT=0 arm: identical token stream, zero records (recording
+    is pure observation)."""
     from dynamo_tpu.engine.config import EngineArgs
     from dynamo_tpu.engine.engine import AsyncJaxEngine
     from dynamo_tpu.protocols import (PreprocessedRequest, SamplingOptions,
@@ -524,3 +524,87 @@ async def test_engine_flight_disabled_is_pure_observation(tiny_engine_cfg):
     off_toks, off_recs = await run(False)
     assert on_toks == off_toks
     assert on_recs > 0 and off_recs == 0
+
+
+@pytest.mark.parametrize("profiled", [False, True],
+                         ids=["tagged", "profiled"])
+async def test_engine_storm_and_steady_compile(profiled, monkeypatch,
+                                               tmp_path):
+    """A SEEDED preempt storm on a real engine — batch-class streams fill
+    every slot, then an interactive burst lands and QoS admission
+    preemption evicts a batch victim per arrival (recompute mode, so each
+    eviction is a genuine preemption) — is tagged ``preempt-storm``; then
+    prompts sized to ragged token buckets the storm never dispatched trace
+    fresh signatures in steady state and are tagged ``compile-steady``.
+    With DYN_PROFILE_ON_ANOMALY set, the anomalies arm at least one REAL
+    ``jax.profiler`` capture, capped by the max-captures budget, with the
+    artifact on disk and its path on the triggering record."""
+    import glob
+
+    import numpy as np
+
+    from dynamo_tpu.engine.config import EngineArgs, ModelConfig
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.protocols import (PreprocessedRequest, SamplingOptions,
+                                      StopConditions)
+
+    if profiled:
+        monkeypatch.setenv("DYN_PROFILE_ON_ANOMALY", str(tmp_path))
+        monkeypatch.setenv("DYN_PROFILE_MAX_CAPTURES", "2")
+        monkeypatch.setenv("DYN_PROFILE_COOLDOWN_S", "0")
+        monkeypatch.setenv("DYN_PROFILE_STEPS", "4")
+    cfg = ModelConfig.tiny()
+    slots, budget = 6, 256
+    rng = np.random.default_rng(31)
+
+    async def one(eng, n_prompt, osl, cls):
+        ctx = Context()
+        ctx.priority = cls
+        r = PreprocessedRequest(
+            model="m",
+            token_ids=rng.integers(1, cfg.vocab_size, n_prompt).tolist(),
+            stop_conditions=StopConditions(max_tokens=osl, ignore_eos=True),
+            sampling_options=SamplingOptions(temperature=0.0))
+        n = 0
+        async for out in eng.generate(r, ctx):
+            n += len(out.token_ids)
+        assert n == osl
+
+    eng = AsyncJaxEngine(cfg, EngineArgs(
+        block_size=4, num_blocks=256, max_num_seqs=slots,
+        max_num_batched_tokens=budget, max_model_len=2 * budget,
+        enable_prefix_caching=False, preempt_swap=False))
+    try:
+        eng.flight.steady_after = 16  # tiny workload: steady state is near
+        batch = [asyncio.ensure_future(one(eng, 24, 48, "batch"))
+                 for _ in range(slots)]
+        for _ in range(20000):  # every slot decoding before the burst lands
+            if sum(s.generated > 0 for s in eng.scheduler.running) >= slots:
+                break
+            await asyncio.sleep(0.001)
+        inter = [asyncio.ensure_future(one(eng, 12, 8, "interactive"))
+                 for _ in range(5)]
+        await asyncio.gather(*batch, *inter)
+        assert eng.scheduler.preempt_recompute_total > 0
+        # sent alone, a prompt's one chunk IS the packed total, so each
+        # traces a fresh (ragged, T) signature mid-traffic
+        unseen = [b for b in eng.args.ragged_token_buckets
+                  if ("ragged", b) not in eng.compiled_signatures
+                  and b <= budget][:4]
+        assert unseen
+        for b in unseen:
+            await one(eng, b, 2, "standard")
+        anoms = dict(eng.flight.summary()["anomalies"])
+        assert anoms.get("preempt-storm"), anoms
+        assert anoms.get("compile-steady"), anoms
+        assert eng.compile_events.get("ragged", 0) >= len(unseen)
+        prof = eng.anomaly_profiler
+        if not profiled:
+            assert prof is None
+        else:
+            assert 1 <= prof.captures <= 2
+            assert glob.glob(str(tmp_path / "**" / "*.pb"), recursive=True)
+            assert any(r.get("profile_path")
+                       for r in eng.flight.snapshot())
+    finally:
+        await eng.close()

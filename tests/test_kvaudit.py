@@ -864,6 +864,71 @@ async def test_kv_audit_http_route_and_radix_metrics():
         await rt.shutdown()
 
 
+async def test_audit_is_pure_observation_on_a_fleet(monkeypatch):
+    """The same seeded prompts through a 2-rank mocker fleet with
+    ``DYN_KV_AUDIT=0`` and with an auditor cycling during the wave read
+    back identical texts, and the audited clean fleet shows no
+    divergence."""
+    import aiohttp
+    import numpy as np
+
+    from dynamo_tpu.frontend.http import HttpService
+    from dynamo_tpu.llm.discovery import ModelManager, ModelWatcher
+    from dynamo_tpu.llm.tokenizer import make_test_tokenizer
+    from dynamo_tpu.mocker.engine import MockEngineArgs
+    from dynamo_tpu.mocker.main import run_mocker
+
+    rng = np.random.default_rng(77)
+    prompts = [rng.integers(10, 200, 24).tolist() for _ in range(8)]
+
+    async def wave(name, audited: bool):
+        rt = await DistributedRuntime.create()
+        engines, handles = await run_mocker(rt, name, MockEngineArgs(
+            vocab_size=make_test_tokenizer().vocab_size, block_size=4,
+            num_gpu_blocks=72, dp_size=2, speedup_ratio=50.0))
+        manager = ModelManager()
+        watcher = await ModelWatcher(rt, manager, router_mode="kv").start()
+        service = HttpService(manager, port=0, runtime=rt)
+        await service.start()
+        auditor = None
+        try:
+            await settle(lambda: manager.list_models(), timeout=10.0,
+                         msg="model never appeared")
+            if audited:
+                auditor = await KvAuditor(
+                    rt.plane, manager.get(name).router.indexer,
+                    AuditConfig(interval_s=0.2, settle_s=0.05)).start()
+            url = f"http://127.0.0.1:{service.port}/v1/completions"
+            texts = []
+            async with aiohttp.ClientSession() as http:
+                for p in prompts:
+                    async with http.post(url, json={
+                            "model": name, "prompt": p, "max_tokens": 12,
+                            "ignore_eos": True}) as r:
+                        assert r.status == 200, await r.text()
+                        texts.append((await r.json())["choices"][0]["text"])
+            if audited:
+                doc = await auditor.audit_once()
+                assert doc["workers"] and all(
+                    w["phantom"] == 0 and w["missing"] == 0
+                    for w in doc["workers"].values()), doc
+            return texts
+        finally:
+            if auditor is not None:
+                await auditor.stop()
+            await service.stop()
+            await watcher.stop()
+            for h in handles:
+                await h.stop(graceful=False)
+            for e in engines:
+                await e.stop()
+            await rt.shutdown()
+
+    monkeypatch.setenv("DYN_KV_AUDIT", "0")
+    assert (await wave("kvaudit-on", audited=True)
+            == await wave("kvaudit-off", audited=False))
+
+
 async def test_mocker_ledger_parity():
     """The mocker's ledger mirrors its KvCacheSim membership exactly."""
     from dynamo_tpu.mocker.engine import MockEngine, MockEngineArgs

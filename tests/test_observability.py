@@ -1,6 +1,6 @@
 """Observability spine: span recorder, trace propagation/stitching, SLO
-histograms, Prometheus exposition correctness, and the bench --observe
-smoke (one mock request → complete stitched trace + /metrics series)."""
+histograms, Prometheus exposition correctness, and the full-stack smoke
+(one mock request → complete stitched trace + /metrics series)."""
 
 import contextvars
 import json
@@ -297,14 +297,92 @@ async def test_trace_collector_over_control_plane():
 # ------------------------------------------------------ end-to-end smoke
 
 
-async def test_observe_smoke_full_stack():
-    """The tier-1 wiring of ``bench.py --observe``: one mock request yields
-    a complete stitched trace (≥6 named phases incl. TTFT and ITL) via
-    /v1/traces/{request_id}, and /metrics exposes the SLO histograms."""
-    import bench
+#: span names one mock request through the full stack must produce
+OBSERVE_PHASES = (
+    "http.request", "preprocess.tokenize", "router.schedule",
+    "worker.handle", "engine.ttft", "engine.decode", "ttft", "itl",
+)
+#: Prometheus series /metrics must expose out of the box
+OBSERVE_SERIES = (
+    "dynamo_ttft_seconds", "dynamo_itl_seconds", "dynamo_e2e_seconds",
+    "dynamo_phase_seconds",
+)
 
-    out = await bench.observe_smoke()
-    assert out["observe"] == "ok"
-    assert len(out["phases"]) >= 6
-    for phase in ("ttft", "itl", "http.request", "router.schedule"):
-        assert phase in out["phases"]
+
+async def test_observe_smoke_full_stack():
+    """One mock request through the full serving stack yields a complete
+    stitched trace (every named phase, TTFT and ITL among them, no span
+    orphaned) via /v1/traces/{request_id}, and /metrics exposes the SLO
+    histograms."""
+    import asyncio
+
+    import aiohttp
+
+    from dynamo_tpu.frontend.http import HttpService
+    from dynamo_tpu.llm.discovery import ModelManager, ModelWatcher
+    from dynamo_tpu.llm.tokenizer import make_test_tokenizer
+    from dynamo_tpu.mocker.engine import MockEngineArgs
+    from dynamo_tpu.mocker.main import run_mocker
+    from dynamo_tpu.observability import configure_tracer
+    from dynamo_tpu.runtime import DistributedRuntime
+
+    configure_tracer(service="observe")  # fresh buffer: hermetic assertions
+    rt = await DistributedRuntime.create()
+    # setup INSIDE the try: a failing start must not leak engine loops /
+    # watcher tasks into the rest of the suite
+    engines, handles = [], []
+    watcher = service = None
+    try:
+        args = MockEngineArgs(vocab_size=make_test_tokenizer().vocab_size,
+                              block_size=4, num_gpu_blocks=128,
+                              speedup_ratio=20.0)
+        engines, handles = await run_mocker(rt, "observe", args)
+        manager = ModelManager()
+        watcher = await ModelWatcher(rt, manager, router_mode="kv").start()
+        service = HttpService(manager, port=0, runtime=rt)
+        await service.start()
+        for _ in range(200):
+            if manager.list_models():
+                break
+            await asyncio.sleep(0.05)
+        else:
+            raise RuntimeError("model never appeared in discovery")
+
+        rid = "observe-smoke-request"
+        base = f"http://127.0.0.1:{service.port}"
+        async with aiohttp.ClientSession() as http:
+            async with http.post(
+                    f"{base}/v1/completions",
+                    json={"model": "observe", "prompt": "hello tokens stream",
+                          "max_tokens": 8, "stream": True,
+                          "ignore_eos": True},
+                    headers={"x-request-id": rid}) as resp:
+                assert resp.status == 200, await resp.text()
+                async for _ in resp.content:
+                    pass
+            async with http.get(f"{base}/v1/traces/{rid}") as resp:
+                assert resp.status == 200, await resp.text()
+                trace = await resp.json()
+            async with http.get(f"{base}/metrics") as resp:
+                assert resp.status == 200
+                metrics_text = await resp.text()
+    finally:
+        if service is not None:
+            await service.stop()
+        if watcher is not None:
+            await watcher.stop()
+        for h in handles:
+            await h.stop(graceful=False)
+        for e in engines:
+            await e.stop()
+        await rt.shutdown()
+
+    phases = set(trace["phases"])
+    assert len(phases) >= 6
+    assert not [p for p in OBSERVE_PHASES if p not in phases], sorted(phases)
+    assert not [s for s in OBSERVE_SERIES if s not in metrics_text]
+    # every span must stitch: a recorded parent id that is absent from the
+    # trace means a broken hop in the parenting chain
+    ids = {s["span_id"] for s in trace["spans"]}
+    assert not [s["name"] for s in trace["spans"]
+                if s.get("parent_span_id") and s["parent_span_id"] not in ids]

@@ -161,8 +161,10 @@ def test_ring_prefill_paged_matches_dense():
     (44, 38),
 ])
 async def test_engine_sp_prefill_matches_single_device(max_model_len, prompt_len):
-    """The engine serves a prompt through chunked ring prefill on an sp=2
-    mesh and reproduces the single-device greedy continuation."""
+    """An engine on an sp=2 mesh serves a prompt in chunks through the
+    packed ragged step and reproduces the single-device greedy
+    continuation. The packed step never takes the ring-attention branch of
+    ``forward``: this holds the mesh, not the ring."""
     from dynamo_tpu.engine import model as M
     from dynamo_tpu.engine.config import EngineArgs, ModelConfig
     from dynamo_tpu.engine.engine import AsyncJaxEngine

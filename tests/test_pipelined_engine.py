@@ -255,17 +255,17 @@ async def test_coalesced_sse_resplits_into_valid_openai_deltas():
     non-streaming result. Fewer chunks than tokens proves coalescing."""
     import aiohttp
 
-    import bench
     from dynamo_tpu.disagg.handlers import DecodeWorkerHandler
     from dynamo_tpu.frontend.http import HttpService
     from dynamo_tpu.llm.discovery import ModelManager, ModelWatcher
     from dynamo_tpu.llm.model_card import ModelDeploymentCard, register_llm
     from dynamo_tpu.runtime import DistributedRuntime
+    from tests.hf_fixture import write_wordlevel_tokenizer_dir
     import tempfile
 
     tmp = tempfile.mkdtemp(prefix="coalesce-tk-")
     cfg = ModelConfig.tiny()
-    bench._write_tokenizer_dir(tmp, cfg.vocab_size)
+    write_wordlevel_tokenizer_dir(tmp, cfg.vocab_size)
 
     rt = await DistributedRuntime.create()
     eng = tiny_engine(multi_step_decode=4)

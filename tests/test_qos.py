@@ -777,17 +777,69 @@ def test_frontend_retry_after_from_drain_rate():
     assert resp.headers["Retry-After"] != "1"
 
 
-# ------------------------------------------------------- bench smoke
+# ------------------------------------------- two tenants, 2x oversubscribed
 
 
 async def test_qos_bench_smoke():
-    """tier-1 wiring for ``bench.py --qos``: the structural guarantees (the
-    batch class completes in full, only batch-class sequences are
-    preempted). The TTFT and tokens/s ratios the phase also prints are host
-    timings of the tiny preset — no evidence of speed, and unsteady under
-    busy xdist workers — so nothing here gates on them."""
-    import bench
+    """Two tenants share one engine whose KV pool holds about half the
+    combined working set and whose seq slots hold half the offered
+    concurrency: a batch-class tenant floods first, then an interactive
+    tenant arrives. The structural guarantees: the batch class completes in
+    full in every pass (no starvation) and only batch-class sequences are
+    preempted."""
+    import numpy as np
 
-    out = await bench.qos_bench(False, reps=2)
-    assert out["batch_completed"] == out["batch_expected"], out
-    assert set(out["qos_preempts_by_class"]) <= {"batch"}, out
+    cfg = ModelConfig.tiny()
+    n_i, isl_i, osl_i = 8, 32, 16
+    # batch OSL long enough that the swap preemptions the interactive wave
+    # triggers land on sustained decode, not a prefill sprint
+    n_b, isl_b, osl_b = 8, 128, 64
+    slots = 8  # 16 offered seqs -> 2x compute oversubscription
+    working = (n_b * ((isl_b + osl_b + BS - 1) // BS)
+               + n_i * ((isl_i + osl_i + BS - 1) // BS))
+    eng = AsyncJaxEngine(cfg, EngineArgs(
+        block_size=BS, num_blocks=working // 2 + 1, max_num_seqs=slots,
+        max_num_batched_tokens=2 * isl_b, max_model_len=2 * (isl_b + osl_b),
+        prefill_buckets=(isl_b,), decode_batch_buckets=(slots,),
+        enable_prefix_caching=False, qos_scheduling=True))
+    rng = np.random.default_rng(23)
+    int_prompts = [rng.integers(1, cfg.vocab_size, isl_i).tolist()
+                   for _ in range(n_i)]
+    bat_prompts = [rng.integers(1, cfg.vocab_size, isl_b).tolist()
+                   for _ in range(n_b)]
+
+    async def one(tokens, osl, tenant, cls):
+        r = PreprocessedRequest(
+            model="m", token_ids=list(tokens),
+            stop_conditions=StopConditions(max_tokens=osl, ignore_eos=True),
+            sampling_options=SamplingOptions(temperature=0.0))
+        n = 0
+        async for out in eng.generate(r, Context(tenant=tenant,
+                                                 priority=cls)):
+            n += len(out.token_ids)
+        return n
+
+    async def mixed():
+        """Batch floods first; interactive arrives once batch occupies the
+        engine. Returns the batch tenant's completed tokens."""
+        bat = [asyncio.ensure_future(one(p, osl_b, "tenant-bat", "batch"))
+               for p in bat_prompts]
+        for _ in range(20000):
+            if (len(eng.scheduler.running) >= min(slots, n_b) - 1
+                    and any(s.num_computed > 0
+                            for s in eng.scheduler.running)):
+                break
+            await asyncio.sleep(0.001)
+        ints = [asyncio.ensure_future(
+            one(p, osl_i, "tenant-int", "interactive")) for p in int_prompts]
+        await asyncio.gather(*ints)
+        return sum(await asyncio.gather(*bat))
+
+    try:
+        for _ in range(2):
+            assert await mixed() == n_b * osl_b
+        preempted = {cls for (_tenant, cls), n
+                     in eng.qos_stats()["preemptions"].items() if n}
+        assert preempted <= {"batch"}, preempted
+    finally:
+        await eng.close()

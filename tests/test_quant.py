@@ -161,6 +161,27 @@ def test_quantize_params_groups_each_weight_along_its_contraction(spec):
                 np.asarray(real["layers"][k][f].astype(jnp.float32)))
 
 
+@pytest.mark.parametrize("spec,element_bytes", [("int8", 1.0),
+                                                 ("int4-g32", 0.5)])
+def test_quantized_weights_cash_their_bytes(spec, element_bytes):
+    """A decode step streams every weight once, so the stored bytes are its
+    roofline: no matmul weight of the layer stack falls back to full width
+    in silence. Each is held at the format's bytes an element plus its
+    scales (f32: 4/in_dim an element per-channel, 4/32 grouped)."""
+    from dynamo_tpu.engine.cache import tree_nbytes
+
+    cfg = ModelConfig.tiny()
+    params = jax.tree.map(np.asarray, M.init_params(cfg, jax.random.key(0)))
+    quant = Q.quantize_params(params, spec)
+    matmuls = {k: w for k, w in params["layers"].items() if w.ndim >= 3}
+    assert set(matmuls) >= {"wq", "wk", "wv", "wo", "w_gate", "w_up",
+                            "w_down"}
+    for key, full in matmuls.items():
+        assert (tree_nbytes(quant["layers"][key])
+                <= full.size * (element_bytes + 0.126)), key
+    assert tree_nbytes(quant) * 1.5 <= tree_nbytes(params)
+
+
 def _small_preset(name):
     import dataclasses
 

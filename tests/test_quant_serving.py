@@ -158,7 +158,7 @@ def test_ragged_int8_scale_budget_degrades_to_oracle(monkeypatch):
 
 def test_ragged_oracle_env_switch(monkeypatch):
     """DYN_RAGGED_ORACLE=1 routes the launch to the XLA oracle — the
-    bench A/B arm that replaced the deleted silent fallback."""
+    explicit A/B arm that replaced the deleted silent fallback."""
     q, kq, vq, ksc, vsc, bt, rows3, t = make_int8_case(
         jax.random.key(3), STAGGERED[:2])
     monkeypatch.setenv("DYN_RAGGED_ORACLE", "1")
@@ -385,7 +385,7 @@ def test_engine_args_quantization_validated():
 def test_plan_70b_quant_gate_holds():
     """The solver half of --assert-quant: the solved tp8_wint4_kvint8
     placement fits and its real-layout bandwidth demand stays under the
-    ceiling (the bench quant phase runs this same gate every round)."""
+    ceiling."""
     from benchmarks.plan_70b import assert_quant
 
     res = assert_quant(run_compile=False)

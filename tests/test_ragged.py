@@ -524,6 +524,79 @@ def _prefill_rows(M, params, cfg, prompts, bt, bs, kc, vc):
     return kc, vc
 
 
+#: every mode compiles into the token-bucket signature families — one
+#: stray kind means a mode escaped the packed launch
+SIGNATURE_FAMILIES = {"ragged", "ragged_dec", "ragged_mm", "pp", "verify",
+                      "verify_fsm", "multi", "multi_fsm", "draft"}
+
+
+@pytest.mark.parametrize("mode", ["spec", "multi", "mla"])
+async def test_modes_ride_the_packed_launch_on_a_mixed_wave(mode):
+    """The same seeded MIXED wave — long-prompt/short-output requests
+    arriving while short-prompt/long-output streams are mid-decode, so
+    steps carry prefill chunks AND decode rows — under each mode. Spec
+    decode (prompt-lookup drafts verified as ragged rows) and multi-step
+    fused decode are dispatch-count optimizations on the same greedy
+    sampler: their streams are BIT-IDENTICAL to plain single-step serving.
+    The MLA preset replays its own wave identically. Every arm's compiled
+    signatures stay in the token-bucket families."""
+    from dynamo_tpu.models import get_model_config
+
+    cfg = ModelConfig.tiny()
+    bs = 4
+    n_p, isl_p, osl_p = 4, 96, 12   # prefill-heavy
+    n_d, isl_d, osl_d = 4, 16, 40   # decode-heavy
+    working = (n_p * ((isl_p + osl_p + bs - 1) // bs)
+               + n_d * ((isl_d + osl_d + bs - 1) // bs))
+    rng = np.random.default_rng(37)
+    p_prompts = [rng.integers(1, cfg.vocab_size, isl_p).tolist()
+                 for _ in range(n_p)]
+    d_prompts = [rng.integers(1, cfg.vocab_size, isl_d).tolist()
+                 for _ in range(n_d)]
+
+    async def one(eng, tokens, osl):
+        toks, _ = await collect(eng, req(tokens, max_tokens=osl,
+                                         temperature=0.0))
+        return toks
+
+    async def wave(eng):
+        dec = [asyncio.ensure_future(one(eng, p, osl_d)) for p in d_prompts]
+        for _ in range(20000):
+            if any(s.generated > 0 for s in eng.scheduler.running):
+                break
+            await asyncio.sleep(0.001)
+        pre = [asyncio.ensure_future(one(eng, p, osl_p)) for p in p_prompts]
+        return await asyncio.gather(*dec, *pre)
+
+    async def arm(arm_cfg, **arm_args):
+        """(first wave's streams, second wave's streams, signature kinds)"""
+        eng = AsyncJaxEngine(arm_cfg, EngineArgs(
+            block_size=bs, num_blocks=2 * working + 8, max_num_seqs=8,
+            max_num_batched_tokens=128,
+            max_model_len=2 * max(isl_p + osl_p, isl_d + osl_d),
+            enable_prefix_caching=False, **arm_args))
+        try:
+            first = await wave(eng)
+            again = await wave(eng)
+            assert ([len(t) for t in again]
+                    == [osl_d] * n_d + [osl_p] * n_p)
+            return first, again, {s[0] for s in eng.compiled_signatures}
+        finally:
+            await eng.close()
+
+    if mode == "mla":
+        first, again, kinds = await arm(get_model_config("mla_tiny"))
+        assert again == first
+    else:
+        _, base, kinds = await arm(cfg)
+        _, streams, mode_kinds = await arm(cfg, **{
+            "spec": dict(speculative_tokens=3),
+            "multi": dict(multi_step_decode=4)}[mode])
+        assert streams == base
+        kinds |= mode_kinds
+    assert kinds <= SIGNATURE_FAMILIES, kinds
+
+
 def test_ragged_verify_matches_legacy_verify_fn():
     """Spec-decode verification as ragged rows (q_len = draft+1 on the
     packed launch) returns the same greedy ids/logps as the legacy [B, S]

@@ -259,6 +259,71 @@ async def test_park_restore_through_g4(tmp_path):
     np.testing.assert_array_equal(k, _page(hashes[0] & 0xFF))
 
 
+async def test_restored_session_computes_fewer_prompt_tokens():
+    """What parking buys, on a real engine: a session's first turn is
+    parked to G4, every local tier is flushed (the churn between turns),
+    and the returning turn is served twice — after a ``restore`` op and,
+    flushed again, without one. Both read the same greedy tokens; the
+    restored arm hits its own prefix and computes strictly fewer prompt
+    tokens."""
+    from dynamo_tpu.engine.config import EngineArgs, ModelConfig
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.protocols import (PreprocessedRequest, SamplingOptions,
+                                      StopConditions)
+
+    cfg, bs = ModelConfig.tiny(), 4
+    blk = 2 * cfg.num_layers * bs * cfg.num_kv_heads * cfg.head_dim * 4
+    eng = AsyncJaxEngine(cfg, EngineArgs(
+        block_size=bs, num_blocks=128, max_num_seqs=2,
+        max_num_batched_tokens=64, max_model_len=128,
+        enable_prefix_caching=True, kvbm_host_bytes=64 * blk))
+    g4 = _FakeG4Client()
+    eng.kvbm.attach_remote(g4, capacity_bytes=1 << 30)
+    handler = SessionKvHandler(eng)
+    turn1 = [(7 * i) % 200 + 1 for i in range(12 * bs)]
+    turn2 = turn1 + [9, 8, 7, 6, 5]
+
+    async def serve(tokens):
+        """(greedy tokens, prompt tokens computed)"""
+        sched = eng.scheduler
+        q0, h0 = sched.prefix_query_tokens, sched.prefix_hit_tokens
+        toks = []
+        async for out in eng.generate(PreprocessedRequest(
+                model="m", token_ids=list(tokens),
+                stop_conditions=StopConditions(max_tokens=6,
+                                               ignore_eos=True),
+                sampling_options=SamplingOptions(temperature=0.0))):
+            toks.extend(out.token_ids)
+        return toks, ((sched.prefix_query_tokens - q0)
+                      - (sched.prefix_hit_tokens - h0))
+
+    async def flush():
+        """What churn does: the device pool and the host tier are evicted;
+        the object store keeps what was parked."""
+        eng.pool.clear()
+        await asyncio.to_thread(eng.kvbm.make_host_room, 0)
+
+    try:
+        await serve(turn1)
+        for _ in range(500):  # the turn's blocks reach the host tier
+            if eng.kvbm.stats()["host_blocks"] >= 12:
+                break
+            await asyncio.sleep(0.02)
+        parked = await _session_op(handler, "park", turn1)
+        assert parked["blocks"] >= 11 and len(g4.store) >= 11
+        await flush()
+        restored = await _session_op(handler, "restore", turn1)
+        assert restored["blocks"] >= 11
+        native, native_computed = await serve(turn2)
+        await flush()
+        control, control_computed = await serve(turn2)
+    finally:
+        await eng.close()
+    assert native == control
+    assert control_computed == len(turn2)
+    assert native_computed < control_computed - 10 * bs
+
+
 async def test_park_stops_at_first_gap(tmp_path):
     """A hole in the local chain truncates the park: G4 onboarding attaches
     contiguous prefixes only, so blocks behind the gap would be stranded."""
@@ -486,6 +551,7 @@ async def test_session_registry_view_and_metrics(stack):
             assert 'dynamo_session_turns_total{kind="delta"}' in text
             assert 'kind="chat"' in text
             assert "dynamo_session_affinity_total" in text
+            assert "dynamo_session_parked_blocks_total" in text
 
 
 async def test_reaper_parks_idle_session_via_worker_endpoint(stack,
@@ -539,6 +605,49 @@ async def test_reaper_parks_idle_session_via_worker_endpoint(stack,
         await handle.stop(graceful=False)
         await engine.stop()
         await rt.shutdown()
+
+
+async def test_trace_shapes_and_ttl_reaper_over_http(stack, monkeypatch):
+    """The load tool's session shapes against a short-TTL frontend: an
+    agent tool-loop session completes with its follow-up turns, an
+    abandoned session walks away mid-conversation, and once the TTL has
+    passed the reaper has collected both (``/v1/sessions`` counts 0)."""
+    import random
+
+    from benchmarks.client import run_session_trace, session_headers
+
+    monkeypatch.setenv("DYN_SESSION_TTL_S", "1.2")
+    monkeypatch.setenv("DYN_SESSION_REAP_INTERVAL_S", "0.15")
+    rt, _service, add_mocker, manager = stack
+    service = HttpService(manager, port=0)
+    await service.start()
+    try:
+        await add_mocker()
+        await wait_for_model(manager)
+        base = f"http://127.0.0.1:{service.port}"
+        rng = random.Random(7)
+        shape = dict(rng=rng, words_per_turn=20, osl=8, think_s=(0.05, 0.1),
+                     sampling={"temperature": 0.0})
+        async with aiohttp.ClientSession() as http:
+            agent = await run_session_trace(
+                http, [base], MODEL, sid="agent", turns=3, tool_loop_p=1.0,
+                headers=session_headers("agent"), **shape)
+            gone = await run_session_trace(
+                http, [base], MODEL, sid="gone", turns=4, abandon_p=1.0,
+                headers=session_headers("gone"), **shape)
+            assert agent.ok and agent.tool_loops > 0
+            assert gone.abandoned
+            async with http.get(f"{base}/v1/sessions") as r:
+                assert (await r.json())["count"] >= 1
+            for _ in range(100):  # TTL 1.2 s + a reap sweep
+                async with http.get(f"{base}/v1/sessions") as r:
+                    if (await r.json())["count"] == 0:
+                        break
+                await asyncio.sleep(0.05)
+            else:
+                raise AssertionError("abandoned session never reaped")
+    finally:
+        await service.stop()
 
 
 async def test_sessions_disabled_is_stateless(stack, monkeypatch):

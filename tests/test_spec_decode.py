@@ -222,7 +222,7 @@ def test_draft_fn_validation():
 # ------------------------------------------- auto-disable governor (ISSUE 4)
 
 async def test_spec_auto_disables_on_losing_gain_and_reprobes():
-    """BENCH_r05 recorded accept 0.019 / gain 0.729 — a 27% slowdown with
+    """An early run recorded accept 0.019 / gain 0.729 — a 27% slowdown with
     nothing turning speculation off. The governor must suspend spec decode
     once the rolling measured gain stays < 1 over the window, count it,
     and re-arm after the re-probe interval."""

@@ -537,17 +537,189 @@ async def test_mocker_guided_parity():
         await eng.stop()
 
 
-# ------------------------------------------------------------ bench smoke
+# ------------------------------------------------ the agentic tool loop
+
+LOOP_TOOLS = [
+    {"type": "function", "function": {
+        "name": "get", "parameters": {
+            "type": "object",
+            "properties": {"k": {"enum": ["a", "b"]}}}}},
+    {"type": "function", "function": {
+        "name": "put", "parameters": {
+            "type": "object",
+            # n is bounded: a bare integer is an unbounded language, and
+            # the random-weight model greedily emits digits up to OSL
+            "properties": {"k": {"enum": ["a", "b"]},
+                           "n": {"type": "integer",
+                                 "enum": [0, 1, 12, 250]}}}}},
+]
+LOOP_OSL = 48  # the char-level tool-call JSON needs ~40 tokens to close
+LOOP_EOS = 2
+
+
+def _loop_req(tokens, pin=None):
+    return PreprocessedRequest(
+        model="m", token_ids=list(tokens),
+        stop_conditions=StopConditions(max_tokens=LOOP_OSL),
+        sampling_options=SamplingOptions(
+            temperature=0.0,
+            guided={"regex": tool_constraint(LOOP_TOOLS, "required", None)}),
+        eos_token_ids=[LOOP_EOS], backend_instance_id=pin)
+
+
+def _loop_call_valid(toks) -> bool:
+    try:
+        obj = json.loads("".join(VOCAB[t] for t in toks if t != LOOP_EOS))
+    except ValueError:
+        return False
+    return (isinstance(obj, dict) and obj.get("name") in {"get", "put"}
+            and isinstance(obj.get("arguments"), dict))
+
+
+async def _tools_peer_leg() -> dict:
+    """2-worker tool loop: a session's first turn lands on worker A, its
+    second is steered to worker B, whose admission peer-pulls the session's
+    own prefix over the onboarding wire (constrained throughout)."""
+    from dynamo_tpu.disagg.handlers import DecodeWorkerHandler, KvPullHandler
+    from dynamo_tpu.disagg.transfer import OnboardConfig, RestoreConfig
+    from dynamo_tpu.router.kv_router import KvPushRouter, KvRouter
+    from dynamo_tpu.router.protocols import KvRouterConfig
+    from dynamo_tpu.router.publisher import KvEventPublisher
+    from dynamo_tpu.runtime import DistributedRuntime
+    from dynamo_tpu.runtime.config import RuntimeConfig
+    from dynamo_tpu.runtime.context import Context
+
+    bs = 16
+    isl = 512  # enough prefix blocks to clear onboard_min_blocks
+    rng = np.random.default_rng(67)
+    prefix = rng.integers(3, CFG.vocab_size, isl).tolist()
+    rcfg = RuntimeConfig(lease_ttl=8.0)
+    rt = await DistributedRuntime.create(config=rcfg)
+    workers = []
+    router = client = None
+
+    async def make_worker():
+        wrt = await DistributedRuntime.create(plane=rt.plane,
+                                              owns_plane=False, config=rcfg)
+        lease = await wrt.primary_lease()
+        eng = await asyncio.to_thread(
+            AsyncJaxEngine, CFG, EngineArgs(
+                block_size=bs, num_blocks=4 * (isl // bs) + 64,
+                max_num_seqs=4, max_num_batched_tokens=1024,
+                max_model_len=isl + 4 * (LOOP_OSL + 16) + bs,
+                enable_prefix_caching=True), guided_vocab=VOCAB)
+        pub = KvEventPublisher(wrt.plane, worker_id=lease, kv_block_size=bs)
+        await pub.start_resync_responder()
+        eng.event_cb = pub.publish_sync
+        comp = wrt.namespace("dynamo").component("backend")
+        pull_client = await comp.endpoint("kv_pull").client().start()
+        handler = DecodeWorkerHandler(
+            eng, pull_clients=[pull_client], metrics=wrt.metrics,
+            restore_config=RestoreConfig(enabled=False),
+            onboard_config=OnboardConfig(enabled=True))
+        handler.instance_id = lease
+        h_gen = await comp.endpoint("generate").serve_endpoint(
+            handler.generate, lease_id=lease)
+        h_pull = await comp.endpoint("kv_pull").serve_endpoint(
+            KvPullHandler(eng).generate, lease_id=lease)
+        w = type("W", (), {})()
+        w.rt, w.engine, w.lease, w.handler = wrt, eng, lease, handler
+        w.pub, w.pull_client, w.handles = pub, pull_client, [h_gen, h_pull]
+        workers.append(w)
+        return w
+
+    try:
+        a = await make_worker()
+        b = await make_worker()
+        client = await (rt.namespace("dynamo").component("backend")
+                        .endpoint("generate").client().start())
+        router = await KvRouter(rt.plane, bs, KvRouterConfig()).start()
+        push = KvPushRouter(client, router)
+
+        async def turn(tokens, pin=None):
+            toks = []
+            async for out in push.generate(_loop_req(tokens, pin), Context()):
+                if isinstance(out, dict) and out.get("token_ids"):
+                    toks.extend(out["token_ids"])
+            return toks
+
+        # turn 1 computes the session prefix on A
+        state = prefix + [5]
+        t1 = await turn(state, pin=a.lease)
+        state = state + t1 + rng.integers(3, CFG.vocab_size, 32).tolist()
+        # radix must learn A's prefix before steering away
+        for _ in range(400):
+            if router.restore_sources(state).get(a.lease, 0) \
+                    >= isl // bs - 1:
+                break
+            await asyncio.sleep(0.02)
+        client.set_busy_instances([a.lease])  # turn 2 lands on B
+        t2 = await turn(state)
+        pulled = b.handler._onboard_blocks._values.get(
+            (("source", "peer"),), 0)
+        return {"pulled_blocks": int(pulled),
+                "complete": bool(t1 and t2 and _loop_call_valid(t1)
+                                 and _loop_call_valid(t2))}
+    finally:
+        for w in workers:
+            for h in w.handles:
+                await h.stop(graceful=False)
+            await w.pull_client.stop()
+            await w.pub.stop()
+            await w.engine.close()
+            await w.rt.shutdown()
+        if router is not None:
+            await router.stop()
+        if client is not None:
+            await client.stop()
+        await rt.shutdown()
+
 
 async def test_tools_bench_smoke():
-    """The tier-1 wiring for bench.py --tools (full gates run in CI's
-    bench-gains step; this keeps the phase green at reduced size)."""
-    import bench
+    """The agentic tool loop as a workload: multi-turn tool-call sessions
+    under ``tool_choice: "required"`` on the device-FSM path, each turn's
+    prompt the previous turn's prompt + the model's tool call + a synthetic
+    tool result. Every constrained turn is schema-valid, turn 2 re-hits its
+    own prefix in the radix cache, no row falls back to the host oracle,
+    and on a 2-worker fleet a session steered to the other worker
+    peer-pulls its own prefix and stays schema-valid."""
+    turns, bs = 2, 16
+    rng = np.random.default_rng(61)
+    base_prompt = rng.integers(3, CFG.vocab_size, 96).tolist()
+    result_filler = [rng.integers(3, CFG.vocab_size, 48).tolist()
+                     for _ in range(turns)]
+    max_len = len(base_prompt) + turns * (LOOP_OSL + 48) + 64
+    eng = AsyncJaxEngine(CFG, EngineArgs(
+        block_size=bs, num_blocks=8 * (max_len // bs) + 16, max_num_seqs=2,
+        max_num_batched_tokens=512, max_model_len=max_len,
+        enable_prefix_caching=True), guided_vocab=VOCAB)
+    assert eng.structured is not None, "device FSM arena failed to build"
 
-    out = await bench.tools_bench(False, reps=1, sessions=1, turns=2)
-    assert out["schema_valid_rate"] == 1.0, out
-    assert out["turn2_prefix_hit_tokens"] > 0, out
-    assert out["structured_rows_host"] == 0, out
-    assert out["structured_rows_device"] > 0, out
-    peer = out.get("peer") or {}
-    assert peer.get("pulled_blocks", 0) > 0 and peer.get("complete"), out
+    async def session(salt):
+        """One session running alone, so the scheduler's (global) hit
+        counter's per-turn deltas are that session's own prefix re-hits.
+        Returns (schema-valid turns, turn-2+ prefix-hit tokens)."""
+        state = base_prompt + [9 + salt]
+        valid = hits = 0
+        for t in range(turns):
+            h0 = eng.scheduler.prefix_hit_tokens
+            toks, _ = await _collect(eng, _loop_req(state))
+            valid += _loop_call_valid(toks)
+            if t > 0:
+                hits += eng.scheduler.prefix_hit_tokens - h0
+            state = state + toks + result_filler[t]
+        return valid, hits
+
+    try:
+        for salt in range(2):
+            valid, hits = await session(salt)
+            assert valid == turns
+            assert hits > 0
+        st = eng.structured.stats()
+    finally:
+        await eng.close()
+    assert st["rows_host"] == 0, st
+    assert st["rows_device"] > 0, st
+
+    peer = await _tools_peer_leg()
+    assert peer["pulled_blocks"] > 0 and peer["complete"], peer
