@@ -1716,6 +1716,10 @@ class AsyncJaxEngine:
             running=len(sched.running),
             starved_decode=(sched.last_starved_decode
                             if starved is None else starved),
+            # like starved_decode: the plan's own count, where the record
+            # is of a plan (a pipelined step passes starved=0: it has none)
+            prefill_blocked=(sched.last_prefill_blocked
+                             if starved is None else 0),
             constrained_rows=constrained,
             kv_tiers=tiers, qos_mix=qos_mix or {},
             decode_ids=self._ctx_ids(decode_seqs),

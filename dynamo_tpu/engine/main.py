@@ -528,6 +528,12 @@ async def amain():
         "guard (failed block reservations)").add_callback(
         _swap_cb("swap_in_blocked"))
     runtime.metrics.counter(
+        "prefill_overtakes_total",
+        "prefill chunks planned while an older prompt was left with tokens "
+        "the step did not give it (fewest-remaining-first order)"
+    ).add_callback(
+        lambda: {None: engine.scheduler.prefill_overtakes_total})
+    runtime.metrics.counter(
         "spec_disabled_total",
         "times the engine auto-suspended losing speculative "
         "decode").add_callback(

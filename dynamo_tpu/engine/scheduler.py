@@ -25,6 +25,7 @@ chunked prefill budget, preemption; vLLM-style recompute preemption):
 
 from __future__ import annotations
 
+import itertools
 import logging
 import time
 from collections import deque
@@ -138,6 +139,32 @@ class StepPlan:
         return not self.prefill and not self.decode
 
 
+def share_prefill_budget(head: int, others: list[int], budget: int,
+                         max_rows: int) -> tuple[int, list[int]]:
+    """Split one step's prefill token budget among the prompts waiting for it.
+
+    ``head`` is the oldest waiting prompt's remaining tokens, ``others`` the
+    rest's in the order they are to be served (fewest remaining first).
+    Half the budget, rounded up, is kept for the head; the others take
+    ``min(remaining, what is left beside that)`` in turn, on at most
+    ``max_rows - 1`` rows; the head then takes all that is left. Returns the
+    head's chunk and the others' (a prefix of ``others``: those past it get
+    nothing this step). With no others the head's chunk is ``min(head,
+    budget)``, the plan of a scheduler that serves in admission order. The
+    real scheduler and the mocker both size their chunks here.
+    """
+    if budget <= 0 or max_rows <= 0:
+        return 0, []
+    left = budget - min(head, (budget + 1) // 2)
+    chunks: list[int] = []
+    for remaining in others[:max_rows - 1]:
+        if left <= 0:
+            break
+        chunks.append(min(remaining, left))
+        left -= chunks[-1]
+    return min(head, budget - sum(chunks)), chunks
+
+
 class Scheduler:
     """Plans one engine iteration; owns admission/preemption/bookkeeping."""
 
@@ -215,6 +242,15 @@ class Scheduler:
         #: step↔request linkage, so attribution can charge the stall to
         #: the request that actually sat out (observability/attribution.py)
         self.last_starved_ids: list = []
+        #: admitted sequences with prompt tokens left that got NO chunk in
+        #: the last plan (budget, row cap or memory): the queue INSIDE
+        #: ``running`` that ``waiting`` does not see — flight field
+        #: ``prefill_blocked``
+        self.last_prefill_blocked = 0
+        #: chunks planned while an older prompt was left with tokens this
+        #: step did not give it (fewest-first order at work) →
+        #: dynamo_prefill_overtakes_total
+        self.prefill_overtakes_total = 0
 
     # -- api ----------------------------------------------------------------
 
@@ -258,7 +294,11 @@ class Scheduler:
         return len(self.waiting)
 
     def plan(self) -> StepPlan:
-        """Admission + one prefill chunk + the decode batch."""
+        """Admission + the decode batch + the step's prefill chunks, sized
+        and ordered by :meth:`_prefill_shares` (fewest remaining prompt
+        tokens first, half the budget kept for the oldest prompt: a
+        prompt's prefill takes at most twice its own steps once it is the
+        oldest, whatever arrives behind it)."""
         self._reap_cancelled()
         self._swap_in_pass()
         self._admit()
@@ -307,40 +347,21 @@ class Scheduler:
         budget -= len(plan.decode)
 
         if self.args.enable_chunked_prefill or not plan.decode:
-            # BATCHED prefill: several sequences' chunks ride one jitted
-            # call as rows of a [B, S_bucket] batch. Rows share one token
-            # bucket (the first chunk picks it; larger chunks wait for the
-            # next step) and the PADDED cost B·S_bucket is bounded by
-            # max_num_batched_tokens — concurrent prompts no longer
-            # serialize one-prefill-per-step.
+            # several sequences' chunks ride the one packed launch; ragged
+            # planning has no per-row padding, so chunks are clamped only
+            # by the step's token budget and by the chunk grid, which sizes
+            # for at most RAGGED_MAX_CHUNKS co-scheduled chunks
+            # (model.ragged_grid_shape capacity proof)
             prefill_seqs = [s for s in self.running if s.remaining > 1]
             if self.args.qos_scheduling:
                 prefill_seqs.sort(key=by_class)
-            # ragged planning has no per-row padding, so chunks are
-            # clamped only by the step's token budget.
-            cap = self.args.max_num_batched_tokens
-            for s in prefill_seqs:
+            rows = min(max_b, RAGGED_MAX_CHUNKS)
+            shares = (self._prefill_shares(prefill_seqs, budget, rows)
+                      if self.args.enable_chunked_prefill else
+                      self._whole_prompts(prefill_seqs, budget, rows))
+            for s, chunk in shares:
                 if s not in self.running:
                     continue  # preempted by an earlier iteration's victim pick
-                chunk = min(s.remaining, max(0, budget), cap)
-                if not self.args.enable_chunked_prefill and chunk < s.remaining:
-                    if s.remaining > cap:
-                        # can never fit in one unchunked step: fail it rather
-                        # than wedge the prefill queue forever
-                        self.finish(s, FinishReason.ERROR)
-                        s.sink.put_nowait(LLMEngineOutput(
-                            finish_reason=FinishReason.ERROR,
-                            text="prompt exceeds max_num_batched_tokens "
-                                 "and chunked prefill is disabled"))
-                        s.sink.put_nowait(None)
-                    continue  # a shorter seq may still fit this step
-                # the ragged step's chunk grid sizes for at most
-                # RAGGED_MAX_CHUNKS co-scheduled chunks (model.
-                # ragged_grid_shape capacity proof); later chunks wait
-                # a step — they were budget-starved anyway
-                prefill_cap = min(max_b, RAGGED_MAX_CHUNKS)
-                if chunk <= 0 or len(plan.prefill) >= prefill_cap:
-                    break
                 protected = plan.decode + [w.seq for w in plan.prefill]
                 if not self._ensure_blocks(s, s.num_computed + chunk):
                     # not enough memory: preempt, but never a seq whose
@@ -354,13 +375,77 @@ class Scheduler:
                     seq=s, start=s.num_computed, chunk=chunk,
                     sample=(s.num_computed + chunk == len(s.tokens)),
                 ))
-                budget -= chunk
+            # a chunk overtook if it is a younger prompt's than the oldest
+            # one that this step leaves with tokens
+            given = {id(w.seq): w.chunk for w in plan.prefill}
+            for i, s in enumerate(prefill_seqs):
+                if s in self.running and given.get(id(s), 0) < s.remaining:
+                    self.prefill_overtakes_total += sum(
+                        id(y) in given for y in prefill_seqs[i + 1:])
+                    break
+        self.last_prefill_blocked = sum(
+            1 for s in self.running if s.remaining > 1) - len(plan.prefill)
         # NOTE: the bucketed planner's QoS decode sit-out (shed worse-class
         # decode rows when that shrank the compiled batch bucket) is gone
         # with the bucketed step itself: the packed ragged launch has no
         # padded batch bucket to shrink, so shedding rows would delay their
         # tokens without speeding the step by a single flop.
         return plan
+
+    def _prefill_shares(self, seqs: list[SeqState], budget: int,
+                        rows: int) -> list[tuple[SeqState, int]]:
+        """This step's prefill chunks, in the order they are planned.
+
+        ``seqs`` are the prompts with tokens left, oldest first (QoS class
+        first where that is on). The oldest keeps half the budget
+        (``share_prefill_budget``); the others are served fewest remaining
+        tokens first, ties in admission order, each class by itself, so
+        a short prompt's first token no longer waits for every chunk of a
+        long prompt admitted before it. The oldest is planned first, so it
+        is the first to get its blocks and is protected from the others'
+        preemptions. No starvation: the oldest prompt advances by at least
+        half the budget every step it has that many tokens left, so once a
+        prompt is the oldest its prefill takes at most twice the steps it
+        would take alone, however many shorter prompts arrive. With one
+        prompt waiting, or several that all fit the budget, the chunks are
+        those of the admission-order plan.
+        """
+        rank = ((lambda s: CLASS_RANK.get(s.priority, 1))
+                if self.args.qos_scheduling else (lambda s: 0))
+        out: list[tuple[SeqState, int]] = []
+        for _, group in itertools.groupby(seqs, key=rank):
+            # a class is a queue of its own: a worse class shares what the
+            # better ones leave once every prompt of theirs is served
+            head, *others = group
+            others.sort(key=lambda s: s.remaining)  # stable: ties by age
+            first, chunks = share_prefill_budget(
+                head.remaining, [s.remaining for s in others], budget,
+                rows - len(out))
+            out += [(s, c) for s, c in [(head, first), *zip(others, chunks)]
+                    if c]
+            budget -= first + sum(chunks)
+        return out
+
+    def _whole_prompts(self, seqs: list[SeqState], budget: int,
+                       rows: int) -> list[tuple[SeqState, int]]:
+        """Chunked prefill off: whole prompts only, in admission order; one
+        that does not fit what the budget has left waits, and a shorter one
+        behind it may still fit this step."""
+        out: list[tuple[SeqState, int]] = []
+        for s in seqs:
+            if s.remaining > self.args.max_num_batched_tokens:
+                # can never fit in one unchunked step: fail it rather
+                # than wedge the prefill queue forever
+                self.finish(s, FinishReason.ERROR)
+                s.sink.put_nowait(LLMEngineOutput(
+                    finish_reason=FinishReason.ERROR,
+                    text="prompt exceeds max_num_batched_tokens "
+                         "and chunked prefill is disabled"))
+                s.sink.put_nowait(None)
+            elif s.remaining <= budget and len(out) < rows:
+                out.append((s, s.remaining))
+                budget -= s.remaining
+        return out
 
     # -- post-step bookkeeping ----------------------------------------------
 

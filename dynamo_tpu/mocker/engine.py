@@ -21,6 +21,7 @@ import time
 from dataclasses import dataclass, field
 from typing import AsyncIterator, Optional
 
+from dynamo_tpu.engine.scheduler import share_prefill_budget
 from dynamo_tpu.protocols import FinishReason, LLMEngineOutput, PreprocessedRequest
 from dynamo_tpu.router.protocols import ForwardPassMetrics, KvStats, StoredBlock, WorkerStats
 from dynamo_tpu.router.publisher import KvEventPublisher, WorkerMetricsPublisher
@@ -108,6 +109,10 @@ class _Seq:
     @property
     def in_prefill(self) -> bool:
         return self.prefill_pos < self.isl
+
+    @property
+    def to_prefill(self) -> int:
+        return self.isl - self.prefill_pos
 
 
 class KvCacheSim:
@@ -493,17 +498,25 @@ class MockEngine:
             self.running.append(seq)
 
     async def _run_prefill_chunk(self, budget: Optional[int] = None) -> int:
+        """One step's prefill. With chunked prefill the budget is shared as
+        the real scheduler shares it (``share_prefill_budget``: fewest
+        remaining tokens first, half kept for the oldest prompt; no row cap
+        here); without it whole prompts run in admission order."""
         if budget is None:
             budget = self.args.max_num_batched_tokens
+        seqs = [s for s in self.running if s.in_prefill and not s.finished]
+        if self.args.enable_chunked_prefill and seqs:
+            others = sorted(seqs[1:], key=lambda s: s.to_prefill)
+            first, chunks = share_prefill_budget(
+                seqs[0].to_prefill, [s.to_prefill for s in others], budget,
+                len(seqs))
+            shares = [(seqs[0], first), *zip(others, chunks)]
+        else:
+            shares = [(s, s.to_prefill) for s in seqs]
         total = 0
-        for seq in self.running:
+        for seq, chunk in shares:
             if budget <= 0:
                 break
-            if not seq.in_prefill or seq.finished:
-                continue
-            chunk = min(seq.isl - seq.prefill_pos, budget) if self.args.enable_chunked_prefill else (
-                seq.isl - seq.prefill_pos
-            )
             start_block = seq.prefill_pos // self.args.block_size
             seq.prefill_pos += chunk
             budget -= chunk

@@ -138,6 +138,9 @@ class StepRecord:
     swapped: int = 0
     running: int = 0
     starved_decode: int = 0  # ready decode rows the step could not carry
+    #: admitted sequences with prompt tokens left that the plan gave no
+    #: chunk (budget, row cap or memory): the queue inside ``running``
+    prefill_blocked: int = 0
     #: rows this step sampled under a structured-decoding constraint
     #: (device FSM or host oracle) — docs/structured.md
     constrained_rows: int = 0
@@ -187,7 +190,9 @@ class StepRecord:
             "chunk_tokens": self.chunk_tokens,
             "padded_tokens": self.padded_tokens,
             "waiting": self.waiting, "swapped": self.swapped,
-            "running": self.running, "tags": list(self.tags),
+            "running": self.running,
+            "prefill_blocked": self.prefill_blocked,
+            "tags": list(self.tags),
         }
         # sparse optional fields: absent-when-zero keeps the wire/JSONL
         # compact at fleet scale (most steps are unremarkable)

@@ -344,7 +344,8 @@ def _print_timeline(name: str, entry: dict) -> None:
         extras = " ".join(
             f"{k}={rec[k]}" for k in
             ("compile_sig", "compile_s", "preempt_swap",
-             "preempt_recompute", "starved_decode", "waiting",
+             "preempt_recompute", "starved_decode", "prefill_blocked",
+             "waiting",
              "swapped", "profile_path") if rec.get(k))
         print(f"  #{rec.get('seq'):<7d} {rec.get('kind', ''):<12s} "
               f"{rec.get('wall_ms', 0.0):>9.2f}ms "
