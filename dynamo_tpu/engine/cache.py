@@ -467,6 +467,24 @@ def allocate_device_cache(cfg, num_blocks: int, block_size: int, mesh=None,
     return (ks[0], vs[0]) if len(groups) == 1 else (ks, vs)
 
 
+def allocate_state(cfg, slots: int):
+    """The recurrent-state arrays of a model with Mamba-2 layers (zeros):
+    (conv ``[L, slots + 1, (d_conv - 1) · C]``, ssm ``[L, slots + 1, H // pack,
+    N, pack · P]``) — one slot a running sequence and the dump slot padding
+    rows write to, last. None for a model without state layers. The third
+    kind of cache beside the pages: allocate it BEFORE the pool is sized,
+    so that :func:`hbm_sized_num_blocks` sees what it left."""
+    import jax.numpy as jnp
+
+    spec = cfg.state_spec
+    if spec is None:
+        return None
+    n = len(spec.layers)
+    taps, width = spec.conv_shape
+    return (jnp.zeros((n, slots + 1, taps * width), spec.conv_dtype),
+            jnp.zeros((n, slots + 1, *spec.ssm_shape), spec.ssm_dtype))
+
+
 def tree_nbytes(params) -> int:
     """Resident bytes of a params pytree (int4 packs two weights/byte on
     TPU HBM — itemsize reports 1)."""
@@ -481,8 +499,14 @@ def tree_nbytes(params) -> int:
 
 def hbm_sized_num_blocks(cfg, block_size: int, fraction: float,
                          tp_size: int = 1, default: int = 512,
-                         kv_cache_dtype: Optional[str] = None) -> int:
+                         kv_cache_dtype: Optional[str] = None,
+                         min_tokens: int = 0) -> int:
     """Size the block count from the device's free memory.
+
+    ``min_tokens``: what one sequence may need (``max_model_len``, asked by
+    a model whose state slots were allocated first): a pool that cannot
+    hold it raises with the arithmetic instead of starting a worker no
+    prompt fits.
 
     The device says what it has: ``memory_stats()`` is called plainly, and
     a device that cannot answer is an error — a pool sized from a guess
@@ -506,6 +530,16 @@ def hbm_sized_num_blocks(cfg, block_size: int, fraction: float,
         slot_bytes(cfg, g, tp_size, kv_cache_dtype)
         for g in cfg.kv_cache_spec)
     n = int(free * fraction / max(1, bytes_per_block))
+    if n * block_size < min_tokens:
+        raise RuntimeError(
+            f"the KV pool would hold {n} blocks = {n * block_size} tokens, "
+            f"fewer than the {min_tokens} one sequence may need: "
+            f"{stats['bytes_limit']} B on the device - "
+            f"{stats['bytes_in_use']} B in use (weights and, allocated "
+            f"before the pool, the state slots) = {free} B free, x "
+            f"{fraction} for the pool, / {bytes_per_block} B a block of "
+            f"{block_size} tokens; lower --max-num-seqs (state slots) or "
+            "--max-model-len")
     return max(16, n)
 
 

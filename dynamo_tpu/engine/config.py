@@ -31,6 +31,31 @@ class LayerKind(NamedTuple):
     rope_theta: float
     window: int  # 0 = full attention
     sink: bool   # learned per-head sink logit joins the softmax
+    #: what mixes tokens: "attention" (keys and values a token, in a cache
+    #: group) or "mamba2" (one fixed-size state record a SEQUENCE, in a
+    #: state slot: ``ModelConfig.state_spec``; the fields above are unused)
+    mixer: str = "attention"
+
+
+class StateSpec(NamedTuple):
+    """The per-sequence recurrent state of a model's Mamba-2 layers: one
+    record a layer a slot, whatever the sequence's length. ``conv`` is the
+    convolution's tail (the last ``d_conv - 1`` inputs), ``ssm`` the state
+    matrix of every head, stored with ``pack`` heads side by side on the
+    minor axis so that it is a whole 128-lane row (ops/mamba2.py)."""
+
+    layers: tuple      # model layer indices, in order
+    conv_shape: tuple  # (d_conv - 1, d_inner + 2·groups·d_state)
+    ssm_shape: tuple   # (heads // pack, d_state, pack · d_head)
+    conv_dtype: str
+    ssm_dtype: str
+
+    def bytes_per_slot(self) -> int:
+        import numpy as np
+
+        return len(self.layers) * (
+            int(np.prod(self.conv_shape)) * np.dtype(self.conv_dtype).itemsize
+            + int(np.prod(self.ssm_shape)) * np.dtype(self.ssm_dtype).itemsize)
 
 
 class CacheGroup(NamedTuple):
@@ -181,6 +206,31 @@ class ModelConfig:
     #: dropless; what the absent experts would add is left out. None =
     #: every expert is held.
     experts_held: Optional[tuple] = None
+    # --- Granite 4.0-H (granitemoehybrid) ---------------------------------
+    #: "rope", or "nope": attention layers turn nothing (no position term)
+    position_embedding: str = "rope"
+    #: h = embedding_multiplier · E[token]; every sublayer's output times
+    #: residual_multiplier before it joins the stream; logits divided by
+    #: logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    #: Mamba-2 mixer sizes (layer kinds whose ``mixer`` is "mamba2"): heads
+    #: of ``mamba_d_head``, a state of ``mamba_d_state`` a head channel, one
+    #: B/C group, a causal depthwise convolution of ``mamba_d_conv`` taps
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    #: dtype the state matrix is kept in between steps (assumed float32:
+    #: what engines recommend for this family's accuracy)
+    mamba_state_dtype: str = "float32"
+    #: random init only: std of the (tied) embedding's entries where fan-in
+    #: scaling would leave the logits flat, and a gain on every projection
+    #: that writes into the residual stream (wo, out_proj, the experts' and
+    #: the shared expert's down projections). None / 1.0 = fan-in scaling
+    init_embed_std: Optional[float] = None
+    init_out_gain: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -197,6 +247,12 @@ class ModelConfig:
                     f"layer_pattern {self.layer_pattern} does not name one "
                     f"of {len(self.layer_kinds)} kinds for each of "
                     f"{self.num_layers} layers")
+            mixers = [k.mixer for k in self.layer_kinds]
+            if sorted(mixers) != mixers or mixers[0] != "attention":
+                # a kind's index is its cache group's: attention kinds first
+                raise ValueError(
+                    f"layer_kinds {mixers}: attention kinds come first, "
+                    "state kinds after them")
         if self.experts_held is not None:
             first, count = self.experts_held = tuple(self.experts_held)
             if not 0 <= first < first + count <= self.num_experts:
@@ -295,7 +351,35 @@ class ModelConfig:
             CacheGroup(tuple(i for i in every if self.layer_pattern[i] == g),
                        k.num_kv_heads, self.k_cache_dim, self.v_dim, k.window,
                        self.k_lane_rows)
-            for g, k in enumerate(self.layer_kinds))
+            for g, k in enumerate(self.layer_kinds) if k.mixer == "attention")
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_head_pack(self) -> int:
+        """Heads stored side by side on the state's minor axis."""
+        return max(1, min(self.mamba_n_heads, 128 // max(1, self.mamba_d_head)))
+
+    @property
+    def state_spec(self) -> Optional[StateSpec]:
+        """The recurrent state the model keeps a sequence, or None (every
+        model whose layers are all attention layers)."""
+        if self.layer_kinds is None:
+            return None
+        layers = tuple(i for i, k in enumerate(self.layer_pattern)
+                       if self.layer_kinds[k].mixer == "mamba2")
+        if not layers:
+            return None
+        pack = self.mamba_head_pack
+        return StateSpec(
+            layers,
+            (self.mamba_d_conv - 1,
+             self.mamba_d_inner + 2 * self.mamba_d_state),
+            (self.mamba_n_heads // pack, self.mamba_d_state,
+             pack * self.mamba_d_head),
+            self.dtype, self.mamba_state_dtype)
 
     @staticmethod
     def from_hf_config(d: dict) -> "ModelConfig":

@@ -30,7 +30,7 @@ from typing import AsyncIterator, Callable, Optional
 import numpy as np
 
 from dynamo_tpu.engine.cache import (
-    BlockPool, NULL_BLOCK, SwapStore, allocate_device_cache,
+    BlockPool, NULL_BLOCK, SwapStore, allocate_device_cache, allocate_state,
     hbm_sized_num_blocks, slot_bytes, tree_nbytes,
 )
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
@@ -73,12 +73,17 @@ class _SwapEntry:
 
 def _layers_by_kind(cfg: ModelConfig) -> dict:
     """Layer counts for the ``engine built:`` line: by attention kind
-    (full / window), and dense / experts."""
+    (full / window), state layers where the model has them, and dense /
+    experts."""
     kinds = [cfg.layer_kind(i) for i in range(cfg.num_layers)]
+    attn = [k for k in kinds if k.mixer == "attention"]
     n_moe = cfg.num_layers - cfg.num_dense_prefix_layers if cfg.is_moe else 0
-    return {"full": sum(not k.window for k in kinds),
-            "window": sum(bool(k.window) for k in kinds),
-            "dense": cfg.num_layers - n_moe, "experts": n_moe}
+    out = {"full": sum(not k.window for k in attn),
+           "window": sum(bool(k.window) for k in attn),
+           "dense": cfg.num_layers - n_moe, "experts": n_moe}
+    if len(attn) < len(kinds):
+        out["mamba2"] = len(kinds) - len(attn)
+    return out
 
 
 def _has_penalties(s) -> bool:
@@ -188,6 +193,48 @@ class AsyncJaxEngine:
                     "parallelism (pp_size=%d); set speculative_tokens=0"
                     % (args.speculative_tokens, self._pp))
         groups = cfg.kv_cache_spec
+        #: recurrent state (a model with Mamba-2 layers): one slot a
+        #: running sequence, allocated BEFORE the pool is sized; None for
+        #: every other model, and nothing below then looks at state
+        self.state = None
+        self.state_bytes = 0
+        self._row_cols = 3  # columns of a step's per-row operand
+        spec = cfg.state_spec
+        if spec is not None:
+            # nothing may move or share PART of a sequence's cache: what
+            # would need the state at a boundary nobody kept is refused
+            unmet = [name for name, on in (
+                ("--kvbm-host-gb / KVBM tiers", args.kvbm_host_bytes > 0),
+                ("preempt-to-swap (pass --no-preempt-swap: a preempted "
+                 "sequence is recomputed, its slot dropped)",
+                 args.preempt_swap),
+                ("int8 KV pages", self._kv_quant),
+                ("a device mesh or pipeline stages", mesh is not None),
+                ("multi-step decode", args.multi_step_decode > 1),
+                ("speculative decoding", args.speculative_tokens > 0))
+                if on]
+            if unmet:
+                raise ValueError(
+                    f"a model with recurrent state ({len(spec.layers)} "
+                    "Mamba-2 layers) does not support: " + "; ".join(unmet))
+            if args.enable_prefix_caching:
+                logger.warning(
+                    "prefix reuse switched off: a prefix hit would need the "
+                    "recurrent state AT the hit boundary, which nobody kept "
+                    "(state snapshots are not implemented)")
+                self.args = args = args.replace(enable_prefix_caching=False)
+            self.state = allocate_state(cfg, args.max_num_seqs)
+            self.state_bytes = tree_nbytes(self.state)
+            self._row_cols = 4  # + the row's state slot
+            # what moves, shares or re-reads part of a sequence's cache
+            # refuses when called (engine/main.py refuses the disagg roles
+            # at start-up)
+            for name in ("embed", "prefill_extract", "prefill_extract_stream",
+                         "alloc_inject", "generate_prefilled",
+                         "generate_injected", "restore_probe",
+                         "export_blocks"):
+                setattr(self, name, functools.partial(self._refuse_state,
+                                                      name))
         if len(groups) > 1:
             # the block movers know one cache group (cache.is_multi_group)
             unmet = [name for name, on in (
@@ -202,7 +249,8 @@ class AsyncJaxEngine:
                     "does not support: " + "; ".join(unmet))
         nb = args.num_blocks or hbm_sized_num_blocks(
             cfg, args.block_size, args.kv_cache_memory_fraction, args.tp_size,
-            kv_cache_dtype="int8" if self._kv_quant else None)
+            kv_cache_dtype="int8" if self._kv_quant else None,
+            **({"min_tokens": args.max_model_len} if spec else {}))
         self.num_blocks = nb
         self.k_cache, self.v_cache = allocate_device_cache(
             cfg, nb, args.block_size, mesh,
@@ -259,7 +307,7 @@ class AsyncJaxEngine:
         #: (pairs, experts touched) a cache group: the next record's
         self._moe_step = np.zeros((len(groups), 2), np.int64)
         mem_after = dev.memory_stats()
-        state = (self.params, self.k_cache, self.v_cache)
+        state = (self.params, self.k_cache, self.v_cache, self.state)
         #: what was built, as one line an operator (or chip_smoke.py) reads
         self.build_facts = {
             "device": {"platform": dev.platform, "kind": dev.device_kind,
@@ -281,6 +329,12 @@ class AsyncJaxEngine:
                      cfg, g, args.tp_size,
                      "int8" if self._kv_quant else None)}
                 for g in groups],
+            **({"state_slots": args.max_num_seqs,
+                "state_bytes": self.state_bytes,
+                "state_layers": len(spec.layers),
+                "mamba": {"heads": cfg.mamba_n_heads,
+                          "d_head": cfg.mamba_d_head,
+                          "d_state": cfg.mamba_d_state}} if spec else {}),
             "bytes_in_use_before": mem_before and mem_before["bytes_in_use"],
             "bytes_in_use_after": mem_after and mem_after["bytes_in_use"],
             "bytes_limit": mem_after and mem_after["bytes_limit"],
@@ -377,7 +431,11 @@ class AsyncJaxEngine:
         #: (spec verify, MLA/TPLA, pp, multi-host, multi-step) rides the
         #: same packed layout.
         self.scheduler = Scheduler(
-            args, self.pool, on_stored=self._on_stored,
+            args, self.pool,
+            # a state model publishes no KV events: its blocks hold one
+            # layer kind's part of a prefix, which nobody can resume from
+            on_stored=self._on_stored if spec is None else None,
+            state_slots=args.max_num_seqs if spec else 0,
             onboard_cb=self._onboard if self.kvbm is not None else None,
             swapper=self if self._swap is not None else None,
             token_budget=True,
@@ -410,6 +468,9 @@ class AsyncJaxEngine:
                 use_pallas=args.use_pallas_attention,
                 replicate_logits=self._multihost,
                 kv_quant=self._kv_quant, chunks=False)
+            if self.state is not None:
+                self.ragged_fn = self._keep_state(self.ragged_fn)
+                self.ragged_dec_fn = self._keep_state(self.ragged_dec_fn)
             if self._moe_held:
                 self.ragged_fn = self._keep_moe_stats(self.ragged_fn)
                 self.ragged_dec_fn = self._keep_moe_stats(self.ragged_dec_fn)
@@ -1618,6 +1679,22 @@ class AsyncJaxEngine:
                 out[tier] = {"blocks": 0, "bytes": 0}
         return out
 
+    def _refuse_state(self, what: str, *_a, **_k):
+        raise NotImplementedError(
+            f"{what}: a model with recurrent state cannot hand over, take "
+            "in or re-read part of a sequence's cache (state snapshots; "
+            "state through disaggregated transfer and KVBM are not "
+            "implemented)")
+
+    def _keep_state(self, fn):
+        """``fn`` with the recurrent-state arrays passed last (donated) and
+        its last output, the updated arrays, kept here: callers see the
+        step of a model without state."""
+        def step(*operands):
+            *out, self.state = fn(*operands, self.state)
+            return tuple(out)
+        return step
+
     def _keep_moe_stats(self, fn):
         """``fn`` minus its fourth output (the held-experts layer's
         counters), which waits on the device until a later flight record
@@ -1712,6 +1789,9 @@ class AsyncJaxEngine:
             moe_experts_touched=sum(t for _, t in moe_step),
             moe_by_group=moe_step if self._moe_held else [],
             dead_window_pages=self._dead_window_pages(),
+            **({} if self.state is None else self._state_fields(
+                kind, decode_rows, prefill_chunks, chunk_tokens, padded,
+                decode_seqs)),
             waiting=sched.num_waiting(), swapped=len(sched.swapped),
             running=len(sched.running),
             starved_decode=(sched.last_starved_decode
@@ -1730,6 +1810,23 @@ class AsyncJaxEngine:
             rec.tags.append("ragged_fallback:" + fb)
         if self.anomaly_profiler is not None:
             self.anomaly_profiler.on_record(rec)
+
+    def _state_fields(self, kind, decode_rows, prefill_chunks, chunk_tokens,
+                      padded, decode_seqs) -> dict:
+        """A state model's flight fields: slots held, the rows whose state
+        the step moved (a pipelined step's: every row it was dispatched
+        with, finished meanwhile or not) and the step program that did."""
+        sched = self.scheduler
+        if kind == "decode_pipe":
+            decode_rows = len(decode_seqs)
+            bucket = self.args.bucket_ragged_tokens(decode_rows)
+        else:
+            bucket = decode_rows + chunk_tokens + padded
+        return {"state_slots_used": sched.state_slots - len(sched.state_free),
+                "state_rows_prefill": prefill_chunks,
+                "state_rows_decode": decode_rows,
+                "state_program": ("m" if prefill_chunks else "d")
+                + str(bucket)}
 
     def _count_wide_rows(self, rows3) -> None:
         """Called where a step's ``rows3`` is built: the rows whose q_len
@@ -1821,8 +1918,10 @@ class AsyncJaxEngine:
                 C, _ = ragged_grid_shape(T)
                 ints5 = np.zeros((5, T), np.int32)
                 ints5[3] = C
-                rows3 = np.zeros((R, 3), np.int32)
-                rows3[0] = (0, 1, 1)  # one real row attending a NULL slot
+                rows3 = np.zeros((R, self._row_cols), np.int32)
+                rows3[0, :3] = (0, 1, 1)  # one real row attending a NULL slot
+                if self.state is not None:
+                    rows3[:, 3] = args.max_num_seqs  # the dump slot
                 bt = np.full((R, W), NULL_BLOCK, np.int32)
                 gr = np.zeros((C,), np.int32)
                 if self.pp_fn is not None:
@@ -1947,7 +2046,8 @@ class AsyncJaxEngine:
         # grid_row defaults to the dump tile C (decode + padding tokens)
         ints5 = np.zeros((5, T), np.int32)
         ints5[3] = C
-        rows3 = np.zeros((R, 3), np.int32)  # q_start/q_len/kv_len; 0 = pad
+        # q_start/q_len/kv_len (0 = pad) and, a state model, the state slot
+        rows3 = np.zeros((R, self._row_cols), np.int32)
         grid_rows = np.zeros((C,), np.int32)
         bt = np.full((R, W), NULL_BLOCK, np.int32)
         mm_vec = mm_mask = None
@@ -1975,7 +2075,9 @@ class AsyncJaxEngine:
                     ints5[3, t + off:t + off + width] = tile
                     ints5[4, t + off:t + off + width] = np.arange(width)
                     tile += 1
-            rows3[i] = (t, chunk, end)
+            rows3[i, :3] = (t, chunk, end)
+            if self.state is not None:
+                rows3[i, 3] = seq.state_slot
             n = min(len(seq.block_table), W)
             bt[i, :n] = seq.block_table[:n]
             if w is not None:
@@ -2667,10 +2769,12 @@ class AsyncJaxEngine:
         ints5[1, :R] = positions[:, 0]
         ints5[2, :R] = slot_map[:, 0]
         ints5[3] = C  # every token is decode: grid dump tile
-        rows3 = np.zeros((R, 3), np.int32)
+        rows3 = np.zeros((R, self._row_cols), np.int32)
         rows3[:len(seqs), 0] = np.arange(len(seqs))
         rows3[:len(seqs), 1] = 1
         rows3[:len(seqs), 2] = kv_lens[:len(seqs)]
+        if self.state is not None:
+            rows3[:len(seqs), 3] = [s.state_slot for s in seqs]
         ints5 = jnp.asarray(ints5)
         if feed is not None:
             ints5 = ints5.at[0, :len(seqs)].set(

@@ -398,6 +398,11 @@ async def amain():
             ap.error(f"--warmup-seq-lens must be comma-separated ints, "
                      f"got {cli.warmup_seq_lens!r}")
 
+    if cfg.state_spec is not None and cli.role != "aggregated":
+        raise SystemExit(
+            f"--role {cli.role}: a model with recurrent state serves "
+            "aggregated only (disaggregated transfer would move a "
+            "sequence's KV pages without its state)")
     engine = build_engine(cli, cfg, args)  # heavy JAX work first (see above)
     if args.warmup_buckets:
         # before joining the control plane: no request can race the dummy
@@ -583,6 +588,24 @@ async def amain():
         "small tile (prompt chunks): how often its wide query tile "
         "engages").add_callback(
         lambda: {None: engine.wide_tile_rows_total})
+    if engine.state is not None:
+        # recurrent state (a model with Mamba-2 layers): one slot a running
+        # sequence beside the KV pool; a model without state has no family
+        runtime.metrics.gauge(
+            "state_slots_in_use",
+            "recurrent-state slots held by running sequences").add_callback(
+            lambda: {None: engine.scheduler.state_slots
+                     - len(engine.scheduler.state_free)})
+        runtime.metrics.counter(
+            "state_slot_wait_total",
+            "admissions that had a row and blocks, and no free "
+            "recurrent-state slot").add_callback(
+            lambda: {None: engine.scheduler.state_slot_wait_total})
+        runtime.metrics.gauge(
+            "state_bytes",
+            "device bytes of the recurrent-state arrays (every slot and "
+            "the dump slot, conv and ssm)").add_callback(
+            lambda: {None: engine.state_bytes})
     # held-experts layer (one rank's share of an expert-parallel layer):
     # how much of the routing lands here, and on which experts
     runtime.metrics.counter(

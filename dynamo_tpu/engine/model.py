@@ -89,8 +89,15 @@ class _LazyLeaf:
     #: the nearly flat logits of random weights, then takes another path
     #: (PERF.md section 7)
     head_major: bool = False
+    #: added to every entry after the draw (a leaf whose neutral value is
+    #: not 0: a normal leaf around ``offset``), by an op of its own so that
+    #: the programs of leaves without one stay what they were
+    offset: float = 0.0
 
     def build(self, sharding=None):
+        if self.offset:
+            w = dataclasses.replace(self, offset=0.0).build(sharding)
+            return w + jnp.asarray(self.offset, w.dtype)
         if self.head_major:
             n, heads, width, d = self.shape
             drawn = None if sharding is None else NamedSharding(
@@ -182,10 +189,29 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
         "attn_norm": ones((n, D)),
         "mlp_norm": ones((n, D)),
     }
+    #: fan-in of a projection that writes into the residual stream
+    out_fan = lambda k: k / cfg.init_out_gain ** 2  # noqa: E731
     if cfg.sandwich_norms:  # Gemma-2 post-norms on sublayer outputs
         layers["post_attn_norm"] = ones((n, D))
         layers["post_mlp_norm"] = ones((n, D))
-    if cfg.is_mla:
+    if kind is not None and kind.mixer == "mamba2":
+        # nothing at its neutral value, so that a path which forgets a
+        # term computes something else: a = exp(-dt·exp(A_log)) spreads
+        # over about (0.5, 0.999) with dt = softplus(N(0, 1) + dt_bias)
+        Hm, di = cfg.mamba_n_heads, cfg.mamba_d_inner
+        Cw = di + 2 * cfg.mamba_d_state
+        f32 = functools.partial(_normal_leaf, dtype=jnp.float32)
+        around = lambda leaf, c: dataclasses.replace(  # noqa: E731
+            leaf, offset=c)
+        layers["in_proj"] = w(ks[0], (n, D, di + Cw + Hm), D)
+        layers["conv_w"] = w(ks[1], (n, cfg.mamba_d_conv, Cw), 2)
+        layers["conv_b"] = w(ks[2], (n, Cw), 25)         # std 0.2
+        layers["dt_bias"] = around(f32(ks[3], (n, Hm), 1), -3.0)
+        layers["A_log"] = f32(ks[9], (n, Hm), 2)         # std 0.7
+        layers["D"] = around(f32(ks[10], (n, Hm), 4), 1.0)
+        layers["ssm_norm"] = around(w(ks[11], (n, di), 25), 1.0)
+        layers["out_proj"] = w(ks[15], (n, di, D), out_fan(di))
+    elif cfg.is_mla:
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
         if cfg.q_lora_rank:
@@ -204,7 +230,7 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
         layers["wq"] = by_heads(ks[0], H, hd)
         layers["wk"] = by_heads(ks[1], KV, hd)
         layers["wv"] = by_heads(ks[2], KV, vd)
-        layers["wo"] = w(ks[3], (n, H * vd, D), H * vd)
+        layers["wo"] = w(ks[3], (n, H * vd, D), out_fan(H * vd))
         if cfg.qkv_bias:
             layers["bq"] = zeros((n, H * hd))
             layers["bk"] = zeros((n, KV * hd))
@@ -225,7 +251,7 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
             if kind else zeros((n, E), dtype=jnp.float32))
         layers["w_gate"] = w(ks[5], (n, Eh, D, Fm), D)
         layers["w_up"] = w(ks[6], (n, Eh, D, Fm), D)
-        layers["w_down"] = w(ks[7], (n, Eh, Fm, D), Fm)
+        layers["w_down"] = w(ks[7], (n, Eh, Fm, D), out_fan(Fm))
         if cfg.moe_activation == "swiglu_oss":
             layers["b_gate"] = zeros((n, E, Fm))
             layers["b_up"] = zeros((n, E, Fm))
@@ -234,7 +260,7 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
             Fs = cfg.n_shared_experts * Fm
             layers["ws_gate"] = w(ks[12], (n, D, Fs), D)
             layers["ws_up"] = w(ks[13], (n, D, Fs), D)
-            layers["ws_down"] = w(ks[14], (n, Fs, D), Fs)
+            layers["ws_down"] = w(ks[14], (n, Fs, D), out_fan(Fs))
     else:
         layers["w_gate"] = w(ks[5], (n, D, F), D)
         layers["w_up"] = w(ks[6], (n, D, F), D)
@@ -264,7 +290,8 @@ def layer_stacks(cfg: ModelConfig) -> tuple:
 
 def _layer_runs(cfg: ModelConfig) -> list:
     """Runs of consecutive layers of one stack, in model order: (stack,
-    offset in the stack, layers, offset in the kind's cache group)."""
+    offset in the stack, layers, offset in the kind's cache group — a
+    Mamba-2 kind's: in the state arrays)."""
     stacks = layer_stacks(cfg)
     where = {i: (s, st.layers.index(i))
              for s, st in enumerate(stacks) for i in st.layers}
@@ -274,8 +301,10 @@ def _layer_runs(cfg: ModelConfig) -> list:
         if runs and runs[-1][0] == s and runs[-1][1] + runs[-1][2] == off:
             runs[-1][2] += 1
         else:
-            group = cfg.kv_cache_spec[stacks[s].kind]
-            runs.append([s, off, 1, group.layers.index(i)])
+            kind = cfg.layer_kinds[stacks[s].kind]
+            home = (cfg.state_spec if kind.mixer == "mamba2"
+                    else cfg.kv_cache_spec[stacks[s].kind])
+            runs.append([s, off, 1, home.layers.index(i)])
     return [tuple(r) for r in runs]
 
 
@@ -306,7 +335,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None, *,
     w = functools.partial(_normal_leaf, dtype=dtype)
 
     lazy = {
-        "embed": w(ks[0], (V, D), D),
+        "embed": w(ks[0], (V, D), D if cfg.init_embed_std is None
+                   else cfg.init_embed_std ** -2),
         "final_norm": _const_leaf(1.0, (D,), dtype=dtype),
     }
     if cfg.layer_kinds is not None:
@@ -1609,7 +1639,8 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             use_pallas: bool = False, use_flash_prefill: bool = False,
             mesh: Optional[Mesh] = None, all_logits: bool = False,
             return_hidden: bool = False, mm_vec=None, mm_mask=None,
-            ragged=None, moe_stats: bool = False, moe_routing: bool = False):
+            ragged=None, moe_stats: bool = False, moe_routing: bool = False,
+            state=None):
     """One engine step.
 
     Args:
@@ -1641,8 +1672,28 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     [cache groups, :func:`moe_stats_width`] as a fourth;
     ``moe_routing`` adds every expert layer's choices [L_moe, B·S, K] as a
     fifth (chipbench/check_reference.py tells them to the reference).
+
+    A model with Mamba-2 layers (``cfg.state_spec``) runs the ragged step
+    only: ``state`` is its (conv, ssm) arrays (ops/mamba2.py), donated and
+    returned LAST, and ``rows3`` carries each row's state slot as a fourth
+    column.
     """
     B, S = tokens.shape
+    spec = cfg.state_spec
+    if spec is not None:
+        if ragged is None or state is None or mesh is not None:
+            raise NotImplementedError(
+                "a model with recurrent state runs the ragged step on one "
+                "chip only (no bucketed, multi-step, verify or embed "
+                "program, no mesh)")
+        rows4 = ragged[0]
+        ragged = (rows4[:, :3],) + tuple(ragged[1:])
+    rm = cfg.residual_multiplier
+
+    def _res(x, y):
+        """x + y, the sublayer's output times the residual multiplier."""
+        return x + y if rm == 1.0 else x + y * jnp.asarray(rm, y.dtype)
+
     D, hd, vd = cfg.hidden_size, cfg.head_dim, cfg.v_dim
     H = cfg.num_heads
     from dynamo_tpu.engine.cache import gather_pages, is_quant_cache
@@ -1668,6 +1719,8 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         # Gemma: embeddings scale by sqrt(D); NOT folded into the weights
         # (the tied lm_head reads them unscaled)
         x = x * jnp.asarray(np.sqrt(D), x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     if mm_vec is not None:
         # multimodal: positions under mm_mask take externally-provided
         # embeddings (llava-style placeholder substitution)
@@ -1718,10 +1771,11 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         if cfg.qk_norm:  # Qwen3: per-head RMSNorm before RoPE
             q = _rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
             k = _rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
-        q = _rope(q, positions, kind.rope_theta, cfg.rope_scaling,
-                  cfg.rotary_dim)
-        k = _rope(k, positions, kind.rope_theta, cfg.rope_scaling,
-                  cfg.rotary_dim)
+        if cfg.position_embedding != "nope":
+            q = _rope(q, positions, kind.rope_theta, cfg.rope_scaling,
+                      cfg.rotary_dim)
+            k = _rope(k, positions, kind.rope_theta, cfg.rope_scaling,
+                      cfg.rotary_dim)
         if cfg.k_cache_dim != hd:
             # a wide K head is stored as whole lane rows; the zeros add
             # nothing to a score, and every reader pads or cuts q to match
@@ -1913,18 +1967,50 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         if cfg.sandwich_norms:  # Gemma-2: post-norm on the sublayer OUTPUT
             attn_out = _rms_norm(attn_out, lp["post_attn_norm"],
                                  cfg.rms_norm_eps)
-        x = x + attn_out
+        x = _res(x, attn_out)
         return _mlp_epilogue(x, kc, vc, st, lp, moe, experts, group)
 
-    def _mlp_epilogue(x, kc, vc, st, lp, moe, experts=None, group=0):
+    def make_mamba_layer(moe: bool, lps, experts=None, tag_group=0, run=""):
+        """The scan body of a run of Mamba-2 layers of the stack ``lps``:
+        the state arrays ride the carry (updated in place at the layer's
+        index), the KV caches stay outside."""
+        from dynamo_tpu.ops.mamba2 import mamba2_ragged
+
+        di = cfg.mamba_d_inner
+        cw = di + 2 * cfg.mamba_d_state
+
+        def layer(carry, xs):
+            x, conv, ssm, st = carry
+            in_stack, lidx = xs
+            lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+                a, in_stack, keepdims=False), lps)
+            lp["layer_in_stack"] = in_stack
+            h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+            zxd = _mm(h, lp["in_proj"])[0]             # [T, di + cw + H]
+            y, conv, ssm = mamba2_ragged(
+                zxd[:, di:di + cw], zxd[:, di + cw:], lp, conv, ssm, lidx,
+                rows4, positions[0], cfg=cfg, chunks=ragged[3] is not None,
+                tag=f"_{run}_{program}")
+            # the gate first, then the norm, over all of d_inner
+            y = y * jax.nn.silu(zxd[:, :di].astype(jnp.float32))
+            y = _rms_norm(y.astype(x.dtype), lp["ssm_norm"], cfg.rms_norm_eps)
+            x = _res(x, _mm(y[None], lp["out_proj"]))
+            (x, _, _, st), ids = _mlp_epilogue(x, None, None, st, lp, moe,
+                                               experts, 0, tag_group)
+            return (x, conv, ssm, st), ids
+        return layer
+
+    def _mlp_epilogue(x, kc, vc, st, lp, moe, experts=None, group=0,
+                      tag_group=None):
         tp_n = mesh.shape.get("tp", 1) if mesh is not None else 1
+        tag_group = group if tag_group is None else tag_group
         h = _rms_norm(x, lp["mlp_norm"], cfg.rms_norm_eps)
         ids = None
         if moe and held:
             y, counted, ids = _mlp_moe_held(h.reshape(B * S, D), lp, cfg,
                                             tok_valid, experts,
-                                            tag=f"_g{group}_{program}")
-            x = x + y.reshape(B, S, D)
+                                            tag=f"_g{tag_group}_{program}")
+            x = _res(x, y.reshape(B, S, D))
             st = st.at[group].add(counted)
             ids = ids if moe_routing else None
         elif moe:
@@ -1960,9 +2046,9 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 out = _rms_norm(out, lp["post_mlp_norm"], cfg.rms_norm_eps)
             x = x + out
         if moe and cfg.n_shared_experts:  # DeepSeek: dense shared experts
-            x = x + _mlp_dense(h, {"w_gate": lp["ws_gate"],
-                                   "w_up": lp["ws_up"],
-                                   "w_down": lp["ws_down"]})
+            x = _res(x, _mlp_dense(h, {"w_gate": lp["ws_gate"],
+                                       "w_up": lp["ws_up"],
+                                       "w_down": lp["ws_down"]}))
         return (x, kc, vc, st), ids
 
     k_dense = cfg.num_dense_prefix_layers
@@ -1981,13 +2067,27 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 # a layer's blocks out of the stack itself
                 experts = {k: lps.pop(k)
                            for k in ("w_gate", "w_up", "w_down")}
-            if n != len(stack.layers):
-                lps = jax.tree.map(lambda a: a[off:off + n], lps)
-            lps["layer_in_stack"] = off + jnp.arange(n)
-            carry, ids = jax.lax.scan(
-                make_layer(stack.moe, cfg.layer_kinds[stack.kind],
-                           stack.kind, experts),
-                carry, (lps, g_off + jnp.arange(n)))
+            if cfg.layer_kinds[stack.kind].mixer == "mamba2":
+                # a run that is part of its stack reads its layers out of
+                # the WHOLE stack by index (a slice of the stack handed to
+                # the scan is copied first, every step: 1.2 GB of in_proj)
+                x, kcs, vcs, st = carry
+                (x, *state, st), ids = jax.lax.scan(
+                    make_mamba_layer(stack.moe, lps, experts, stack.kind,
+                                     run=f"l{g_off}x{n}"),
+                    (x, *state, st),
+                    (off + jnp.arange(n), g_off + jnp.arange(n)))
+                carry = (x, kcs, vcs, st)
+            else:
+                if n != len(stack.layers):
+                    lps = jax.tree.map(lambda a: a[off:off + n], lps)
+                lps["layer_in_stack"] = off + jnp.arange(n)
+                carry, ids = jax.lax.scan(
+                    make_layer(stack.moe, cfg.layer_kinds[stack.kind],
+                               # one cache group: bare arrays, not tuples
+                               stack.kind if len(cfg.kv_cache_spec) > 1
+                               else None, experts),
+                    carry, (lps, g_off + jnp.arange(n)))
             if ids is not None:
                 routing.append(ids)
     else:
@@ -2023,11 +2123,15 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     else:
         x_last = x[jnp.arange(B), last_idx]  # [B, D]
     logits = _cap(_mm(x_last, head).astype(jnp.float32))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    out = (logits, k_cache, v_cache)
     if moe_stats and held:
+        out += (stats,)
         if moe_routing:
-            return logits, k_cache, v_cache, stats, jnp.concatenate(routing)
-        return logits, k_cache, v_cache, stats
-    return logits, k_cache, v_cache
+            out += (jnp.concatenate(routing),)
+    # the state arrays last, whatever comes before them
+    return out if spec is None else out + (tuple(state),)
 
 
 def make_verify_fn(cfg: ModelConfig, block_size: int,
@@ -2289,7 +2393,9 @@ def ragged_fallback_reason(cfg: ModelConfig, mesh: Optional[Mesh],
     a degraded launch is never silent. Returns None as well when Pallas
     was never requested (a config choice, not a degrade) and for MLA
     models (the latent ragged walk is their designed path, not a
-    fallback)."""
+    fallback). A Mamba-2 layer kind has no cache group (its state lies in
+    slots, ``cfg.state_spec``) and takes no gate here: only the attention
+    kinds beside it can name a reason."""
     from dynamo_tpu.ops.ragged_attention import (
         ragged_int8_kernel_supported, ragged_pallas_supported,
     )
@@ -2510,11 +2616,19 @@ def make_ragged_step_fn(cfg: ModelConfig, block_size: int,
     ``moe_routing``, its routers' choices as a fifth.
     """
     decode_pallas, _ = _resolve_kernel_flags(cfg, mesh, use_pallas, False)
+    stateful = cfg.state_spec is not None
+    if stateful and mm:
+        raise NotImplementedError("multimodal rows in a state model's step")
 
     def f(params, ints5, rows3, grid_rows, block_tables, *rest):
+        state = None
         if mm:
             mm_vec, mm_mask, k_cache, v_cache = rest
             mm_vec, mm_mask = mm_vec[None], mm_mask[None]
+        elif stateful:
+            # rows3 is [R, 4] here: the fourth column is the state slot
+            k_cache, v_cache, state = rest
+            mm_vec = mm_mask = None
         else:
             k_cache, v_cache = rest
             mm_vec = mm_mask = None
@@ -2526,13 +2640,15 @@ def make_ragged_step_fn(cfg: ModelConfig, block_size: int,
             cfg=cfg, block_size=block_size, use_pallas=decode_pallas,
             mesh=mesh, mm_vec=mm_vec, mm_mask=mm_mask, moe_stats=True,
             moe_routing=moe_routing, ragged=(rows3, ints5[3], ints5[4],
-                    grid_rows if chunks else None))
+                    grid_rows if chunks else None),
+            **({"state": state} if stateful else {}))
 
     kw = {}
     if replicate_logits and mesh is not None:
         csh = cache_shardings(mesh, cfg, quant=kv_quant)
         kw["out_shardings"] = (NamedSharding(mesh, P()), csh, csh)
-    return jax.jit(f, donate_argnums=(7, 8) if mm else (5, 6), **kw)
+    donate = (7, 8) if mm else (5, 6, 7) if stateful else (5, 6)
+    return jax.jit(f, donate_argnums=donate, **kw)
 
 
 def make_step_fn(cfg: ModelConfig, block_size: int, mesh: Optional[Mesh] = None,

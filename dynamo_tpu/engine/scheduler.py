@@ -104,6 +104,11 @@ class SeqState:
     qos_arrival: Optional[int] = None  # global arrival stamp (ClassQueues)
     swap_in_attempts: int = 0     # consecutive failed swap-in reservations
     parked_t: float = 0.0         # when the seq entered the swapped queue
+    #: recurrent-state slot of a model with state layers: taken at
+    #: admission, given back at finish, abort and recompute preemption
+    #: (None = none held; models without state never set it)
+    state_slot: Optional[int] = None
+    state_waited: bool = False  # counted once in state_slot_wait_total
 
     @property
     def remaining(self) -> int:
@@ -173,9 +178,21 @@ class Scheduler:
                  onboard_cb: Optional[Callable] = None,
                  swapper: Optional[object] = None,
                  token_budget: bool = True,
-                 hot_cb: Optional[Callable] = None):
+                 hot_cb: Optional[Callable] = None,
+                 state_slots: int = 0):
         self.args = args
         self.pool = pool
+        #: free recurrent-state slots (engine/cache.py:allocate_state), or
+        #: None for a model without state layers: nothing below looks at
+        #: slots then. A sequence that starts at position 0 starts from
+        #: zeros whatever its slot holds (ops/mamba2.py), so a slot is a
+        #: binding only: nothing is zeroed or moved when it changes hands.
+        self.state_free: Optional[list] = (
+            list(range(state_slots - 1, -1, -1)) if state_slots else None)
+        self.state_slots = state_slots
+        #: admissions that had blocks and a row, and no slot →
+        #: dynamo_state_slot_wait_total
+        self.state_slot_wait_total = 0
         #: ragged-step planning (docs/performance.md), the ONLY planning
         #: mode: the step is ONE packed launch, so plan() budgets TOKENS
         #: (prefill chunks + decode rows co-scheduled under
@@ -562,11 +579,27 @@ class Scheduler:
         self._flush_stored(seq)
         if seq in self.running:
             self.running.remove(seq)
+        self._drop_state_slot(seq)
         if seq.swap is not None and self.swapper is not None:
             self.swapper.swap_drop(seq)
         if not seq.hold_blocks:
             self.pool.release(seq.block_table)
             seq.block_table = []
+
+    def _note_slot_wait(self, seq: SeqState) -> None:
+        """``seq`` could not be admitted: counted, once a sequence, if what
+        it lacks is a state slot (every slot, one a row, is held) and not
+        blocks."""
+        if (not self.state_free and not seq.state_waited
+                and self.pool.num_free_blocks
+                > max(1, self.args.watermark * self.pool.num_blocks)):
+            seq.state_waited = True
+            self.state_slot_wait_total += 1
+
+    def _drop_state_slot(self, seq: SeqState) -> None:
+        if seq.state_slot is not None:
+            self.state_free.append(seq.state_slot)
+            seq.state_slot = None
 
     def release_held(self, seq: SeqState) -> None:
         """Free the blocks of a finished hold_blocks sequence."""
@@ -797,6 +830,8 @@ class Scheduler:
             # each call shrinks running and the loop is bounded.
             while len(self.running) >= self.args.max_num_seqs:
                 if not self._make_room_for(seq):
+                    if self.state_free is not None:
+                        self._note_slot_wait(seq)
                     return
             # watermark: keep a fraction of blocks free (ref: mocker watermark)
             needed_first = max(1, min(len(seq.tokens), bs) // bs + 1)
@@ -805,6 +840,11 @@ class Scheduler:
                        < self.args.watermark * self.pool.num_blocks)):
                 if not self._make_room_for(seq):
                     return
+            if self.state_free is not None:
+                if not self.state_free:
+                    self._note_slot_wait(seq)
+                    return
+                seq.state_slot = self.state_free.pop()
             self.waiting.remove(seq)
             self.qos.note_queue_wait(seq.tenant, seq.priority,
                                      max(0.0, now - seq.qos_enqueue_t))
@@ -926,6 +966,7 @@ class Scheduler:
             self.recomputed_tokens_total += seq.num_computed
         self.pool.release(seq.block_table)
         seq.block_table = []
+        self._drop_state_slot(seq)  # dropped, not swapped: recompute
         self._reset_for_recompute(seq)
         seq.preemptions += 1
         if seq in self.running:

@@ -194,6 +194,63 @@ def mimo_tiny(experts_held=(0, 8)) -> ModelConfig:
         max_position_embeddings=512, dtype="float32")
 
 
+def _granite4_h(*, vocab_size: int, pattern: str, experts_held,
+                **sizes) -> ModelConfig:
+    """Granite 4.0-H (``granitemoehybrid``): Mamba-2 mixers ("m") and NoPE
+    attention layers ("a") in the published order, every layer followed by
+    softmax-routed experts (the top-k LOGITS' softmax) beside an ungated
+    shared expert; embedding, residual, attention and logit multipliers are
+    keys of the config; the head is the embedding, transposed."""
+    sizes = dict(
+        hidden_size=4096, num_heads=32, head_dim=128,
+        layer_kinds=((8, 0.0, 0, False), (0, 0.0, 0, False, "mamba2")),
+        query_pre_attn_scalar=128.0 ** 2,  # scores x attention_multiplier
+        mamba_n_heads=128, mamba_d_head=64, mamba_d_state=128,
+        mamba_d_conv=4, intermediate_size=1536,
+        num_experts=72, num_experts_per_tok=10, moe_intermediate_size=768,
+        n_shared_experts=2,  # shared_intermediate_size 1536 = 2 x 768
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        logits_scaling=16.0, init_embed_std=0.25, init_out_gain=4.0,
+        max_position_embeddings=131072) | sizes
+    return ModelConfig(
+        vocab_size=vocab_size, num_layers=len(pattern),
+        num_kv_heads=sizes["layer_kinds"][0][0],
+        layer_pattern=tuple("am".index(c) for c in pattern),
+        position_embedding="nope", tie_word_embeddings=True,
+        rms_norm_eps=1e-5, scoring_func="softmax", norm_topk_prob=True,
+        experts_held=experts_held, **sizes)
+
+
+def granite4_h_small_ep2() -> ModelConfig:
+    """One chip's share of Granite-4.0-H-Small where 2 chips share each
+    layer of a pipeline stage: the first period of ten layers (nine Mamba-2
+    mixers, one attention layer), 36 of the 72 experts, half the vocabulary
+    (chipbench/configs/granite4-h-small-ep2.json has the arithmetic)."""
+    # tied to a 12 x embedding, the head scores the token a row just read
+    # at 64 x the cosine between the final stream and that embedding (in
+    # units of the other logits' sd): with sublayers at fan-in scale every
+    # greedy pick repeats its input (gap 41, first chip run). At 64 x
+    # fan-in the sublayers' sum is ~17 x the embedding and the picks vary
+    return _granite4_h(vocab_size=50176, pattern="mmmmmammmm",
+                       experts_held=(0, 36), init_out_gain=64.0)
+
+
+def granite4_tiny(experts_held=(0, 4), pattern="mmammm") -> ModelConfig:
+    """Granite 4.0-H's shape at test size: Mamba-2 runs either side of an
+    attention layer, G = 2, 8 experts top-3 of which 4 are held, a shared
+    expert twice an expert's width, float32."""
+    return _granite4_h(
+        vocab_size=256, pattern=pattern, experts_held=experts_held,
+        hidden_size=64, num_heads=4, head_dim=16,
+        layer_kinds=((2, 0.0, 0, False), (0, 0.0, 0, False, "mamba2")),
+        query_pre_attn_scalar=32.0 ** 2,
+        mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16,
+        intermediate_size=64, num_experts=8, num_experts_per_tok=3,
+        moe_intermediate_size=32, max_position_embeddings=512,
+        logits_scaling=2.0,  # logits of sd about 1 at this hidden size
+        dtype="float32")
+
+
 PRESETS = {
     "tiny": ModelConfig.tiny,
     "moe_tiny": moe_tiny,
@@ -213,14 +270,17 @@ PRESETS = {
     "gpt_oss_120b": gpt_oss_120b,
     "mimo_tiny": mimo_tiny,
     "mimo_v25_ep16": mimo_v25_ep16,
+    "granite4_tiny": granite4_tiny,
+    "granite4_h_small_ep2": granite4_h_small_ep2,
 }
 
 #: architectures the forward pass does NOT cover yet (listed so callers
 #: fail loudly instead of serving wrong numerics). DeepSeek V2/V3 (MLA)
 #: graduated from this map in round 2 — engine/model.py:_mla_attention.
 UNSUPPORTED = {
-    "MambaForCausalLM": "state-space layers not implemented",
-    "JambaForCausalLM": "state-space layers not implemented",
+    "MambaForCausalLM": "no preset maps its config (Mamba-2 mixers run in "
+                        "granite4_h_small_ep2; Mamba-1's scan does not)",
+    "JambaForCausalLM": "no preset maps its config (Mamba-1 mixers)",
 }
 
 

@@ -55,3 +55,48 @@ def mimo_v2_inputs(cfg, params) -> tuple[dict, dict]:
                "final_norm": params["final_norm"],
                "lm_head": params["lm_head"]}
     return weights, hp
+
+
+def granite4_h_inputs(cfg, params, consume: bool = False
+                      ) -> tuple[dict, dict]:
+    """(weights, hp) for ``reference.granite4_h.forward`` from a
+    ModelConfig with a Mamba-2 kind and its ``init_params`` pytree.
+    ``consume``: take each stacked leaf OUT of ``params`` as it is cut into
+    its layers, so that the stacks and their cuts are never both whole on
+    the device (9.5 GB each at the published widths)."""
+    from dynamo_tpu.engine.model import layer_stacks
+    from dynamo_tpu.engine.quant import HEAD_MAJOR_KEYS
+
+    layers: list = [{} for _ in range(cfg.num_layers)]
+    for stack, lps in zip(layer_stacks(cfg), params["stacks"]):
+        for k in list(lps):
+            a = lps.pop(k) if consume else lps[k]
+            for j, i in enumerate(stack.layers):
+                w = a[j]
+                if k in HEAD_MAJOR_KEYS:
+                    # the engine keeps [heads, width, D]; the reference
+                    # reads the published x @ W orientation
+                    w = w.reshape(-1, w.shape[-1]).T
+                layers[i][k] = w
+            del a
+    hp = {
+        "hidden_size": cfg.hidden_size,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.layer_kinds[0].num_kv_heads,
+        "attention_multiplier": cfg.query_pre_attn_scalar ** -0.5,
+        "embedding_multiplier": cfg.embedding_multiplier,
+        "residual_multiplier": cfg.residual_multiplier,
+        "logits_scaling": cfg.logits_scaling,
+        "layer_types": [("attention", "mamba")[k]
+                        for k in cfg.layer_pattern],
+        "mamba_n_heads": cfg.mamba_n_heads,
+        "mamba_d_head": cfg.mamba_d_head,
+        "mamba_d_state": cfg.mamba_d_state,
+        "mamba_d_conv": cfg.mamba_d_conv,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "rms_norm_eps": cfg.rms_norm_eps,
+        "experts_held": list(cfg.experts_held or (0, cfg.num_experts)),
+    }
+    weights = {"embed": params["embed"], "layers": layers,
+               "final_norm": params["final_norm"]}
+    return weights, hp

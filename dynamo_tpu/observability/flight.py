@@ -157,6 +157,16 @@ class StepRecord:
     #: pages of window cache groups that lie wholly behind their
     #: sequence's window: what releasing them would free
     dead_window_pages: int = 0
+    #: a model with recurrent state (engine/cache.py:allocate_state): slots
+    #: held by running sequences after the step, and the rows whose state
+    #: the step moved — prefill chunks (the chunked scan) and decode rows
+    #: (one update each); all absent for a model without state layers
+    state_slots_used: int = 0
+    state_rows_prefill: int = 0
+    state_rows_decode: int = 0
+    #: which step program moved them, as the update kernel's op names have
+    #: it: "d" (decode-only) or "m" (mixed) and the token bucket
+    state_program: str = ""
     kv_tiers: dict = field(default_factory=dict)  # {g1..g4: blocks}
     onboard_inflight: int = 0
     restore_inflight: int = 0
@@ -205,7 +215,8 @@ class StepRecord:
                   "swap_in_blocks", "starved_decode", "onboard_inflight",
                   "restore_inflight", "constrained_rows", "wide_tile_rows",
                   "moe_pairs", "moe_experts_touched", "dead_window_pages",
-                  "profile_path"):
+                  "state_slots_used", "state_rows_prefill",
+                  "state_rows_decode", "state_program", "profile_path"):
             v = getattr(self, k)
             if v:
                 d[k] = v

@@ -329,6 +329,79 @@ def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
     assert not moved, moved
 
 
+@pytest.mark.parametrize("T", [64, 2048], ids=["decode_only", "mixed"])
+def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
+        compile_for_chip, T):
+    """The whole jitted ragged step of the Granite-4.0-H-Small share the
+    benchmark runs (models.granite4_h_small_ep2: ten layers, published
+    widths, 64 state slots): the update kernel, the ragged kernel and the
+    grouped matmul are Mosaic calls; the SSM state stack (2.45 GB) and the
+    page pool are updated in place — no op copies either — and no op
+    launched on its own slices or copies a layer's ``in_proj``,
+    ``out_proj`` or experts out of their stacks (a run of layers that is
+    part of its stack reads it by index, where a sliced stack was copied
+    whole: 1.2 GB of ``in_proj`` a step)."""
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.models import granite4_h_small_ep2
+
+    cfg = granite4_h_small_ep2()
+    args = EngineArgs(max_num_seqs=64, max_num_batched_tokens=2048,
+                      max_model_len=8192)
+    nb, slots = 8192, args.max_num_seqs + 1
+    R, W = args.ragged_rows(T), args.max_blocks_per_seq
+    C, _ = M.ragged_grid_shape(T)
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.key(0)))
+    (g,), st = cfg.kv_cache_spec, cfg.state_spec
+    kc = spec((1, nb * BS, *g.k_shape), jnp.bfloat16)
+    vc = spec((1, nb * BS, g.kv_heads, g.v_dim), jnp.bfloat16)
+    n = len(st.layers)
+    state = (spec((n, slots, st.conv_shape[0] * st.conv_shape[1]),
+                  jnp.bfloat16),
+             spec((n, slots, *st.ssm_shape), jnp.float32))
+    step = M.make_ragged_step_fn(cfg, BS, None, use_pallas=True,
+                                 chunks=T > 64)
+    with mock.patch("dynamo_tpu.ops.grouped_matmul.kernel_interpret_mode",
+                    return_value=False), \
+         mock.patch("dynamo_tpu.ops.mamba2.kernel_interpret_mode",
+                    return_value=False):
+        text = compile_for_chip(
+            step, params, spec((5, T), jnp.int32), spec((R, 4), jnp.int32),
+            spec((C,), jnp.int32), spec((R, W), jnp.int32), kc, vc, state)
+    program = ("m" if T > 64 else "d") + str(T)
+    for run in ("l0x5", "l5x4"):  # one launch a run of Mamba-2 layers
+        assert f"mamba2_decode_update_{run}_{program}" in text
+    assert "ragged_paged_attention" in text
+    assert "moe_grouped_matmul" in text
+    lines = text.splitlines()
+    ssm = "f32[" + ",".join(map(str, (n, slots, *st.ssm_shape))) + "]"
+    pool = f"[1,{nb * BS},8,128]"
+    copies = [ln.strip()[:120] for ln in lines
+              if (" copy(" in ln or " copy-start(" in ln)
+              and (ssm in ln.split("(")[0] or pool in ln.split(" copy")[0])]
+    assert not copies, copies[:2]
+    # a layer's matrix leaves its stack only as an operand of its own dot
+    D, di = cfg.hidden_size, cfg.mamba_d_inner
+    wide = di + st.conv_shape[1] + cfg.mamba_n_heads
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    moved, inside = [], False
+    for ln in lines:
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            inside = head.group(1) in fused
+            continue
+        op = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = bf16\[(?:\d+,)?(\d+),(\d+)\]"
+                      r"\S* (?:copy|fusion|slice|dynamic-slice)\(", ln)
+        if op and not inside and (int(op.group(1)), int(op.group(2))) in (
+                (D, wide), (di, D)):
+            moved.append(ln.strip()[:120])
+    assert not moved, moved[:2]
+    F, Eh = cfg.moe_ffn_size, cfg.num_experts_held
+    sliced = [ln for ln in lines
+              if f" = bf16[{Eh},{D},{F}]" in ln or f" = bf16[{Eh},{F},{D}]" in ln]
+    assert not sliced, sliced[:2]
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_init_leaf_never_holds_a_float32_copy_on_v5e(compile_for_chip,
                                                      quantized):
