@@ -296,16 +296,18 @@ class AsyncJaxEngine:
                          if kernel_interpret_mode()
                          else "pallas: ragged kernel (Mosaic)")
         #: held-experts layer counters (model.moe_stats_width), read with
-        #: the step's other outputs: dynamo_moe_assignments_total{to} and
-        #: dynamo_moe_expert_tokens_total{expert}
+        #: the step's other outputs: dynamo_moe_assignments_total{to},
+        #: dynamo_moe_expert_tokens_total{expert}, dynamo_moe_row_tiles_total
         self._moe_held = cfg.is_moe and cfg.experts_held is not None
         self.moe_assignments_total = (
             {"all": 0, "held": 0} if self._moe_held else {})
         self.moe_expert_tokens_total = np.zeros(
             (cfg.num_experts_held if self._moe_held else 0,), np.int64)
+        self.moe_row_tiles_total = 0
         self._moe_pending: collections.deque = collections.deque()
-        #: (pairs, experts touched) a cache group: the next record's
-        self._moe_step = np.zeros((len(groups), 2), np.int64)
+        #: (pairs, experts touched, row tiles) a cache group: the next
+        #: record's
+        self._moe_step = np.zeros((len(groups), 3), np.int64)
         mem_after = dev.memory_stats()
         state = (self.params, self.k_cache, self.v_cache, self.state)
         #: what was built, as one line an operator (or chip_smoke.py) reads
@@ -1716,8 +1718,9 @@ class AsyncJaxEngine:
             stats = np.asarray(self._moe_pending.popleft())  # [groups, ·]
             self.moe_assignments_total["all"] += int(stats[:, 0].sum())
             self.moe_assignments_total["held"] += int(stats[:, 1].sum())
-            self.moe_expert_tokens_total += stats[:, 3:].sum(0)
-            self._moe_step += stats[:, 1:3]
+            self.moe_expert_tokens_total += stats[:, 4:].sum(0)
+            self.moe_row_tiles_total += int(stats[:, 3].sum())
+            self._moe_step += stats[:, 1:4]
 
     def _dead_window_pages(self) -> int:
         """Pages of window cache groups wholly behind their sequence's
@@ -1785,8 +1788,9 @@ class AsyncJaxEngine:
             preempt_swap=delta["ps"], preempt_recompute=delta["pr"],
             swap_out_blocks=delta["so"], swap_in_blocks=delta["si"],
             wide_tile_rows=delta["wt"],
-            moe_pairs=sum(p for p, _ in moe_step),
-            moe_experts_touched=sum(t for _, t in moe_step),
+            moe_pairs=sum(g[0] for g in moe_step),
+            moe_experts_touched=sum(g[1] for g in moe_step),
+            moe_tiles=sum(g[2] for g in moe_step),
             moe_by_group=moe_step if self._moe_held else [],
             dead_window_pages=self._dead_window_pages(),
             **({} if self.state is None else self._state_fields(

@@ -621,6 +621,13 @@ async def amain():
         "layers)").add_callback(
         lambda: {(("expert", str(e)),): int(v)
                  for e, v in enumerate(engine.moe_expert_tokens_total)})
+    runtime.metrics.counter(
+        "moe_row_tiles_total",
+        "row tiles the held experts' grouped matmuls launched (summed over "
+        "expert layers); an expert's weights are read once a launch, so "
+        "tiles minus experts touched found theirs resident").add_callback(
+        lambda: ({None: engine.moe_row_tiles_total}
+                 if engine.moe_assignments_total else {}))
     runtime.metrics.gauge(
         "engine_warmup_skipped",
         "1 = requested AOT warmup could not run (multi-host step "

@@ -1319,9 +1319,11 @@ def _router_weights(xf, router_w, router_bias, cfg: ModelConfig):
 def moe_stats_width(cfg: ModelConfig) -> int:
     """Length of the held-experts layer's counter vector: assignments
     routed anywhere, assignments to held experts, held experts with at
-    least one token, then each held expert's tokens. A step returns one
-    such row a cache group (layer kind), summed over the group's layers."""
-    return 3 + cfg.num_experts_held
+    least one token, row tiles launched (an expert's weights cross HBM once
+    a launch, so tiles − experts is the tiles that found theirs resident),
+    then each held expert's tokens. A step returns one such row a cache
+    group (layer kind), summed over the group's layers."""
+    return 4 + cfg.num_experts_held
 
 
 def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
@@ -1402,9 +1404,9 @@ def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
     y = jnp.where((row < M)[:, None], yb[jnp.minimum(row, M - 1)], 0)
     y = (y.reshape(N, K, D).astype(jnp.float32)
          * gates[..., None]).sum(1).astype(x.dtype)
-    stats = jnp.pad(counts, (3, 0)).at[0].set(
+    stats = jnp.pad(counts, (4, 0)).at[0].set(
         valid.sum().astype(jnp.int32) * K).at[1].set(counts.sum()).at[2].set(
-        (counts > 0).sum().astype(jnp.int32))
+        (counts > 0).sum().astype(jnp.int32)).at[3].set(num_tiles)
     return y, stats, topi
 
 

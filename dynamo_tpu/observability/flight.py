@@ -148,11 +148,15 @@ class StepRecord:
     #: small tile (prompt chunks, long verify rows): its wide tile's work
     wide_tile_rows: int = 0
     #: held-experts layer (engine/model._mlp_moe_held), summed over the
-    #: step's expert layers: (token, expert) pairs computed here, and held
-    #: experts with at least one token (the weight bytes the step read)
+    #: step's expert layers: (token, expert) pairs computed here, held
+    #: experts with at least one token (the weight bytes the step read),
+    #: and the row tiles the grouped matmuls launched (tiles − experts
+    #: touched: the tiles that found their expert's weights resident)
     moe_pairs: int = 0
     moe_experts_touched: int = 0
-    #: the same two, a cache group (layer kind): [[pairs, touched], ...]
+    moe_tiles: int = 0
+    #: the same three, a cache group (layer kind):
+    #: [[pairs, touched, tiles], ...]
     moe_by_group: list = field(default_factory=list)
     #: pages of window cache groups that lie wholly behind their
     #: sequence's window: what releasing them would free
@@ -214,7 +218,8 @@ class StepRecord:
         for k in ("preempt_swap", "preempt_recompute", "swap_out_blocks",
                   "swap_in_blocks", "starved_decode", "onboard_inflight",
                   "restore_inflight", "constrained_rows", "wide_tile_rows",
-                  "moe_pairs", "moe_experts_touched", "dead_window_pages",
+                  "moe_pairs", "moe_experts_touched", "moe_tiles",
+                  "dead_window_pages",
                   "state_slots_used", "state_rows_prefill",
                   "state_rows_decode", "state_program", "profile_path"):
             v = getattr(self, k)

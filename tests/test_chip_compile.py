@@ -184,15 +184,21 @@ def test_ragged_kernel_compiles_for_v5e_k_wider_than_v(compile_for_chip,
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("shape", [(4096, 2048), (2048, 4096)],
-                         ids=["gate_up", "down"])
-def test_grouped_matmul_compiles_for_v5e(compile_for_chip, shape):
-    """The held-experts layer's product at MiMo-V2.5's widths: 16 experts,
-    the dropless buffer of a 2,048-token step, a traced count of tiles."""
+@pytest.mark.parametrize("tokens", [2048, 8], ids=["chunk", "decode"])
+@pytest.mark.parametrize("shape", [
+    (4096, 2048, 16, 8), (2048, 4096, 16, 8),
+    (4096, 768, 36, 10), (768, 4096, 36, 10)],
+    ids=["mimo_gate_up", "mimo_down", "granite_gate_up", "granite_down"])
+def test_grouped_matmul_compiles_for_v5e(compile_for_chip, shape, tokens):
+    """The held-experts layer's product at MiMo-V2.5's widths (16 experts,
+    8 a token) and Granite-4.0-H-Small's (36 held, 10 a token): the
+    dropless buffer of a 2,048-token step (a traced count of tiles under
+    the grid's column blocks, whole-contraction weight blocks beyond the
+    default VMEM limit) and of an 8-row decode step (square blocks)."""
     from dynamo_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
 
-    (k, n), E = shape, 16
-    tiles = 2048 * 8 // ROW_TILE + E
+    k, n, E, K = shape
+    tiles = -(-tokens * K // ROW_TILE) + E
     with mock.patch("dynamo_tpu.ops.grouped_matmul.kernel_interpret_mode",
                     return_value=False):
         text = compile_for_chip(

@@ -418,6 +418,8 @@ async def test_engine_serves_granite_through_slots_and_says_what_it_did():
     assert sum(r.get("state_rows_prefill", 0) for r in recs) >= 5  # 70 = 3
     assert sum(r.get("state_rows_decode", 0) for r in recs) >= 15
     assert eng.moe_assignments_total["held"] > 0
+    assert sum(r.get("moe_tiles", 0) for r in recs) == \
+        eng.moe_row_tiles_total > 0
     # greedy tokens are the reference's: each request alone, one pass
     weights, hp = granite4_h_inputs(cfg, eng.params)
     for ids, out in zip(prompts, outs):

@@ -90,7 +90,9 @@ def run_engine_steps(cfg, params, seqs, *, routing=False):
         n_moe = sum(st.moe * len(st.layers) for st in M.layer_stacks(cfg))
         # every real token's K choices, in every expert layer, and no pad's
         assert stats[0] == n_tok * cfg.num_experts_per_tok * n_moe
-        assert stats[1] == stats[3:].sum() <= stats[0]
+        assert stats[1] == stats[4:].sum() <= stats[0]
+        # row tiles launched: one at least an expert touched
+        assert stats[2] <= stats[3] <= stats[1]
         t = 0
         for i, (s, start, chunk) in enumerate(rows):
             got = (np.asarray(ids[0])[:, t:t + chunk] if routing else None)
@@ -211,6 +213,22 @@ def test_padding_tokens_are_routed_nowhere():
     assert not np.asarray(y[5:]).any()
 
 
+def test_row_tiles_are_counted_as_the_kernel_launches_them():
+    """An expert with more pairs than one tile holds takes two; the
+    counters say so: tiles − experts touched found their weights there."""
+    from dynamo_tpu.ops.grouped_matmul import ROW_TILE
+    cfg = mimo_tiny()
+    lp = jax.tree.map(lambda a: a[0], M.init_params(
+        cfg, jax.random.key(0))["stacks"][1])
+    n = ROW_TILE * 8
+    x = jax.random.normal(jax.random.key(5), (n, cfg.hidden_size))
+    _, stats, _ = M._mlp_moe_held(x, lp, cfg, jnp.ones((n,), bool))
+    stats = np.asarray(stats)
+    per_expert = stats[4:]
+    assert stats[2] == (per_expert > 0).sum()
+    assert stats[3] == (-(-per_expert // ROW_TILE)).sum() > stats[2]
+
+
 def test_k_rows_wider_than_a_lane_row_are_stored_padded():
     """A head wider than 128 lanes that is no lane multiple (the published
     192) is stored at the next one, zeros behind it; the model's logits are
@@ -294,6 +312,12 @@ async def test_engine_serves_mimo_and_counts_what_its_experts_did():
     assert sum(r.get("moe_pairs", 0) for r in recs) == \
         eng.moe_assignments_total["held"] == \
         int(eng.moe_expert_tokens_total.sum()) > 0
+    assert sum(r.get("moe_tiles", 0) for r in recs) == \
+        eng.moe_row_tiles_total >= \
+        sum(r.get("moe_experts_touched", 0) for r in recs) > 0
+    assert all([sum(col) for col in zip(*r["moe_by_group"])] == [
+        r["moe_pairs"], r["moe_experts_touched"], r["moe_tiles"]]
+        for r in recs if r.get("moe_pairs"))
     n_tok = 40 + 20 + 2 * 5  # prompts, and every emitted token but the last
     assert eng.moe_assignments_total["all"] == n_tok * 4 * 12
     # the 40-token prompt outgrows the 8-token window: pages behind it
