@@ -43,7 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from dynamo_tpu.engine.config import ModelConfig
 from dynamo_tpu.engine.model import (
     _mlp_dense, _mm, _paged_attention, _qkv_heads, _ragged_attention,
-    _rms_norm, _rope,
+    _rms_norm, _rope, jit_step_program,
 )
 
 AXIS = "pp"
@@ -406,4 +406,4 @@ def make_pp_step_fn(cfg: ModelConfig, block_size: int, mesh: Mesh,
 
         csh = cache_shardings(mesh, cfg)
         kw["out_shardings"] = (NamedSharding(mesh, P()), csh, csh)
-    return jax.jit(f, donate_argnums=(5, 6), **kw)
+    return jit_step_program(f, (5, 6), mesh, **kw)

@@ -93,8 +93,39 @@ def compile_for_chip(one_chip, no_compile_cache):
     return run
 
 
+@pytest.fixture
+def step_options_of_the_chip():
+    """While this is in use ``model.step_compiler_options`` answers what it
+    answers where the default backend is the TPU (here it is the CPU, whose
+    compiler refuses the names), so a ``make_*_fn`` builds the jit the
+    engine builds on the chip."""
+    from dynamo_tpu.engine import model as M
+
+    with mock.patch.object(jax, "default_backend", return_value="tpu"):
+        options = M.step_compiler_options()
+    assert options
+    with mock.patch.object(M, "step_compiler_options",
+                           return_value=options):
+        yield options
+
+
 def spec(shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def rematerialised_ops(text):
+    """Names and shapes of the ops XLA's rematerialisation pass added to a
+    compiled program: it names a recomputed op ``<op>.remat<n>``."""
+    return sorted(set(re.findall(r"%([\w.\-]*remat[\w.\-]*) = (\w+\[[\d,]*\])",
+                                 text)))
+
+
+def dots_producing(text, shape):
+    """The matrix products of a compiled program (the TPU compiler writes a
+    dot as a ``convolution``) whose result is ``shape``, e.g.
+    ``bf16[2048,16768]``, fused or not."""
+    return re.findall(r"%([\w.\-]+) = " + re.escape(shape)
+                      + r"\S* (?:convolution|dot)\(", text)
 
 
 def lone_projection_weight_ops(text, dtype, projections):
@@ -241,23 +272,29 @@ def test_gqa_decode_kernel_compiles_for_v5e(compile_for_chip):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("T", [8, 256], ids=["decode_only", "mixed"])
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv, T):
+@pytest.mark.parametrize("kv,T,layers,nb", [
+    ("bf16", 8, 2, 2048), ("bf16", 256, 2, 2048),
+    ("int8", 8, 2, 2048), ("int8", 256, 2, 2048),
+    ("bf16", 1024, 32, 2723)],
+    ids=["bf16-decode_only", "bf16-mixed", "int8-decode_only", "int8-mixed",
+         "bf16-cell_m1024"])
+def test_serving_step_compiles_with_kernel_for_v5e(
+        compile_for_chip, step_options_of_the_chip, kv, T, layers, nb):
     """The whole jitted ragged step — layer scan, int8 weights, the kernel
     inside — at Mistral-7B widths (depth cut to 2: the scan makes the
-    program the same modulo the leading L): the cache must pass into the
-    kernel without a relayout copy of the pool, and no op but its own dot
-    reads a layer's q, k or v projection weight."""
+    program the same modulo the leading L; and the 1,024-token program of
+    ``mistral7b-w8.chat-steady`` at full depth with the cell's pool, 13.09
+    GB of arguments): the cache must pass into the kernel without a
+    relayout copy of the pool, no op but its own dot reads a layer's q, k
+    or v projection weight, and nothing is computed twice."""
     import dataclasses
 
     from dynamo_tpu.engine import model as M
     from dynamo_tpu.engine.config import EngineArgs
     from dynamo_tpu.models import mistral_7b
 
-    cfg = dataclasses.replace(mistral_7b(), num_layers=2)
+    cfg = dataclasses.replace(mistral_7b(), num_layers=layers)
     args = EngineArgs()
-    nb = 2048
     R, W = args.ragged_rows(T), args.max_blocks_per_seq
     C, _ = M.ragged_grid_shape(T)
     params = jax.eval_shape(lambda: M.init_params(
@@ -271,8 +308,8 @@ def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv, T):
         step, params, spec((5, T), jnp.int32), spec((R, 3), jnp.int32),
         spec((C,), jnp.int32), spec((R, W), jnp.int32), cache, cache)
     assert "tpu_custom_call" in text
-    # the pool is 2 x 2 layers x 32768 slots x 8 x 128: no op but the
-    # in-place page write may produce an array of that size
+    # the pool is 2 x layers x slots x 8 x 128: no op but the in-place
+    # page write may produce an array of that size
     pool = f"[{cfg.num_layers},{nb * BS},{cfg.num_kv_heads},{cfg.head_dim}]"
     copies = [ln for ln in text.splitlines()
               if " copy(" in ln and pool in ln.split(" copy(")[0]]
@@ -281,16 +318,19 @@ def test_serving_step_compiles_with_kernel_for_v5e(compile_for_chip, kv, T):
     moved = lone_projection_weight_ops(
         text, "s8", [(H, hd, cfg.hidden_size), (KV, hd, cfg.hidden_size)])
     assert not moved, moved
+    assert not rematerialised_ops(text)
 
 
 @pytest.mark.parametrize("T", [64, 2048], ids=["decode_heavy", "mixed"])
-def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
+def test_mimo_step_compiles_with_both_kernels_for_v5e(
+        compile_for_chip, step_options_of_the_chip, T):
     """The whole jitted ragged step of the MiMo-V2.5 share the benchmark
     runs (models.mimo_v25_ep16: all 7 layers, published widths, both cache
-    groups, the held-experts layer): the ragged kernel for both layer kinds
-    and the grouped matmul are Mosaic calls, neither pool is copied, and no
-    op but its own dot reads a layer's q, k or v projection weight (the two
-    one-layer stacks' included)."""
+    groups at the cell's 10,088 blocks — 12.81 GB of arguments — the
+    held-experts layer): the ragged kernel for both layer kinds and the
+    grouped matmul are Mosaic calls, neither pool is copied, no op but its
+    own dot reads a layer's q, k or v projection weight (the two one-layer
+    stacks' included), and nothing is computed twice."""
     from dynamo_tpu.engine import model as M
     from dynamo_tpu.engine.config import EngineArgs
     from dynamo_tpu.models import mimo_v25_ep16
@@ -298,7 +338,7 @@ def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
     cfg = mimo_v25_ep16()
     args = EngineArgs(max_num_seqs=64, max_num_batched_tokens=2048,
                       max_model_len=34816)
-    nb = 4096
+    nb = 10088
     R, W = args.ragged_rows(T), args.max_blocks_per_seq
     C, _ = M.ragged_grid_shape(T)
     params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.key(0)))
@@ -333,20 +373,19 @@ def test_mimo_step_compiles_with_both_kernels_for_v5e(compile_for_chip, T):
         + [(k.num_kv_heads, w, D) for k in cfg.layer_kinds
            for w in (cfg.head_dim, cfg.v_dim)])
     assert not moved, moved
+    assert not rematerialised_ops(text)
 
 
-@pytest.mark.parametrize("T", [64, 2048], ids=["decode_only", "mixed"])
-def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
-        compile_for_chip, T):
-    """The whole jitted ragged step of the Granite-4.0-H-Small share the
-    benchmark runs (models.granite4_h_small_ep2: ten layers, published
-    widths, 64 state slots): the update kernel, the ragged kernel and the
-    grouped matmul are Mosaic calls; the SSM state stack (2.45 GB) and the
-    page pool are updated in place — no op copies either — and no op
-    launched on its own slices or copies a layer's ``in_proj``,
-    ``out_proj`` or experts out of their stacks (a run of layers that is
-    part of its stack reads it by index, where a sliced stack was copied
-    whole: 1.2 GB of ``in_proj`` a step)."""
+#: the KV pool of ``granite4-h-small-ep2.chat-steady`` (PERF.md section 4):
+#: with it a step program's arguments are 14.93 GB of the chip's 16.9
+GRANITE_CELL_BLOCKS = 44740
+
+
+def granite_step_text(compile_for_chip, T):
+    """(compiled text, cfg, state slots) of the T-token ragged step (T = 64:
+    decode-only) of the Granite-4.0-H-Small share the benchmark runs, 64
+    state slots and the cell's KV blocks, built as
+    ``model.step_compiler_options`` says at the moment of the call."""
     from dynamo_tpu.engine import model as M
     from dynamo_tpu.engine.config import EngineArgs
     from dynamo_tpu.models import granite4_h_small_ep2
@@ -354,7 +393,7 @@ def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
     cfg = granite4_h_small_ep2()
     args = EngineArgs(max_num_seqs=64, max_num_batched_tokens=2048,
                       max_model_len=8192)
-    nb, slots = 8192, args.max_num_seqs + 1
+    nb, slots = GRANITE_CELL_BLOCKS, args.max_num_seqs + 1
     R, W = args.ragged_rows(T), args.max_blocks_per_seq
     C, _ = M.ragged_grid_shape(T)
     params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.key(0)))
@@ -374,6 +413,28 @@ def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
         text = compile_for_chip(
             step, params, spec((5, T), jnp.int32), spec((R, 4), jnp.int32),
             spec((C,), jnp.int32), spec((R, W), jnp.int32), kc, vc, state)
+    return text, cfg, slots
+
+
+@pytest.mark.parametrize("T", [64, 256, 512, 1024, 2048],
+                         ids=["decode_only", "m256", "m512", "m1024", "mixed"])
+def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
+        compile_for_chip, step_options_of_the_chip, T):
+    """The whole jitted ragged step of the Granite-4.0-H-Small share the
+    benchmark runs (models.granite4_h_small_ep2: ten layers, published
+    widths, 64 state slots, THE CELL'S POOL: at 8,192 blocks the compiler
+    rematerialises nothing whatever it is told, so tier 1 never saw what
+    the cell ran): the update kernel, the ragged kernel and the
+    grouped matmul are Mosaic calls; the SSM state stack (2.45 GB) and the
+    page pool are updated in place — no op copies either — and no op
+    launched on its own slices or copies a layer's ``in_proj``,
+    ``out_proj`` or experts out of their stacks (a run of layers that is
+    part of its stack reads it by index, where a sliced stack was copied
+    whole: 1.2 GB of ``in_proj`` a step). Nothing is computed twice:
+    ``in_proj``'s product is made once a run of layers."""
+    text, cfg, slots = granite_step_text(compile_for_chip, T)
+    st, nb = cfg.state_spec, GRANITE_CELL_BLOCKS
+    n = len(st.layers)
     program = ("m" if T > 64 else "d") + str(T)
     for run in ("l0x5", "l5x4"):  # one launch a run of Mamba-2 layers
         assert f"mamba2_decode_update_{run}_{program}" in text
@@ -406,6 +467,23 @@ def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
     sliced = [ln for ln in lines
               if f" = bf16[{Eh},{D},{F}]" in ln or f" = bf16[{Eh},{F},{D}]" in ln]
     assert not sliced, sliced[:2]
+    assert not rematerialised_ops(text)
+    if T >= 512:  # one scan a run of Mamba-2 layers: two in the program
+        assert len(dots_producing(text, f"bf16[{T},{wide}]")) == 2
+
+
+def test_granite_step_is_rematerialised_without_the_options(compile_for_chip):
+    """Why ``model.step_compiler_options`` exists: at the cell's pool, left
+    to its defaults, the compiler makes ``in_proj``'s 68 MB product twice a
+    run of layers (and more besides) to save 71 MB of a temp that fits. The
+    day this fails the compiler no longer needs telling."""
+    with mock.patch("dynamo_tpu.engine.model.step_compiler_options",
+                    return_value={}):
+        text, _, _ = granite_step_text(compile_for_chip, 2048)
+    again = rematerialised_ops(text)
+    assert [op for op, shape in again
+            if op.endswith(".remat3") and shape == "bf16[2048,16768]"], again
+    assert len(dots_producing(text, "bf16[2048,16768]")) == 4
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
