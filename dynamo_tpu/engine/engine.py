@@ -576,18 +576,18 @@ class AsyncJaxEngine:
         #: gate does not count a cold worker as warm (docs/autoscaling.md)
         self.warmup_requested = args.warmup_buckets
         self.warmup_skipped = False
-        #: per-step phase timing ring (kind, n_seqs, n_tokens, wall_ms) —
-        #: the profile that located the r4 serving-vs-kernel gap; cheap
-        #: enough to keep always-on, dumped by step_trace_summary()
-        self.step_trace: "collections.deque" = collections.deque(maxlen=2048)
         #: step flight recorder (observability/flight.py): one structured,
         #: anomaly-tagged record per executed step — the fleet-queryable
-        #: "why was this step slow" layer the step_trace ring cannot answer
+        #: "why was this step slow" layer; step_trace_summary() reads it
         from dynamo_tpu.observability.flight import (
             FlightRecorder, register_recorder,
         )
         self.flight = FlightRecorder(service="engine")
         self._flight_name = register_recorder("engine", self.flight)
+        #: the engine loop's phase clock (observability/flight.py
+        #: PhaseClock), made when the loop starts; None with DYN_FLIGHT=0
+        #: and DYN_JAX_PROFILER unset, and a mark is then one test
+        self._clock = None
         #: anomaly-triggered bounded jax.profiler capture (None unless
         #: DYN_PROFILE_ON_ANOMALY names a directory): a slow-step /
         #: compile-steady flight tag arms one device-trace capture whose
@@ -608,7 +608,6 @@ class AsyncJaxEngine:
         self.compile_events: dict[str, int] = {}
         self.compile_seconds: dict[str, float] = {}
         self._last_compile: Optional[tuple] = None  # (kind, sig, seconds)
-        self._last_dispatch_ms = 0.0  # latest jitted-call dispatch wall
         #: bytes per KV block (both caches, quant scales included) —
         #: computed lazily once for the G1 tier-occupancy gauge
         self._kv_block_nbytes: Optional[int] = None
@@ -1484,19 +1483,50 @@ class AsyncJaxEngine:
                                  return_exceptions=True)
         if self.anomaly_profiler is not None:
             self.anomaly_profiler.close()  # stop a capture left open
+        if self._clock is not None:
+            self._clock.close()
         from dynamo_tpu.observability.flight import unregister_recorder
         unregister_recorder(self._flight_name)
 
     # ------------------------------------------------------------ main loop
 
+    def _mark(self, phase: str) -> None:
+        """The serving thread enters ``phase`` (flight.PHASES): one list of
+        call sites feeds the flight records' ``phases`` and, under
+        DYN_JAX_PROFILER=1, the ``dynamo.<phase>`` trace annotations."""
+        if self._clock is not None:
+            self._clock.mark(phase)
+
+    def _landed(self) -> float:
+        """Last thing a worker thread does with a step's result on the
+        host: the stamp that ends the serving thread's ``device_wait``."""
+        return self._clock.landed() if self._clock is not None else 0.0
+
+    def _resumed(self, stamp: float) -> None:
+        """The loop runs again after an await on the device: the wait ended
+        at the worker's ``stamp``, what lies between is ``lag``, and what
+        follows every such await is ``commit``."""
+        if self._clock is not None:
+            self._clock.mark("lag", at=stamp)
+            self._clock.mark("commit")
+
     async def _run(self) -> None:
         logger.info("engine loop starting: %d blocks × %d tokens, tp=%d",
                     self.num_blocks, self.args.block_size, self.args.tp_size)
+        from dynamo_tpu.observability import profiler
+        from dynamo_tpu.observability.flight import PhaseClock
+
+        if self._clock is not None:
+            self._clock.close()
+        self._clock = (PhaseClock(annotate=profiler.enabled())
+                       if self.flight.enabled or profiler.enabled() else None)
         while not self._closed:
             if not self.scheduler.has_work:
+                self._mark("idle")
                 self._wake.clear()
                 await self._wake.wait()
                 continue
+            self._mark("plan")
             plan = self.scheduler.plan()
             chaos = _get_chaos()
             if (chaos is not None and not plan.empty
@@ -1525,6 +1555,7 @@ class AsyncJaxEngine:
                 # the old 5 ms poll burned a wakeup per tick under pressure).
                 # The timeout is a safety net for edge signals that have no
                 # hook (e.g. a context cancelled while we sleep).
+                self._mark("blocked")
                 self._wake.clear()
                 t0 = time.perf_counter()
                 try:
@@ -1554,19 +1585,16 @@ class AsyncJaxEngine:
                         finish_reason=FinishReason.ERROR, text="engine step failed"))
             self.steps += 1
             if self.metrics_cb:
+                self._mark("record")
                 self.metrics_cb(self._metrics())
             # let request ingress / cancellation run
+            self._mark("lag")
             await asyncio.sleep(0)
 
     async def _execute(self, plan: StepPlan) -> None:
-        # env-gated jax.profiler correlation (DYN_JAX_PROFILER=1): device
-        # traces carry the serving phase names alongside request spans
-        from dynamo_tpu.observability.profiler import annotate
-
         if not plan.prefill and plan.decode and self._can_pipeline(plan.decode):
-            with annotate("dynamo.decode_pipeline"):
-                if await self._run_decode_pipelined(plan.decode):
-                    return
+            if await self._run_decode_pipelined(plan.decode):
+                return
         if plan.empty:
             return
         # decode-only plans may take the burst/spec fast paths (K tokens or
@@ -1580,46 +1608,29 @@ class AsyncJaxEngine:
         # flight record per plan: the record owns the plan's starvation
         # count, QoS mix, and padded-token accounting.
         t0 = time.perf_counter()
-        n_tok = sum(w.chunk for w in plan.prefill) + len(plan.decode)
-        with annotate("dynamo.ragged_step"):
-            padded = await self._run_ragged(plan)
+        padded = await self._run_ragged(plan)
         wall = (time.perf_counter() - t0) * 1000
         if not plan.prefill and plan.decode:
             # plain decode step wall: the spec governor's cost baseline
             self._decode_step_ms = (
                 wall if self._decode_step_ms is None
                 else 0.8 * self._decode_step_ms + 0.2 * wall)
-        self.step_trace.append((
-            "ragged", len(plan.prefill) + len(plan.decode), n_tok,
-            wall, padded))
         self._flight_record(
             "ragged", wall, decode_rows=len(plan.decode),
             prefill_chunks=len(plan.prefill),
             chunk_tokens=sum(w.chunk for w in plan.prefill),
-            padded=padded, dispatch_ms=self._last_dispatch_ms,
-            qos_mix=self._plan_qos_mix(plan),
+            padded=padded, qos_mix=self._plan_qos_mix(plan),
             constrained=self._constrained_count(
                 plan.decode + [w.seq for w in plan.prefill]),
             decode_seqs=plan.decode,
             prefill_seqs=[w.seq for w in plan.prefill])
 
     def step_trace_summary(self) -> dict:
-        """Aggregate the timing ring: per kind, steps / seqs / tokens /
-        total+mean wall — the first thing to read when e2e throughput is
-        far below the kernel ceiling."""
-        agg: dict[str, list] = {}
-        for kind, n, toks, ms, *rest in self.step_trace:
-            a = agg.setdefault(kind, [0, 0, 0, 0.0, 0])
-            a[0] += 1
-            a[1] += n
-            a[2] += toks
-            a[3] += ms
-            a[4] += rest[0] if rest else 0  # padded tokens (ragged entries)
-        return {k: {"steps": a[0], "seqs": a[1], "tokens": a[2],
-                    "total_ms": round(a[3], 1),
-                    "mean_ms": round(a[3] / a[0], 1),
-                    "padded_tokens": a[4]}
-                for k, a in agg.items()}
+        """The flight ring's newest steps by kind: steps / seqs / tokens /
+        total+mean wall / padded tokens — the first thing to read when e2e
+        throughput is far below the kernel ceiling. Empty with DYN_FLIGHT=0
+        (the records are the only step timing the engine keeps)."""
+        return self.flight.kind_summary()
 
     # --------------------------------------------------- flight recording
 
@@ -1736,7 +1747,7 @@ class AsyncJaxEngine:
 
     def _flight_record(self, kind: str, wall_ms: float, decode_rows: int,
                        prefill_chunks: int, chunk_tokens: int,
-                       padded: int = 0, dispatch_ms: float = 0.0,
+                       padded: int = 0,
                        qos_mix: Optional[dict] = None,
                        starved: Optional[int] = None,
                        constrained: int = 0,
@@ -1745,8 +1756,11 @@ class AsyncJaxEngine:
         depths + tier occupancy, difference the cumulative preempt/swap
         totals into per-step deltas, attach a compile staged by
         ``_note_compile`` during this step's dispatch, stamp the
-        step↔request-id linkage the attribution join needs, and feed the
-        anomaly-triggered profiler."""
+        step↔request-id linkage the attribution join needs, cut the phase
+        clock (the record carries the loop's time since the record before
+        it, by phase: its own making up to here is ``record``) and feed
+        the anomaly-triggered profiler."""
+        self._mark("record")
         fb = self.ragged_fallback_reason
         if fb is not None:
             # every executed step on a degraded attention path counts —
@@ -1779,9 +1793,14 @@ class AsyncJaxEngine:
                 t: v["blocks"] for t, v in self.kv_tier_occupancy().items()}
             self._flight_tiers_t = now
         tiers = self._flight_tiers
+        # what follows the cut (the fields below, the ring, the anomaly
+        # profiler, metrics_cb) is the NEXT record's ``record``
+        period_ms, phases = (self._clock.cut() if self._clock is not None
+                             else (0.0, {}))
         rec = self.flight.record(
             kind, wall_ms,
-            dispatch_ms=dispatch_ms,
+            period_ms=period_ms, phases=phases,
+            dispatch_ms=phases.get("dispatch", 0.0),
             decode_rows=decode_rows, prefill_chunks=prefill_chunks,
             chunk_tokens=chunk_tokens, padded_tokens=padded,
             compile_s=compile_s, compile_sig=compile_sig,
@@ -2035,6 +2054,7 @@ class AsyncJaxEngine:
 
         from dynamo_tpu.engine.model import ragged_grid_shape
 
+        self._mark("build")
         args = self.args
         bs = args.block_size
         works = plan.prefill
@@ -2111,19 +2131,20 @@ class AsyncJaxEngine:
             kind, fn = "ragged_dec", self.ragged_dec_fn
         new_sig = (kind, T) not in self.compiled_signatures
         self.compiled_signatures.add((kind, T))
+        self._mark("put")
         self._broadcast(kind, **operands)
-        t0d = time.perf_counter()
+        on_device = [self._put_batch(k, v) for k, v in operands.items()]
+        self._mark("dispatch")
+        t0c = time.perf_counter() if new_sig else 0.0
         logits, self.k_cache, self.v_cache = fn(
-            self.params,
-            *(self._put_batch(k, v) for k, v in operands.items()),
-            self.k_cache, self.v_cache)
-        self._last_dispatch_ms = (time.perf_counter() - t0d) * 1000
+            self.params, *on_device, self.k_cache, self.v_cache)
         if new_sig:
-            self._note_compile(kind, (T,), time.perf_counter() - t0d)
+            self._note_compile(kind, (T,), time.perf_counter() - t0c)
 
         # commit BEFORE sampling, exactly like the bucketed steps: chunk
         # progress (and disagg block shipping) must never wait on the
         # sampler's host round trip
+        self._mark("commit")
         for w in works:
             seq, end = w.seq, w.start + w.chunk
             self.scheduler.commit_computed(seq, end)
@@ -2142,7 +2163,7 @@ class AsyncJaxEngine:
                        if smp]
         if not sample_rows:
             # every row was a mid-prompt chunk: logits unused, sync to pace
-            await asyncio.to_thread(lambda: logits.block_until_ready())
+            await self._device_sync(logits)
             return T - total
         idx = [i for i, _ in sample_rows]
         if idx == list(range(len(rows))):
@@ -2150,6 +2171,7 @@ class AsyncJaxEngine:
             # padded R >= len(rows), no gather needed
             sel = logits
         else:
+            self._mark("put")
             Bp = args.bucket_batch(len(idx))
             sel = logits[jnp.asarray(idx + [idx[0]] * (Bp - len(idx)),
                                      jnp.int32)]
@@ -2158,6 +2180,13 @@ class AsyncJaxEngine:
         for j, (_i, seq) in enumerate(sample_rows):
             self._deliver(seq, int(toks[j]), float(logps[j]), tops.get(j))
         return T - total
+
+    async def _device_sync(self, logits) -> None:
+        """Wait in a worker thread until ``logits`` are computed: a step
+        that samples nothing still paces the loop by its device time."""
+        self._mark("device_wait")
+        self._resumed(await asyncio.to_thread(
+            lambda: (logits.block_until_ready(), self._landed())[1]))
 
     async def _run_ragged_pp(self, plan: StepPlan) -> int:
         """The pipeline-parallel ragged step: the plan's rows split into
@@ -2171,6 +2200,7 @@ class AsyncJaxEngine:
 
         from dynamo_tpu.engine.model import ragged_grid_shape
 
+        self._mark("build")
         args = self.args
         bs = args.block_size
         works = plan.prefill
@@ -2243,17 +2273,18 @@ class AsyncJaxEngine:
                     "block_tables": bt}
         new_sig = ("pp", T, Mmb) not in self.compiled_signatures
         self.compiled_signatures.add(("pp", T, Mmb))
+        self._mark("put")
         self._broadcast("pp", **operands)
-        t0d = time.perf_counter()
+        on_device = [self._put_batch(k, v) for k, v in operands.items()]
+        self._mark("dispatch")
+        t0c = time.perf_counter() if new_sig else 0.0
         logits, self.k_cache, self.v_cache = self.pp_fn(
-            self.params,
-            *(self._put_batch(k, v) for k, v in operands.items()),
-            self.k_cache, self.v_cache)
-        self._last_dispatch_ms = (time.perf_counter() - t0d) * 1000
+            self.params, *on_device, self.k_cache, self.v_cache)
         if new_sig:
-            self._note_compile("pp", (T, Mmb), time.perf_counter() - t0d)
+            self._note_compile("pp", (T, Mmb), time.perf_counter() - t0c)
 
         # commit BEFORE sampling, exactly like the single-bin launch
+        self._mark("commit")
         for w in works:
             seq, end = w.seq, w.start + w.chunk
             self.scheduler.commit_computed(seq, end)
@@ -2269,10 +2300,11 @@ class AsyncJaxEngine:
             self.scheduler.commit_computed(s, len(s.tokens))
 
         if not sample_rows:
-            await asyncio.to_thread(lambda: logits.block_until_ready())
+            await self._device_sync(logits)
             return Mmb * T - total
         # logits land [M, R, V]: flatten and gather the sampling rows,
         # padded to a batch bucket so the sampling jit sees bounded shapes
+        self._mark("put")
         idx = [m * R + i for m, i, _ in sample_rows]
         Bp = args.bucket_batch(len(idx))
         flat = logits.reshape(Mmb * R, logits.shape[-1])
@@ -2376,6 +2408,7 @@ class AsyncJaxEngine:
         token — emitting 1..K+1 tokens per dispatch with EXACTLY the tokens
         plain greedy decode would produce. Returns False (fall back) when no
         seq drafts anything or block preallocation fails."""
+        self._mark("other")  # no phase marks on the draft/verify path yet
         args = self.args
         K = args.speculative_tokens
         t0 = time.perf_counter()
@@ -2624,7 +2657,6 @@ class AsyncJaxEngine:
         # Returns True when a fast path consumed the plan (with its own
         # flight record); False → the caller's packed ragged launch runs.
         t0 = time.perf_counter()
-        gen0 = sum(s.generated for s in seqs)
         kind = None
         if (self.verify_fn is not None and seqs and self._spec_active()
                 and all(s.sampling_tuple()[0] == 0.0 for s in seqs)
@@ -2660,12 +2692,9 @@ class AsyncJaxEngine:
         if kind is None:
             return False
         wall = (time.perf_counter() - t0) * 1000
-        self.step_trace.append((
-            kind, len(seqs), sum(s.generated for s in seqs) - gen0, wall))
         self._flight_record(
             kind, wall, decode_rows=len(seqs),
             prefill_chunks=0, chunk_tokens=0,
-            dispatch_ms=self._last_dispatch_ms,
             qos_mix=self._qos_mix_of(seqs),
             constrained=self._constrained_count(seqs),
             decode_seqs=seqs)
@@ -2716,6 +2745,7 @@ class AsyncJaxEngine:
         """
         import jax.numpy as jnp
 
+        self._mark("build")
         args = self.args
         bs = args.block_size
         off = 1 if feed is not None else 0  # uncommitted in-flight tokens
@@ -2779,6 +2809,7 @@ class AsyncJaxEngine:
         rows3[:len(seqs), 2] = kv_lens[:len(seqs)]
         if self.state is not None:
             rows3[:len(seqs), 3] = [s.state_slot for s in seqs]
+        self._mark("put")
         ints5 = jnp.asarray(ints5)
         if feed is not None:
             ints5 = ints5.at[0, :len(seqs)].set(
@@ -2786,14 +2817,16 @@ class AsyncJaxEngine:
         new_sig = ("ragged_dec", B) not in self.compiled_signatures
         self.compiled_signatures.add(("ragged_dec", B))
         self.padded_tokens_total += B - len(seqs)
-        t0 = time.perf_counter()
+        t0 = time.perf_counter()  # where ``wall_ms`` has always begun
+        on_device = (ints5, jnp.asarray(rows3), jnp.zeros((C,), jnp.int32),
+                     jnp.asarray(bt))
+        self._mark("dispatch")
         logits, self.k_cache, self.v_cache = self.ragged_dec_fn(
-            self.params, ints5, jnp.asarray(rows3),
-            jnp.zeros((C,), jnp.int32), jnp.asarray(bt),
-            self.k_cache, self.v_cache)
+            self.params, *on_device, self.k_cache, self.v_cache)
         if new_sig:
             self._note_compile("ragged_dec", (B,),
                                time.perf_counter() - t0)
+        self._mark("sample")
         states = None
         if any(_guided_fsm(s) is not None for s in seqs):
             # constrained rows: per-row FSM state is one more device-fed
@@ -2819,7 +2852,7 @@ class AsyncJaxEngine:
         # device→host copy in a worker thread: the loop dispatches step N+1
         # and only then awaits this
         copy = asyncio.get_running_loop().create_task(asyncio.to_thread(
-            lambda: (np.asarray(toks), np.asarray(logps))))
+            lambda: (np.asarray(toks), np.asarray(logps), self._landed())))
         return {"seqs": list(seqs), "toks": toks, "states": states,
                 "copy": copy, "t0": t0}
 
@@ -2827,7 +2860,9 @@ class AsyncJaxEngine:
         """Land one in-flight step: await its host copy, then commit + emit.
         Rows of sequences that finished at an earlier step are overshoot —
         their KV write targeted an unregistered block and is discarded."""
-        toks, logps = await handle["copy"]
+        self._mark("device_wait")
+        toks, logps, stamp = await handle["copy"]
+        self._resumed(stamp)
         n = 0
         constrained = 0
         for i, s in enumerate(handle["seqs"]):
@@ -2845,8 +2880,6 @@ class AsyncJaxEngine:
             n += 1
         self.pipelined_steps += 1
         wall = (time.perf_counter() - handle["t0"]) * 1000
-        self.step_trace.append((
-            "decode_pipe", len(handle["seqs"]), n, wall))
         self._flight_record(
             "decode_pipe", wall, decode_rows=n, prefill_chunks=0,
             chunk_tokens=0, starved=0, constrained=constrained,
@@ -2910,6 +2943,7 @@ class AsyncJaxEngine:
         single-step."""
         import jax.numpy as jnp
 
+        self._mark("build")
         args = self.args
         K = args.multi_step_decode
         # the burst writes positions len-1 .. len+K-2 → len+K-1 slots
@@ -2953,10 +2987,11 @@ class AsyncJaxEngine:
         new_sig = (kind, B) not in self.compiled_signatures
         self.compiled_signatures.add((kind, B))
         self.padded_tokens_total += (B - len(seqs)) * K
+        self._mark("dispatch")
         self._broadcast("multi", ints=ints, floats=floats, rand=rand,
                         block_tables=bt)
         self.param_reads += K
-        t0d = time.perf_counter()
+        t0c = time.perf_counter() if new_sig else 0.0
         if use_fsm:
             # constrained rows: per-row FSM state rides the burst scan —
             # masked sampling + table advance on device each of the K
@@ -2988,11 +3023,12 @@ class AsyncJaxEngine:
                 self._put_batch("rand", rand),
                 self._put_batch("block_tables", bt),
                 self.k_cache, self.v_cache)
-        self._last_dispatch_ms = (time.perf_counter() - t0d) * 1000
         if new_sig:
-            self._note_compile(kind, (B,), time.perf_counter() - t0d)
-        toks, logps = await asyncio.to_thread(
-            lambda: (np.asarray(toks), np.asarray(logps)))
+            self._note_compile(kind, (B,), time.perf_counter() - t0c)
+        self._mark("device_wait")
+        toks, logps, stamp = await asyncio.to_thread(
+            lambda: (np.asarray(toks), np.asarray(logps), self._landed()))
+        self._resumed(stamp)
 
         for i, s in enumerate(seqs):
             # one coalesced output per seq per burst (overshoot discarded)
@@ -3034,6 +3070,7 @@ class AsyncJaxEngine:
         [token_id, logprob] alternatives when seq i requested logprobs
         (ref surface: perf/logprobs.rs TokenLogProbs), else absent.
         """
+        self._mark("build")
         B = len(rows) if rows is not None else logits.shape[0]
         temp = np.zeros((B,), np.float32)
         top_k = np.zeros((B,), np.int32)
@@ -3224,9 +3261,15 @@ class AsyncJaxEngine:
                     tops[i] = [[int(j), float(v)]
                                for j, v in zip(ids[i, :n], vals[i, :n])]
                     l[i] = sel[i]
-            return t, l, tops
+            return t, l, tops, self._landed()
 
-        return await asyncio.to_thread(run_sampling)
+        # everything run_sampling does (host logit edits, the sampler's
+        # call, the top-k read) is the worker thread's time: the serving
+        # thread is in ``device_wait`` up to the stamp it returns
+        self._mark("device_wait")
+        t, l, tops, stamp = await asyncio.to_thread(run_sampling)
+        self._resumed(stamp)
+        return t, l, tops
 
     def _deliver_batch(self, seq: SeqState, tokens, logps) -> int:
         """Coalesced per-step emission: commit/append each token of a fused

@@ -13,6 +13,11 @@ survey's per-tier visibility argument, arXiv 2607.02574 §6):
   swap deltas, queue depths, KV tier occupancy G1–G4, onboard/restore
   pulls in flight, QoS class mix, and the anomaly ``tags`` computed the
   moment the record lands.
+- ``PhaseClock`` — the lap clock of the engine loop's thread: every
+  millisecond between two records goes to ONE named phase (``PHASES``), so a
+  record also says what its step cost the loop (``period_ms``) and what the
+  loop was doing meanwhile (``phases``); the same transitions annotate a
+  device trace as ``dynamo.<phase>`` under ``DYN_JAX_PROFILER=1``.
 - ``FlightRecorder`` — bounded ring of records + rolling step-time
   baseline; tags are computed inline (no offline pass needed):
   ``slow-step`` (wall > kσ over the rolling baseline), ``compile`` /
@@ -41,6 +46,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import itertools
 import json
 import logging
 import math
@@ -122,8 +128,18 @@ class StepRecord:
     kind: str = ""          # ragged|spec|multi|decode_pipe|mock|empty —
     #                         ONE record per plan (the packed ragged launch
     #                         is the only step path; no per-bucket records)
-    wall_ms: float = 0.0    # plan+execute wall clock
-    dispatch_ms: float = 0.0  # jitted-call dispatch portion (0 = unknown)
+    wall_ms: float = 0.0    # plan+execute wall clock; of a ``decode_pipe``
+    #                         record the LATENCY of one step through the
+    #                         depth-2 pipe (its dispatch → its commit, the
+    #                         next step's build and dispatch included)
+    dispatch_ms: float = 0.0  # the period's ``dispatch`` phase: the jitted
+    #                           step's call (0 = none in the period)
+    #: the serving thread's time since the record before this one landed
+    #: (what the step COST the loop), and that time by phase, ``{name: ms}``
+    #: with zero entries left out, summing to it (``PhaseClock``); both 0 /
+    #: empty and off the wire where the engine runs no clock
+    period_ms: float = 0.0
+    phases: dict = field(default_factory=dict)
     decode_rows: int = 0
     prefill_chunks: int = 0
     chunk_tokens: int = 0   # real prefill tokens this step
@@ -212,6 +228,9 @@ class StepRecord:
         # compact at fleet scale (most steps are unremarkable)
         if self.dispatch_ms:
             d["dispatch_ms"] = round(self.dispatch_ms, 3)
+        if self.period_ms:
+            d["period_ms"] = round(self.period_ms, 3)
+            d["phases"] = {k: round(v, 3) for k, v in self.phases.items()}
         if self.compile_s:
             d["compile_s"] = round(self.compile_s, 4)
             d["compile_sig"] = self.compile_sig
@@ -246,7 +265,94 @@ class StepRecord:
         rec.tags = list(d.get("tags") or [])
         rec.kv_tiers = dict(d.get("kv_tiers") or {})
         rec.qos_mix = dict(d.get("qos_mix") or {})
+        rec.phases = dict(d.get("phases") or {})
         return rec
+
+
+#: what the engine loop's thread can be doing (docs/observability.md "The
+#: phase clock" says what each covers); ``other`` is what no mark covers
+PHASES = ("idle", "plan", "blocked", "build", "put", "dispatch", "sample",
+          "device_wait", "lag", "commit", "record", "other")
+
+
+class PhaseClock:
+    """Lap clock of ONE thread, the engine loop's. At any instant the thread
+    is in exactly one phase; ``mark`` enters a phase and thereby ends the
+    one before (one ``perf_counter()`` and one add: no nesting, so the
+    phases of an interval sum to it by construction). ``cut`` hands over
+    what has accumulated since the last cut — the engine cuts at every
+    flight record — and the open phase goes on.
+
+    With ``annotate`` the same transitions open and close a
+    ``jax.profiler.TraceAnnotation`` named ``dynamo.<phase>``: flat, never
+    nested, events of the profiler's host plane on the clock of the
+    device's ops, so a device trace says what the host was doing in every
+    idle gap. ``landed`` is the one call made from ANOTHER thread (hence
+    the lock, taken only when annotating).
+    """
+
+    def __init__(self, annotate: bool = False):
+        self._acc: dict[str, float] = {}
+        self._phase = "other"
+        self._t = time.perf_counter()
+        self._span = None        # the open TraceAnnotation
+        self._span_phase = ""
+        self._annotation = None  # the class, when annotating
+        self._lock = threading.Lock()
+        if annotate:
+            try:
+                from jax.profiler import TraceAnnotation
+                self._annotation = TraceAnnotation
+            except Exception:  # jax absent/old: never break serving
+                logger.warning("DYN_JAX_PROFILER is set but jax.profiler "
+                               "has no TraceAnnotation: not annotating")
+
+    def mark(self, phase: str, at: Optional[float] = None) -> None:
+        """Enter ``phase`` now, or at the earlier instant ``at`` (a stamp
+        another thread took; never before the open phase began)."""
+        now = time.perf_counter() if at is None else max(at, self._t)
+        acc = self._acc
+        acc[self._phase] = acc.get(self._phase, 0.0) + (now - self._t)
+        self._t = now
+        self._phase = phase
+        if self._annotation is not None:
+            self._annotate(phase)
+
+    def landed(self) -> float:
+        """Called in a WORKER thread the instant a step's result is on the
+        host: the stamp that ends the serving thread's ``device_wait``
+        (handed back through the await and given to ``mark`` as ``at``).
+        An annotation cannot be opened in the past, so where the serving
+        thread is waiting the worker flips it to ``dynamo.lag`` here."""
+        now = time.perf_counter()
+        if self._annotation is not None and self._phase == "device_wait":
+            self._annotate("lag")
+        return now
+
+    def _annotate(self, phase: str) -> None:
+        with self._lock:
+            if phase == self._span_phase:
+                return
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+            self._span = self._annotation("dynamo." + phase)
+            self._span.__enter__()
+            self._span_phase = phase
+
+    def cut(self) -> tuple[float, dict]:
+        """``(period_ms, {phase: ms})`` since the last cut, zero entries
+        left out; the period IS the sum of the phases."""
+        self.mark(self._phase)
+        phases = {k: v * 1000.0 for k, v in self._acc.items() if v > 0.0}
+        self._acc = {}
+        return sum(phases.values()), phases
+
+    def close(self) -> None:
+        """End the open annotation (the loop has stopped)."""
+        with self._lock:
+            if self._span is not None:
+                self._span.__exit__(None, None, None)
+            self._span, self._span_phase = None, ""
 
 
 class FlightRecorder:
@@ -496,6 +602,29 @@ class FlightRecorder:
             "onboard_inflight": self.gauges.get("onboard_inflight", 0),
             "restore_inflight": self.gauges.get("restore_inflight", 0),
         }
+
+    def kind_summary(self, n: int = 2048) -> dict:
+        """The newest ``n`` records by kind: steps / seqs / tokens / total
+        and mean wall / padded tokens — what the worker's ``/metrics``
+        prints as ``engine_step_*`` (a sliding window; empty when
+        recording is off). Marks nothing as served."""
+        with self._lock:
+            recs = list(itertools.islice(reversed(self._ring), n))
+        agg: dict[str, list] = {}
+        for r in recs:
+            if r.kind == "empty":
+                continue
+            a = agg.setdefault(r.kind, [0, 0, 0, 0.0, 0])
+            a[0] += 1
+            a[1] += r.decode_rows + r.prefill_chunks
+            a[2] += r.tokens
+            a[3] += r.wall_ms
+            a[4] += r.padded_tokens
+        return {k: {"steps": a[0], "seqs": a[1], "tokens": a[2],
+                    "total_ms": round(a[3], 1),
+                    "mean_ms": round(a[3] / a[0], 1),
+                    "padded_tokens": a[4]}
+                for k, a in agg.items()}
 
     def export_jsonl(self, path: str) -> int:
         """Dump the ring as JSONL; returns the line count."""

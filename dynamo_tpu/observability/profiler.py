@@ -1,13 +1,19 @@
-"""``jax.profiler`` hooks: dispatch annotation + anomaly-triggered capture.
+"""``jax.profiler`` hooks: the annotation gate + anomaly-triggered capture.
 
 Two env-gated layers, both off by default:
 
-- ``DYN_JAX_PROFILER=1`` wraps each jitted step dispatch in a
-  ``jax.profiler.TraceAnnotation``, so device traces captured with
-  ``jax.profiler.start_trace`` carry the serving-layer phase names
-  (``dynamo.prefill_step`` / ``dynamo.decode_step``) and line up with the
-  request spans recorded by the tracer. The annotation is a per-dispatch
-  host-side cost the steady-state serving loop should not pay unasked.
+- ``DYN_JAX_PROFILER=1`` (:func:`enabled`) makes the engine loop's phase
+  clock (``flight.PhaseClock``) open a ``jax.profiler.TraceAnnotation`` at
+  every transition, so device traces captured with
+  ``jax.profiler.start_trace`` carry, on the clock of the device's ops,
+  what the serving thread was doing: ``dynamo.idle``, ``dynamo.plan``,
+  ``dynamo.blocked``, ``dynamo.build``, ``dynamo.put``,
+  ``dynamo.dispatch``, ``dynamo.sample``, ``dynamo.device_wait``,
+  ``dynamo.lag``, ``dynamo.commit``, ``dynamo.record``, ``dynamo.other``
+  (``flight.PHASES``) — flat, never nested
+  (docs/observability.md "The phase clock"). The annotations are a
+  per-transition host-side cost the steady-state serving loop should not
+  pay unasked.
 
 - ``DYN_PROFILE_ON_ANOMALY=<dir>`` arms :class:`AnomalyProfiler`: when the
   flight recorder tags a step ``slow-step`` or ``compile-steady``, ONE
@@ -24,7 +30,6 @@ Two env-gated layers, both off by default:
 
 from __future__ import annotations
 
-import contextlib
 import logging
 import os
 import time
@@ -47,22 +52,6 @@ def enabled() -> bool:
 def _reset_for_tests() -> None:
     global _enabled
     _enabled = None
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """``with annotate("dynamo.decode_step"): <dispatch>`` — no-op unless
-    DYN_JAX_PROFILER is set and jax's profiler is importable."""
-    if not enabled():
-        yield
-        return
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # jax absent/old: gating must never break serving
-        yield
-        return
-    with TraceAnnotation(name):
-        yield
 
 
 # ------------------------------------------------- anomaly-triggered capture

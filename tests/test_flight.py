@@ -1,10 +1,13 @@
 """Fleet flight recorder (docs/observability.md "Flight recorder"):
 ring-bounded memory, inline anomaly tagging, fleet fan-out with dead-worker
 drop, JSONL round-trip, mocker parity, trace head-sampling, hub event
-instrumentation, engine compile visibility, and tier occupancy gauges."""
+instrumentation, engine compile visibility, tier occupancy gauges, and the
+engine loop's phase clock (records' ``period_ms`` / ``phases``, the
+``dynamo.<phase>`` trace annotations)."""
 
 import asyncio
 import json
+import types
 
 import msgpack
 import pytest
@@ -17,8 +20,11 @@ from dynamo_tpu.observability import (
     serve_flight,
     trace_sampled,
 )
+from dynamo_tpu.observability import flight as flight_mod
 from dynamo_tpu.observability.flight import (
     FLIGHT_PREFIX,
+    PHASES,
+    PhaseClock,
     TAG_COMPILE_STEADY,
     TAG_EMPTY,
     TAG_PREEMPT_STORM,
@@ -406,6 +412,66 @@ async def test_hub_stats_over_tcp_and_metrics_render():
         await server.stop()
 
 
+# ------------------------------------------------------------- phase clock
+
+
+def test_phase_clock_laps_sum_to_the_period(monkeypatch):
+    """A lap clock: entering a phase ends the one before, a phase entered
+    twice accumulates, a worker's stamp splits a wait into ``device_wait``
+    and ``lag``, and ``cut`` hands over the laps and starts again. (Times
+    are binary fractions, so the sums are exact.)"""
+    now = [10.0]
+    monkeypatch.setattr(flight_mod, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0]))
+    clock = PhaseClock()
+
+    def after(seconds, phase, **kw):
+        now[0] += seconds
+        clock.mark(phase, **kw)
+
+    after(0.25, "plan")          # 0.25 s in no phase: ``other``
+    after(0.5, "build")
+    after(1.0, "dispatch")
+    after(0.125, "build")        # a second build accumulates
+    after(2.0, "device_wait")
+    # the result landed 0.5 s into a wait the loop left after 0.75 s
+    after(0.75, "lag", at=now[0] + 0.5)
+    clock.mark("commit")
+    now[0] += 0.0625
+    period, phases = clock.cut()
+    assert phases == {"other": 250.0, "plan": 500.0, "build": 3000.0,
+                      "dispatch": 125.0, "device_wait": 500.0, "lag": 250.0,
+                      "commit": 62.5}
+    assert period == sum(phases.values()) == 4687.5
+    # the cut reset the laps, the open phase goes on, zeros are left out
+    now[0] += 0.5
+    assert clock.cut() == (500.0, {"commit": 500.0})
+    # a stamp from before the wait began (the result was there already):
+    # no ``device_wait`` at all, the whole await is ``lag``
+    clock.mark("device_wait")
+    after(0.25, "lag", at=now[0] - 5.0)
+    clock.mark("commit")
+    assert clock.cut() == (250.0, {"lag": 250.0})
+    assert set(phases) <= set(PHASES)
+
+
+def test_step_record_carries_period_and_sparse_phases():
+    rec = make_recorder()
+    r = rec.record("decode_pipe", 19.0, period_ms=9.5,
+                   phases={"build": 3.0, "dispatch": 2.25, "commit": 4.25},
+                   dispatch_ms=2.25, decode_rows=8)
+    d = r.to_dict()
+    assert d["period_ms"] == 9.5 and d["dispatch_ms"] == 2.25
+    assert d["phases"] == {"build": 3.0, "dispatch": 2.25, "commit": 4.25}
+    back = StepRecord.from_dict(json.loads(json.dumps(d)))
+    assert back.period_ms == 9.5 and back.phases == d["phases"]
+    assert back.wall_ms == 19.0   # the pipe latency keeps its own field
+    # a recorder that runs no clock (the mocker) keeps both off the wire
+    bare = rec.record("mock", 5.0).to_dict()
+    assert "period_ms" not in bare and "phases" not in bare
+    assert StepRecord.from_dict(bare).phases == {}
+
+
 # ------------------------------------------- engine parity + compile + tiers
 
 
@@ -516,14 +582,16 @@ async def test_engine_flight_disabled_is_pure_observation(tiny_engine_cfg):
         toks = []
         async for out in eng.generate(req):
             toks.extend(out.token_ids)
-        recs = len(eng.flight)
+        recs, clock = len(eng.flight), eng._clock
         await eng.close()
-        return toks, recs
+        return toks, recs, clock
 
-    on_toks, on_recs = await run(True)
-    off_toks, off_recs = await run(False)
+    on_toks, on_recs, on_clock = await run(True)
+    off_toks, off_recs, off_clock = await run(False)
     assert on_toks == off_toks
     assert on_recs > 0 and off_recs == 0
+    # nothing to feed: the phase clock is not even made, a mark is one test
+    assert on_clock is not None and off_clock is None
 
 
 @pytest.mark.parametrize("profiled", [False, True],
@@ -608,3 +676,141 @@ async def test_engine_storm_and_steady_compile(profiled, monkeypatch,
                        for r in eng.flight.snapshot())
     finally:
         await eng.close()
+
+
+def _tiny_request(n_prompt, max_tokens):
+    from dynamo_tpu.protocols import (PreprocessedRequest, SamplingOptions,
+                                      StopConditions)
+
+    return PreprocessedRequest(
+        model="m", token_ids=list(range(1, n_prompt + 1)),
+        stop_conditions=StopConditions(max_tokens=max_tokens,
+                                       ignore_eos=True),
+        sampling_options=SamplingOptions(temperature=0.0))
+
+
+async def _stream(eng, n_prompt, max_tokens):
+    n = 0
+    async for out in eng.generate(_tiny_request(n_prompt, max_tokens)):
+        n += len(out.token_ids)
+    assert n == max_tokens
+
+
+async def test_engine_phase_clock_through_mixed_and_pipelined_steps(
+        tiny_engine_cfg):
+    """Every step record says what its step cost the loop (``period_ms``)
+    and what the loop did meanwhile (``phases``, summing to it): a pause
+    between two requests is the NEXT record's ``idle`` and nobody else's, a
+    pipelined step's dispatch is timed like a mixed step's, and a plan that
+    could run nothing leaves a ``blocked`` record with no step phase."""
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.engine.scheduler import StepPlan
+
+    cfg, base = tiny_engine_cfg
+    eng = AsyncJaxEngine(cfg, EngineArgs(**base))
+    try:
+        await _stream(eng, 29, 6)
+        await asyncio.sleep(0.05)           # the loop has nothing to run
+        before = len(eng.flight.snapshot())
+        # the next plan finds nothing it can run, once: the loop waits for
+        # a wake-up and leaves an ``empty`` record
+        plan, calls = eng.scheduler.plan, []
+
+        def plan_once_empty():
+            calls.append(1)
+            return StepPlan() if len(calls) == 1 else plan()
+
+        eng.scheduler.plan = plan_once_empty
+        # 100 tokens = chunks of 64 + 36 beside the other's decode rows
+        await asyncio.gather(_stream(eng, 20, 5), _stream(eng, 100, 4))
+        snap = eng.flight.snapshot()
+    finally:
+        await eng.close()
+    steps = [r for r in snap if r["kind"] in ("ragged", "decode_pipe")]
+    assert {r["kind"] for r in steps} == {"ragged", "decode_pipe"}
+    for r in snap:
+        assert set(r["phases"]) <= set(PHASES), r
+        assert abs(sum(r["phases"].values()) - r["period_ms"]) <= 0.01, r
+        assert r.get("dispatch_ms", 0.0) == r["phases"].get("dispatch", 0.0)
+    for r in steps:
+        assert {"commit", "record"} <= set(r["phases"]), r
+        if r["kind"] == "ragged":
+            assert ({"build", "put", "dispatch", "device_wait"}
+                    <= set(r["phases"])), r
+            # its sampler runs in the worker thread: ``device_wait``
+            assert "sample" not in r["phases"], r
+    # the depth-2 pipe dispatches step N+1 before it commits N: a run's
+    # first record holds two builds and dispatches, the one that drains the
+    # pipe none, every one between exactly one
+    piped = [r for r in steps if r["kind"] == "decode_pipe"]
+    drained = [r for r in piped if "build" not in r["phases"]]
+    assert all(not {"put", "dispatch", "sample"} & set(r["phases"])
+               for r in drained)
+    for r in piped:
+        if r not in drained:
+            assert r["dispatch_ms"] > 0, r
+            assert {"put", "dispatch", "sample"} <= set(r["phases"]), r
+    assert len(piped) > len(drained)
+    assert len(drained) <= sum("plan" in r["phases"] for r in piped)
+    # the pause: in the first record after it and nowhere else
+    assert [i for i, r in enumerate(snap) if "idle" in r["phases"]] == [before]
+    # the plan that could run nothing
+    empty = [r for r in snap if r["kind"] == "empty"]
+    assert len(empty) == 1 and snap.index(empty[0]) == before
+    assert {"plan", "blocked"} <= set(empty[0]["phases"])
+    assert not ({"build", "put", "dispatch", "sample", "device_wait"}
+                & set(empty[0]["phases"]))
+    assert all("blocked" not in r["phases"] for r in steps)
+
+
+@pytest.mark.parametrize("gate", ["", "1"], ids=["gate-off", "gate-on"])
+async def test_phase_annotations_are_flat_and_gated(gate, monkeypatch,
+                                                    tiny_engine_cfg):
+    """Under DYN_JAX_PROFILER=1 the clock's transitions open and close
+    ``dynamo.<phase>`` trace annotations, one at a time (a device trace
+    tags an idle gap by the annotation that holds it: nested ones would
+    hide); with the gate off none is made."""
+    import jax.profiler
+
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.engine.engine import AsyncJaxEngine
+    from dynamo_tpu.observability import profiler
+
+    events = []
+
+    class Recorded:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            events.append(("close", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorded)
+    monkeypatch.setenv("DYN_JAX_PROFILER", gate)
+    profiler._reset_for_tests()
+    cfg, base = tiny_engine_cfg
+    eng = AsyncJaxEngine(cfg, EngineArgs(**base))
+    try:
+        await asyncio.gather(_stream(eng, 20, 5), _stream(eng, 100, 4))
+        recorded = len(eng.flight)
+    finally:
+        await eng.close()
+        profiler._reset_for_tests()
+    assert recorded > 0               # the flight records need no gate
+    if not gate:
+        assert events == []
+        return
+    names = {name for _, name in events}
+    assert names <= {"dynamo." + p for p in PHASES}
+    assert {"dynamo.build", "dynamo.put", "dynamo.dispatch",
+            "dynamo.sample", "dynamo.device_wait", "dynamo.commit",
+            "dynamo.record"} <= names
+    # open, close, open, close ... each closed before the next opens, the
+    # last one by the engine's close()
+    assert len(events) % 2 == 0
+    for (what0, name0), (what1, name1) in zip(events[::2], events[1::2]):
+        assert (what0, what1) == ("open", "close") and name0 == name1

@@ -767,8 +767,9 @@ async def test_token_budget_plan_deletes_chunk_clamp():
     assert eng.scheduler.token_budget
     toks, _ = await collect(eng, req(range(1, 32), max_tokens=2))
     assert len(toks) == 2
-    ragged_entries = [e for e in eng.step_trace if e[0] == "ragged"]
-    assert ragged_entries[0][2] == 31, \
+    ragged_entries = [r for r in eng.flight.snapshot()
+                      if r["kind"] == "ragged"]
+    assert ragged_entries[0]["chunk_tokens"] == 31, \
         "first ragged step should carry the whole 31-token prompt"
     await eng.close()
 
@@ -776,7 +777,7 @@ async def test_token_budget_plan_deletes_chunk_clamp():
     e_b = tiny_engine(max_num_batched_tokens=8, prefill_buckets=(8,))
     toks_b, _ = await collect(e_b, req(range(1, 32), max_tokens=2))
     assert toks_b == toks
-    ragged_b = [e for e in e_b.step_trace if e[0] == "ragged"]
+    ragged_b = [r for r in e_b.flight.snapshot() if r["kind"] == "ragged"]
     assert len(ragged_b) >= 4, "8-token budget should need >= 4 chunks"
     await e_b.close()
 
@@ -790,9 +791,13 @@ async def test_padded_tokens_and_signature_metrics():
     assert eng.compiled_signatures
     assert all(k in ("ragged", "ragged_dec")
                for k, *_ in eng.compiled_signatures)
-    # the step trace surfaces per-kind padded totals
+    # the flight records carry each step's padding, and the per-kind
+    # summary (engine_step_* on /metrics) adds them up
+    steps = [r for r in eng.flight.snapshot() if r["kind"] != "empty"]
     summary = eng.step_trace_summary()
-    assert all("padded_tokens" in v for v in summary.values())
+    assert set(summary) == {r["kind"] for r in steps}
+    assert (sum(v["padded_tokens"] for v in summary.values())
+            == sum(r["padded_tokens"] for r in steps) > 0)
     await eng.close()
 
 
