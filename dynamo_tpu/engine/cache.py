@@ -468,12 +468,14 @@ def allocate_device_cache(cfg, num_blocks: int, block_size: int, mesh=None,
 
 
 def allocate_state(cfg, slots: int):
-    """The recurrent-state arrays of a model with Mamba-2 layers (zeros):
-    (conv ``[L, slots + 1, (d_conv - 1) · C]``, ssm ``[L, slots + 1, H // pack,
-    N, pack · P]``) — one slot a running sequence and the dump slot padding
-    rows write to, last. None for a model without state layers. The third
-    kind of cache beside the pages: allocate it BEFORE the pool is sized,
-    so that :func:`hbm_sized_num_blocks` sees what it left."""
+    """The recurrent-state arrays of a model with state layers (zeros), one
+    slot a running sequence and the dump slot padding rows write to, last:
+    the convolution's tail ``[L, slots + 1, (taps - 1) · C]`` and, for
+    Mamba-2 layers, the SSM state ``[L, slots + 1, H // pack, N, pack · P]``
+    beside it — a tuple of one array (the short convolution: its tail is
+    its whole state) or two. None for a model without state layers. The
+    third kind of cache beside the pages: allocate it BEFORE the pool is
+    sized, so that :func:`hbm_sized_num_blocks` sees what it left."""
     import jax.numpy as jnp
 
     spec = cfg.state_spec
@@ -481,7 +483,10 @@ def allocate_state(cfg, slots: int):
         return None
     n = len(spec.layers)
     taps, width = spec.conv_shape
-    return (jnp.zeros((n, slots + 1, taps * width), spec.conv_dtype),
+    conv = jnp.zeros((n, slots + 1, taps * width), spec.conv_dtype)
+    if spec.ssm_shape is None:
+        return (conv,)
+    return (conv,
             jnp.zeros((n, slots + 1, *spec.ssm_shape), spec.ssm_dtype))
 
 

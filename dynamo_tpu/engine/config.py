@@ -32,30 +32,43 @@ class LayerKind(NamedTuple):
     window: int  # 0 = full attention
     sink: bool   # learned per-head sink logit joins the softmax
     #: what mixes tokens: "attention" (keys and values a token, in a cache
-    #: group) or "mamba2" (one fixed-size state record a SEQUENCE, in a
-    #: state slot: ``ModelConfig.state_spec``; the fields above are unused)
+    #: group), or one of the recurrent mixers, which keep one fixed-size
+    #: state record a SEQUENCE in a state slot (``ModelConfig.state_spec``;
+    #: the fields above are unused): "mamba2" (a convolution's tail and an
+    #: SSM matrix) or "shortconv" (LFM2's gated short convolution: the tail
+    #: alone)
     mixer: str = "attention"
 
 
+#: the mixers whose layers keep their state in a slot, not in pages
+STATE_MIXERS = ("mamba2", "shortconv")
+
+
 class StateSpec(NamedTuple):
-    """The per-sequence recurrent state of a model's Mamba-2 layers: one
-    record a layer a slot, whatever the sequence's length. ``conv`` is the
-    convolution's tail (the last ``d_conv - 1`` inputs), ``ssm`` the state
-    matrix of every head, stored with ``pack`` heads side by side on the
-    minor axis so that it is a whole 128-lane row (ops/mamba2.py)."""
+    """The per-sequence recurrent state of a model's state layers (either
+    recurrent mixer; a model has one of the two): one record a layer a
+    slot, whatever the sequence's length. ``conv`` is the convolution's
+    tail (the last ``taps - 1`` inputs). A Mamba-2 layer keeps ``ssm``
+    beside it, the state matrix of every head, stored with ``pack`` heads
+    side by side on the minor axis so that it is a whole 128-lane row
+    (ops/mamba2.py); a short-convolution layer keeps the tail and nothing
+    else (``ssm_shape`` None: ops/shortconv.py)."""
 
     layers: tuple      # model layer indices, in order
-    conv_shape: tuple  # (d_conv - 1, d_inner + 2·groups·d_state)
-    ssm_shape: tuple   # (heads // pack, d_state, pack · d_head)
+    conv_shape: tuple  # (taps - 1, channels of the convolution)
+    ssm_shape: Optional[tuple]  # (heads // pack, d_state, pack · d_head)
     conv_dtype: str
-    ssm_dtype: str
+    ssm_dtype: Optional[str]
+    mixer: str = "mamba2"
 
     def bytes_per_slot(self) -> int:
         import numpy as np
 
+        ssm = 0 if self.ssm_shape is None else (
+            int(np.prod(self.ssm_shape)) * np.dtype(self.ssm_dtype).itemsize)
         return len(self.layers) * (
             int(np.prod(self.conv_shape)) * np.dtype(self.conv_dtype).itemsize
-            + int(np.prod(self.ssm_shape)) * np.dtype(self.ssm_dtype).itemsize)
+            + ssm)
 
 
 class CacheGroup(NamedTuple):
@@ -89,10 +102,12 @@ class ModelConfig:
     attention with a SwiGLU or token-choice expert MLP. Covers the Llama
     family (Llama 2/3, Mistral, Qwen2/3, Phi-3), Gemma 1/2, gpt-oss
     (per-layer windows, sinks), DeepSeek V2/V3 (MLA, shared experts,
-    sigmoid routing) and MiMo-V2 (layer KINDS with their own KV-head
+    sigmoid routing), MiMo-V2 (layer KINDS with their own KV-head
     count, rope base, window and sink; K/Q heads wider than V heads;
     partial rotary; an expert layer that holds a share of the experts it
-    routes over)."""
+    routes over) and the hybrids whose layer kinds include a recurrent
+    mixer with per-sequence state in slots: Granite 4.0-H (Mamba-2) and
+    LFM2 (gated short convolution)."""
 
     vocab_size: int = 32000
     hidden_size: int = 4096
@@ -225,12 +240,31 @@ class ModelConfig:
     #: dtype the state matrix is kept in between steps (assumed float32:
     #: what engines recommend for this family's accuracy)
     mamba_state_dtype: str = "float32"
+    # --- LFM2 (lfm2_moe) ---------------------------------------------------
+    #: taps of the gated short convolution (layer kinds whose ``mixer`` is
+    #: "shortconv"; the published ``conv_L_cache``): a sequence carries the
+    #: last ``shortconv_taps - 1`` inputs of every such layer, nothing else
+    shortconv_taps: int = 3
+    #: added to the sum of a token's chosen gates before they are divided by
+    #: it (``norm_topk_prob``): 1e-20 is DeepSeek's and MiMo's, LFM2
+    #: publishes 1e-6
+    router_norm_eps: float = 1e-20
+    #: store K and V heads narrower than a 128-lane row zero-padded to one,
+    #: so that the ragged kernel (which strides whole lane rows) takes a
+    #: model of 64-wide heads: a page doubles, the zeros add nothing to a
+    #: score and the output's padding lanes are cut. False: such heads are
+    #: stored as they are and the kernel refuses them (``lane_align``)
+    kv_lane_pad: bool = False
     #: random init only: std of the (tied) embedding's entries where fan-in
     #: scaling would leave the logits flat, and a gain on every projection
     #: that writes into the residual stream (wo, out_proj, the experts' and
     #: the shared expert's down projections). None / 1.0 = fan-in scaling
     init_embed_std: Optional[float] = None
     init_out_gain: float = 1.0
+    #: random init only: std of every RMSNorm weight around 1 (0: exactly
+    #: 1, the neutral value, at which a path that forgets a norm's weight
+    #: computes the same)
+    init_norm_std: float = 0.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -248,11 +282,14 @@ class ModelConfig:
                     f"of {len(self.layer_kinds)} kinds for each of "
                     f"{self.num_layers} layers")
             mixers = [k.mixer for k in self.layer_kinds]
-            if sorted(mixers) != mixers or mixers[0] != "attention":
+            n_attn = mixers.count("attention")
+            state = set(mixers[n_attn:])
+            if (not n_attn or "attention" in state or len(state) > 1
+                    or not state <= set(STATE_MIXERS)):
                 # a kind's index is its cache group's: attention kinds first
                 raise ValueError(
                     f"layer_kinds {mixers}: attention kinds come first, "
-                    "state kinds after them")
+                    f"then the kinds of ONE state mixer of {STATE_MIXERS}")
         if self.experts_held is not None:
             first, count = self.experts_held = tuple(self.experts_held)
             if not 0 <= first < first + count <= self.num_experts:
@@ -310,8 +347,28 @@ class ModelConfig:
         next one, as that many whole 128-lane rows (``k_lane_rows``): the
         ragged kernel DMAs lane rows and strides their sublanes, q is
         padded alike, and the zeros add nothing to a score."""
-        return 128 * self.k_lane_rows if self.k_lane_rows > 1 \
-            else self.head_dim
+        if self.k_lane_rows > 1:
+            return 128 * self.k_lane_rows
+        return self._lane_padded(self.head_dim)
+
+    @property
+    def v_cache_dim(self) -> int:
+        """Stored width of a V head: ``v_dim``, or a whole lane row under
+        ``kv_lane_pad``."""
+        return self._lane_padded(self.v_dim)
+
+    def _lane_padded(self, width: int) -> int:
+        return 128 if self.kv_lane_pad and width < 128 else width
+
+    @property
+    def kv_lane_pad_share(self) -> float:
+        """Share of a KV page's bytes that is padding (wide K heads stored
+        as lane rows, narrow heads under ``kv_lane_pad``): 0 where heads
+        are stored as they are."""
+        if self.is_mla:
+            return 0.0
+        return 1.0 - (self.head_dim + self.v_dim) / (
+            self.k_cache_dim + self.v_cache_dim)
 
     @property
     def k_lane_rows(self) -> int:
@@ -345,12 +402,12 @@ class ModelConfig:
                                self.rope_cache_dim, 0),)
         if self.layer_kinds is None:
             return (CacheGroup(every, self.num_kv_heads, self.k_cache_dim,
-                               self.v_dim, self.sliding_window or 0,
+                               self.v_cache_dim, self.sliding_window or 0,
                                self.k_lane_rows),)
         return tuple(
             CacheGroup(tuple(i for i in every if self.layer_pattern[i] == g),
-                       k.num_kv_heads, self.k_cache_dim, self.v_dim, k.window,
-                       self.k_lane_rows)
+                       k.num_kv_heads, self.k_cache_dim, self.v_cache_dim,
+                       k.window, self.k_lane_rows)
             for g, k in enumerate(self.layer_kinds) if k.mixer == "attention")
 
     @property
@@ -369,9 +426,13 @@ class ModelConfig:
         if self.layer_kinds is None:
             return None
         layers = tuple(i for i, k in enumerate(self.layer_pattern)
-                       if self.layer_kinds[k].mixer == "mamba2")
+                       if self.layer_kinds[k].mixer != "attention")
         if not layers:
             return None
+        if self.layer_kinds[-1].mixer == "shortconv":
+            return StateSpec(layers,
+                             (self.shortconv_taps - 1, self.hidden_size),
+                             None, self.dtype, None, "shortconv")
         pack = self.mamba_head_pack
         return StateSpec(
             layers,
@@ -434,6 +495,9 @@ class ModelConfig:
                 # one rank's share: the key that counts the experts gives
                 # how many are held, the router keeps its published width
                 d["n_routed_experts"] = d["n_routed_experts_published"]
+        if d.get("model_type") == "lfm2_moe":
+            kinds = _lfm2_moe_fields(d)
+            d = {**d, **kinds.pop("keys")}
         if is_gemma2:
             # HF Gemma2: sliding attention on EVEN layer indices
             # (Gemma2DecoderLayer: is_sliding = not bool(layer_idx % 2))
@@ -498,7 +562,7 @@ class ModelConfig:
             **kinds,
             qkv_bias=("qwen2" in arch
                       or (is_gpt_oss and d.get("attention_bias", True))),
-            qk_norm="qwen3" in arch,
+            qk_norm="qwen3" in arch or d.get("model_type") == "lfm2_moe",
             o_bias=is_gpt_oss and d.get("attention_bias", True),
             layer_windows=layer_windows,
             attention_sinks=is_gpt_oss,
@@ -554,6 +618,40 @@ class ModelConfig:
             rope_theta=500000.0, max_position_embeddings=8192,
             tie_word_embeddings=True,
         )
+
+
+def _lfm2_moe_fields(d: dict) -> dict:
+    """LFM2-MoE's published keys → the ModelConfig fields they set:
+    ``layer_types`` ("conv": the gated short convolution, anything else an
+    attention layer), ``conv_L_cache``, ``num_dense_layers``, ``norm_eps``,
+    ``rope_parameters`` (or a bare ``rope_theta``), ``use_expert_bias``.
+    Every expert is held (``experts_held`` = all) unless the file says
+    otherwise, the head is the embedding unless it says otherwise, and a
+    64-wide head is stored as a whole lane row (``kv_lane_pad``)."""
+    if d.get("conv_bias"):
+        raise NotImplementedError("lfm2_moe with conv_bias is not supported")
+    if not d.get("use_expert_bias", True):
+        raise NotImplementedError(
+            "lfm2_moe without use_expert_bias (a softmax router) is not "
+            "supported")
+    rope = d.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        raise NotImplementedError(f"lfm2_moe rope_parameters {rope}")
+    theta = float(rope.get("rope_theta", d.get("rope_theta", 1e6)))
+    types = d["layer_types"]
+    E = d["num_experts"]
+    return dict(
+        layer_kinds=((d["num_key_value_heads"], theta, 0, False),
+                     (0, 0.0, 0, False, "shortconv")),
+        layer_pattern=tuple(int(t == "conv") for t in types),
+        shortconv_taps=int(d.get("conv_L_cache", 3)),
+        kv_lane_pad=True, router_norm_eps=1e-6,
+        experts_held=tuple(d.get("experts_held") or (0, E)),
+        keys={"first_k_dense_replace": d.get("num_dense_layers", 0),
+              "rms_norm_eps": d.get("norm_eps", 1e-5),
+              "rope_theta": theta, "scoring_func": "sigmoid",
+              "tie_word_embeddings": d.get("tie_word_embeddings", True)},
+    )
 
 
 def _mimo_v2_fields(d: dict) -> dict:

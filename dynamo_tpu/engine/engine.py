@@ -81,8 +81,8 @@ def _layers_by_kind(cfg: ModelConfig) -> dict:
     out = {"full": sum(not k.window for k in attn),
            "window": sum(bool(k.window) for k in attn),
            "dense": cfg.num_layers - n_moe, "experts": n_moe}
-    if len(attn) < len(kinds):
-        out["mamba2"] = len(kinds) - len(attn)
+    if len(attn) < len(kinds):  # one state mixer a model
+        out[cfg.state_spec.mixer] = len(kinds) - len(attn)
     return out
 
 
@@ -193,9 +193,10 @@ class AsyncJaxEngine:
                     "parallelism (pp_size=%d); set speculative_tokens=0"
                     % (args.speculative_tokens, self._pp))
         groups = cfg.kv_cache_spec
-        #: recurrent state (a model with Mamba-2 layers): one slot a
-        #: running sequence, allocated BEFORE the pool is sized; None for
-        #: every other model, and nothing below then looks at state
+        #: recurrent state (a model with Mamba-2 or short-convolution
+        #: layers): one slot a running sequence, allocated BEFORE the pool
+        #: is sized; None for every other model, and nothing below then
+        #: looks at state
         self.state = None
         self.state_bytes = 0
         self._row_cols = 3  # columns of a step's per-row operand
@@ -216,7 +217,8 @@ class AsyncJaxEngine:
             if unmet:
                 raise ValueError(
                     f"a model with recurrent state ({len(spec.layers)} "
-                    "Mamba-2 layers) does not support: " + "; ".join(unmet))
+                    f"{spec.mixer} layers) does not support: "
+                    + "; ".join(unmet))
             if args.enable_prefix_caching:
                 logger.warning(
                     "prefix reuse switched off: a prefix hit would need the "
@@ -299,6 +301,15 @@ class AsyncJaxEngine:
         #: the step's other outputs: dynamo_moe_assignments_total{to},
         #: dynamo_moe_expert_tokens_total{expert}, dynamo_moe_row_tiles_total
         self._moe_held = cfg.is_moe and cfg.experts_held is not None
+        if (cfg.is_moe and not self._moe_held and mesh is None
+                and cfg.num_experts > 8):
+            logger.warning(
+                "%d experts and experts_held is None: on one chip every "
+                "token runs through EVERY expert (the one-hot layer, %.0f x "
+                "the work of its %d chosen); set experts_held=(0, %d) to "
+                "take the dropless held-experts layer", cfg.num_experts,
+                cfg.num_experts / cfg.num_experts_per_tok,
+                cfg.num_experts_per_tok, cfg.num_experts)
         self.moe_assignments_total = (
             {"all": 0, "held": 0} if self._moe_held else {})
         self.moe_expert_tokens_total = np.zeros(
@@ -324,6 +335,7 @@ class AsyncJaxEngine:
             "experts_per_tok": cfg.num_experts_per_tok,
             "expert_ffn": cfg.moe_ffn_size if cfg.is_moe else None,
             "hidden_size": cfg.hidden_size,
+            "kv_lane_pad_share": cfg.kv_lane_pad_share,
             "cache_groups": [
                 {"layers": len(g.layers), "kv_heads": g.kv_heads,
                  "k_dim": g.k_dim, "v_dim": g.v_dim, "window": g.window,
@@ -334,9 +346,11 @@ class AsyncJaxEngine:
             **({"state_slots": args.max_num_seqs,
                 "state_bytes": self.state_bytes,
                 "state_layers": len(spec.layers),
-                "mamba": {"heads": cfg.mamba_n_heads,
+                "state_mixer": spec.mixer} if spec else {}),
+            **({"mamba": {"heads": cfg.mamba_n_heads,
                           "d_head": cfg.mamba_d_head,
-                          "d_state": cfg.mamba_d_state}} if spec else {}),
+                          "d_state": cfg.mamba_d_state}}
+               if spec and spec.mixer == "mamba2" else {}),
             "bytes_in_use_before": mem_before and mem_before["bytes_in_use"],
             "bytes_in_use_after": mem_after and mem_after["bytes_in_use"],
             "bytes_limit": mem_after and mem_after["bytes_limit"],

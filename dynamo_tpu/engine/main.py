@@ -588,9 +588,16 @@ async def amain():
         "small tile (prompt chunks): how often its wide query tile "
         "engages").add_callback(
         lambda: {None: engine.wide_tile_rows_total})
+    runtime.metrics.gauge(
+        "kv_lane_pad_share",
+        "share of a KV page's bytes that is padding: heads stored as whole "
+        "128-lane rows for the ragged kernel (0.5: 64-wide heads padded "
+        "to one row; 0: heads stored as they are)").add_callback(
+        lambda: {None: engine.cfg.kv_lane_pad_share})
     if engine.state is not None:
-        # recurrent state (a model with Mamba-2 layers): one slot a running
-        # sequence beside the KV pool; a model without state has no family
+        # recurrent state (a model with Mamba-2 or short-convolution
+        # layers): one slot a running sequence beside the KV pool; a model
+        # without state has no family
         runtime.metrics.gauge(
             "state_slots_in_use",
             "recurrent-state slots held by running sequences").add_callback(
@@ -604,7 +611,8 @@ async def amain():
         runtime.metrics.gauge(
             "state_bytes",
             "device bytes of the recurrent-state arrays (every slot and "
-            "the dump slot, conv and ssm)").add_callback(
+            "the dump slot: the convolution's tails and, for Mamba-2 "
+            "layers, the SSM state)").add_callback(
             lambda: {None: engine.state_bytes})
     # held-experts layer (one rank's share of an expert-parallel layer):
     # how much of the routing lands here, and on which experts

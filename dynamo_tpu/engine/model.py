@@ -164,6 +164,14 @@ def _const_leaf(value, shape, dtype) -> _LazyLeaf:
     return _LazyLeaf(shape, jnp.dtype(dtype), None, value)
 
 
+def _norm_leaf(cfg: ModelConfig, key, shape, dtype) -> _LazyLeaf:
+    """An RMSNorm weight: 1, or drawn around it (``cfg.init_norm_std``)."""
+    if not cfg.init_norm_std:
+        return _const_leaf(1.0, shape, dtype)
+    return dataclasses.replace(
+        _normal_leaf(key, shape, cfg.init_norm_std ** -2, dtype), offset=1.0)
+
+
 def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
                       dtype, kind=None) -> dict:
     """Random-init one stacked layer group (n layers, dense or MoE MLP).
@@ -178,8 +186,11 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
     ks = jax.random.split(key, 16)
 
     w = functools.partial(_normal_leaf, dtype=dtype)
-    ones = functools.partial(_const_leaf, 1.0, dtype=dtype)
     zeros = functools.partial(_const_leaf, 0.0, dtype=dtype)
+    norm_keys = iter(jax.random.split(jax.random.fold_in(key, 1), 8))
+
+    def ones(shape):  # a norm's weight
+        return _norm_leaf(cfg, next(norm_keys), shape, dtype)
 
     def by_heads(key, heads, width):
         return dataclasses.replace(w(key, (n, heads, width, D), D),
@@ -211,6 +222,13 @@ def _init_layer_stack(cfg: ModelConfig, key: jax.Array, n: int, moe: bool,
         layers["D"] = around(f32(ks[10], (n, Hm), 4), 1.0)
         layers["ssm_norm"] = around(w(ks[11], (n, di), 25), 1.0)
         layers["out_proj"] = w(ks[15], (n, di, D), out_fan(di))
+    elif kind is not None and kind.mixer == "shortconv":
+        # [B | C | x] = in_proj(u); the taps at fan-in scale, none at a
+        # neutral value: a tail that forgets an input computes otherwise
+        layers["in_proj"] = w(ks[0], (n, D, 3 * D), D)
+        layers["conv_w"] = w(ks[1], (n, cfg.shortconv_taps, D),
+                             cfg.shortconv_taps)
+        layers["out_proj"] = w(ks[15], (n, D, D), out_fan(D))
     elif cfg.is_mla:
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
@@ -302,7 +320,7 @@ def _layer_runs(cfg: ModelConfig) -> list:
             runs[-1][2] += 1
         else:
             kind = cfg.layer_kinds[stacks[s].kind]
-            home = (cfg.state_spec if kind.mixer == "mamba2"
+            home = (cfg.state_spec if kind.mixer != "attention"
                     else cfg.kv_cache_spec[stacks[s].kind])
             runs.append([s, off, 1, home.layers.index(i)])
     return [tuple(r) for r in runs]
@@ -337,7 +355,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=None, *,
     lazy = {
         "embed": w(ks[0], (V, D), D if cfg.init_embed_std is None
                    else cfg.init_embed_std ** -2),
-        "final_norm": _const_leaf(1.0, (D,), dtype=dtype),
+        "final_norm": _norm_leaf(cfg, jax.random.fold_in(key, 1), (D,),
+                                 dtype),
     }
     if cfg.layer_kinds is not None:
         # one stack per (kind, dense | experts), run in the published order
@@ -1303,7 +1322,7 @@ def _router_choice(xf, router_w, router_bias, cfg: ModelConfig):
         _, topi = jax.lax.top_k(choice, K)
         gates = jnp.take_along_axis(probs, topi, axis=1)
     if cfg.norm_topk_prob:
-        gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+        gates = gates / (gates.sum(-1, keepdims=True) + cfg.router_norm_eps)
     return topi, gates * cfg.routed_scaling_factor
 
 
@@ -1675,10 +1694,11 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     ``moe_routing`` adds every expert layer's choices [L_moe, B·S, K] as a
     fifth (chipbench/check_reference.py tells them to the reference).
 
-    A model with Mamba-2 layers (``cfg.state_spec``) runs the ragged step
-    only: ``state`` is its (conv, ssm) arrays (ops/mamba2.py), donated and
-    returned LAST, and ``rows3`` carries each row's state slot as a fourth
-    column.
+    A model with state layers (``cfg.state_spec``: Mamba-2 or short-
+    convolution mixers) runs the ragged step only: ``state`` is the tuple
+    of its state arrays (``cache.allocate_state``: ops/mamba2.py,
+    ops/shortconv.py), donated and returned LAST, and ``rows3`` carries
+    each row's state slot as a fourth column.
     """
     B, S = tokens.shape
     spec = cfg.state_spec
@@ -1697,12 +1717,14 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         return x + y if rm == 1.0 else x + y * jnp.asarray(rm, y.dtype)
 
     D, hd, vd = cfg.hidden_size, cfg.head_dim, cfg.v_dim
+    vcd = cfg.v_cache_dim  # width of a stored V row
     H = cfg.num_heads
     from dynamo_tpu.engine.cache import gather_pages, is_quant_cache
     kv_quant = is_quant_cache(k_cache)
     #: the ring, bucketed-decode and flash-prefill kernels know one KV-head
     #: count and one head width
-    one_width = hd == vd == cfg.k_cache_dim and cfg.layer_kinds is None
+    one_width = (hd == vd == cfg.k_cache_dim == vcd
+                 and cfg.layer_kinds is None)
     held = cfg.is_moe and cfg.experts_held is not None
     tok_valid = None
     #: which step program this is, in the grouped matmuls' op names: the
@@ -1779,9 +1801,14 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             k = _rope(k, positions, kind.rope_theta, cfg.rope_scaling,
                       cfg.rotary_dim)
         if cfg.k_cache_dim != hd:
-            # a wide K head is stored as whole lane rows; the zeros add
-            # nothing to a score, and every reader pads or cuts q to match
+            # a wide K head is stored as whole lane rows (a narrow one,
+            # under kv_lane_pad, as one); the zeros add nothing to a score,
+            # and every reader pads or cuts q to match
             k = jnp.pad(k, ((0, 0),) * 3 + ((0, cfg.k_cache_dim - hd),))
+        if vcd != vd:
+            # kv_lane_pad: V rows are whole lane rows too, and the lanes
+            # past vd of every reader's output are cut below
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, vcd - vd),))
         if cfg.query_pre_attn_scalar is not None:
             # Gemma-2: score scale is qpas^-0.5, not hd^-0.5; every path
             # below folds hd^-0.5, so pre-scale q by sqrt(hd/qpas)
@@ -1793,7 +1820,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             from dynamo_tpu.engine.cache import quantize_kv
 
             kq, ks = quantize_kv(k.reshape(B * S, KV, -1))
-            vq, vs = quantize_kv(v.reshape(B * S, KV, vd))
+            vq, vs = quantize_kv(v.reshape(B * S, KV, vcd))
             kq = kq.reshape(B * S, *kc["q"].shape[2:])
             kc = {"q": kc["q"].at[lidx, flat_slots].set(kq, mode="drop"),
                   "s": kc["s"].at[lidx, flat_slots].set(ks, mode="drop")}
@@ -1802,7 +1829,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         else:
             kc = kc.at[lidx, flat_slots].set(
                 k.reshape(B * S, *kc.shape[2:]), mode="drop")
-            vc = vc.at[lidx, flat_slots].set(v.reshape(B * S, KV, vd),
+            vc = vc.at[lidx, flat_slots].set(v.reshape(B * S, KV, vcd),
                                              mode="drop")
 
         # shard_map needs the (static) batch divisible by the dp axis
@@ -1862,7 +1889,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                                  and not cfg.attn_logit_softcap
                                  and ragged_pallas_supported(
                                      KV, cfg.k_cache_dim // cfg.k_lane_rows,
-                                     vd))
+                                     vcd))
             if use_ragged_kernel:
                 from dynamo_tpu.engine.cache import cache_shape
 
@@ -1879,7 +1906,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 # scale_slot_base so the VMEM scale budget is per-layer
                 attn = ragged_paged_attention(
                     q[0], kc["q"].reshape(flat, KV_, hd_),
-                    vc["q"].reshape(flat, KV, vd),
+                    vc["q"].reshape(flat, KV, vcd),
                     block_tables + lidx * nb, rows3,
                     block_size=block_size, window=window,
                     sinks=lp.get("sink"),
@@ -1891,7 +1918,7 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             elif use_ragged_kernel:
                 attn = ragged_paged_attention(
                     q[0], kc.reshape(flat, KV_, hd_),
-                    vc.reshape(flat, KV, vd),
+                    vc.reshape(flat, KV, vcd),
                     block_tables + lidx * nb, rows3,
                     block_size=block_size, window=window,
                     sinks=lp.get("sink"))[None]
@@ -1961,6 +1988,8 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
             attn = _paged_attention(q, kc, vc, lidx, block_tables, positions,
                                     kv_lens, cfg, block_size, window=window,
                                     sinks=lp.get("sink"))
+        if vcd != vd:
+            attn = attn[..., :vd]
         if cfg.value_scale != 1.0:  # P·(c·v) = c·(P·v)
             attn = attn * jnp.asarray(cfg.value_scale, attn.dtype)
         attn_out = _mm(attn.reshape(B, S, H * vd), lp["wo"])
@@ -1972,34 +2001,49 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
         x = _res(x, attn_out)
         return _mlp_epilogue(x, kc, vc, st, lp, moe, experts, group)
 
-    def make_mamba_layer(moe: bool, lps, experts=None, tag_group=0, run=""):
-        """The scan body of a run of Mamba-2 layers of the stack ``lps``:
-        the state arrays ride the carry (updated in place at the layer's
-        index), the KV caches stay outside."""
+    def _mix_mamba2(h, lp, state, lidx, run):
+        """One Mamba-2 mixer: (what ``out_proj`` reads, the state arrays)."""
         from dynamo_tpu.ops.mamba2 import mamba2_ragged
 
         di = cfg.mamba_d_inner
         cw = di + 2 * cfg.mamba_d_state
+        zxd = _mm(h, lp["in_proj"])[0]             # [T, di + cw + H]
+        y, *state = mamba2_ragged(
+            zxd[:, di:di + cw], zxd[:, di + cw:], lp, *state, lidx,
+            rows4, positions[0], cfg=cfg, chunks=ragged[3] is not None,
+            tag=f"_{run}_{program}")
+        # the gate first, then the norm, over all of d_inner
+        y = y * jax.nn.silu(zxd[:, :di].astype(jnp.float32))
+        return _rms_norm(y.astype(h.dtype), lp["ssm_norm"],
+                         cfg.rms_norm_eps), state
 
+    def _mix_shortconv(h, lp, state, lidx, run):
+        """One gated short convolution (no launch of its own to name)."""
+        from dynamo_tpu.ops.shortconv import shortconv_ragged
+
+        with jax.named_scope("shortconv"):
+            y, conv = shortconv_ragged(
+                _mm(h, lp["in_proj"])[0], lp["conv_w"], *state, lidx,
+                rows4, positions[0])
+        return y, [conv]
+
+    def make_state_layer(mix, moe: bool, lps, experts=None, tag_group=0,
+                         run=""):
+        """The scan body of a run of state layers (mixer ``mix``) of the
+        stack ``lps``: the state arrays ride the carry (updated in place at
+        the layer's index), the KV caches stay outside."""
         def layer(carry, xs):
-            x, conv, ssm, st = carry
+            x, *state, st = carry
             in_stack, lidx = xs
             lp = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
                 a, in_stack, keepdims=False), lps)
             lp["layer_in_stack"] = in_stack
             h = _rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-            zxd = _mm(h, lp["in_proj"])[0]             # [T, di + cw + H]
-            y, conv, ssm = mamba2_ragged(
-                zxd[:, di:di + cw], zxd[:, di + cw:], lp, conv, ssm, lidx,
-                rows4, positions[0], cfg=cfg, chunks=ragged[3] is not None,
-                tag=f"_{run}_{program}")
-            # the gate first, then the norm, over all of d_inner
-            y = y * jax.nn.silu(zxd[:, :di].astype(jnp.float32))
-            y = _rms_norm(y.astype(x.dtype), lp["ssm_norm"], cfg.rms_norm_eps)
+            y, state = mix(h, lp, state, lidx, run)
             x = _res(x, _mm(y[None], lp["out_proj"]))
             (x, _, _, st), ids = _mlp_epilogue(x, None, None, st, lp, moe,
                                                experts, 0, tag_group)
-            return (x, conv, ssm, st), ids
+            return (x, *state, st), ids
         return layer
 
     def _mlp_epilogue(x, kc, vc, st, lp, moe, experts=None, group=0,
@@ -2069,14 +2113,16 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                 # a layer's blocks out of the stack itself
                 experts = {k: lps.pop(k)
                            for k in ("w_gate", "w_up", "w_down")}
-            if cfg.layer_kinds[stack.kind].mixer == "mamba2":
+            mixer = cfg.layer_kinds[stack.kind].mixer
+            if mixer != "attention":
                 # a run that is part of its stack reads its layers out of
                 # the WHOLE stack by index (a slice of the stack handed to
                 # the scan is copied first, every step: 1.2 GB of in_proj)
+                mix = _mix_mamba2 if mixer == "mamba2" else _mix_shortconv
                 x, kcs, vcs, st = carry
                 (x, *state, st), ids = jax.lax.scan(
-                    make_mamba_layer(stack.moe, lps, experts, stack.kind,
-                                     run=f"l{g_off}x{n}"),
+                    make_state_layer(mix, stack.moe, lps, experts,
+                                     stack.kind, run=f"l{g_off}x{n}"),
                     (x, *state, st),
                     (off + jnp.arange(n), g_off + jnp.arange(n)))
                 carry = (x, kcs, vcs, st)
@@ -2427,9 +2473,9 @@ def ragged_fallback_reason(cfg: ModelConfig, mesh: Optional[Mesh],
     a degraded launch is never silent. Returns None as well when Pallas
     was never requested (a config choice, not a degrade) and for MLA
     models (the latent ragged walk is their designed path, not a
-    fallback). A Mamba-2 layer kind has no cache group (its state lies in
-    slots, ``cfg.state_spec``) and takes no gate here: only the attention
-    kinds beside it can name a reason."""
+    fallback). A state layer kind (Mamba-2, short convolution) has no cache
+    group (its state lies in slots, ``cfg.state_spec``) and takes no gate
+    here: only the attention kinds beside it can name a reason."""
     from dynamo_tpu.ops.ragged_attention import (
         ragged_int8_kernel_supported, ragged_pallas_supported,
     )

@@ -185,7 +185,7 @@ class Scheduler:
         #: free recurrent-state slots (engine/cache.py:allocate_state), or
         #: None for a model without state layers: nothing below looks at
         #: slots then. A sequence that starts at position 0 starts from
-        #: zeros whatever its slot holds (ops/mamba2.py), so a slot is a
+        #: zeros whatever its slot holds (ops/shortconv.py), so a slot is a
         #: binding only: nothing is zeroed or moved when it changes hands.
         self.state_free: Optional[list] = (
             list(range(state_slots - 1, -1, -1)) if state_slots else None)

@@ -8,7 +8,9 @@ latent attention with a compressed paged cache (DeepSeek V2/V3, incl.
 sigmoid + group-limited routing, shared experts, and the dense layer
 prefix), and layer KINDS with their own KV-head count, rope base, window
 and sink, K/Q heads wider than V heads, and an expert layer that holds a
-share of the experts it routes over (MiMo-V2). Presets below are the shapes used by the reference's recipes (ref:
+share of the experts it routes over (MiMo-V2), and two hybrids whose other
+layers keep a fixed-size state a sequence in slots: Mamba-2 mixers (Granite
+4.0-H) and gated short convolutions (LFM2). Presets below are the shapes used by the reference's recipes (ref:
 recipes/llama-3-70b, recipes/deepseek-r1, recipes/gpt-oss-120b); unsupported
 architectures fail loudly rather than being approximated silently.
 """
@@ -251,6 +253,57 @@ def granite4_tiny(experts_held=(0, 4), pattern="mmammm") -> ModelConfig:
         dtype="float32")
 
 
+def _lfm2_moe(*, pattern: str, experts_held=None, **sizes) -> ModelConfig:
+    """LFM2-MoE (``lfm2_moe``: LFM2-8B-A1B, LFM2-24B-A2B): gated short-
+    convolution mixers ("c": ``C * conv3(B * x)``, whose state is the last
+    two inputs a sequence) and GQA attention layers ("a": per-head RMSNorm
+    on q and k, rotate-half RoPE) in the published order; ``num_dense``
+    SwiGLU layers first, then sigmoid-routed experts whose choice adds a
+    bias the weights do not carry, the weights normalised over the chosen
+    (+ 1e-6); the head is the embedding. Every expert is held unless
+    ``experts_held`` says otherwise; 64-wide heads are stored as whole lane
+    rows (``kv_lane_pad``: the ragged kernel takes them)."""
+    sizes = dict(
+        hidden_size=2048, intermediate_size=11776, num_heads=32,
+        layer_kinds=((8, 1e6, 0, False), (0, 0.0, 0, False, "shortconv")),
+        num_experts=64, num_experts_per_tok=4, moe_intermediate_size=1536,
+        first_k_dense_replace=2, vocab_size=65536,
+        max_position_embeddings=128000) | sizes
+    E = sizes["num_experts"]
+    return ModelConfig(
+        num_layers=len(pattern), num_kv_heads=sizes["layer_kinds"][0][0],
+        rope_theta=sizes["layer_kinds"][0][1],
+        layer_pattern=tuple("ac".index(c) for c in pattern),
+        qk_norm=True, rms_norm_eps=1e-5, tie_word_embeddings=True,
+        scoring_func="sigmoid", norm_topk_prob=True, router_norm_eps=1e-6,
+        routed_scaling_factor=1.0, shortconv_taps=3, kv_lane_pad=True,
+        experts_held=experts_held or (0, E),
+        # nothing at its neutral value: norm weights ~ N(1, 0.2)
+        init_norm_std=0.2, **sizes)
+
+
+def lfm2_24b_a2b_pp4() -> ModelConfig:
+    """One chip's share of LFM2-24B-A2B served as four pipeline stages of
+    ten layers, no layer shared between chips: stage 0 — the two dense
+    layers and two whole periods (attention, conv, conv, conv), ALL 64
+    experts of each, the whole vocabulary — and the tied head the last
+    stage would hold (chipbench/configs/lfm2-24b-a2b-pp4.json has the
+    arithmetic)."""
+    return _lfm2_moe(pattern="ccacccaccc")
+
+
+def lfm2_tiny(experts_held=None, pattern="ccacccac") -> ModelConfig:
+    """LFM2-MoE's shape at test size: two dense conv layers, then attention
+    (G = 2, heads narrower than a lane row and padded to one) and conv
+    layers with 8 experts top-3, float32."""
+    return _lfm2_moe(
+        pattern=pattern, experts_held=experts_held, hidden_size=64,
+        intermediate_size=128, num_heads=4,
+        layer_kinds=((2, 1e6, 0, False), (0, 0.0, 0, False, "shortconv")),
+        num_experts=8, num_experts_per_tok=3, moe_intermediate_size=32,
+        vocab_size=256, max_position_embeddings=512, dtype="float32")
+
+
 PRESETS = {
     "tiny": ModelConfig.tiny,
     "moe_tiny": moe_tiny,
@@ -272,6 +325,8 @@ PRESETS = {
     "mimo_v25_ep16": mimo_v25_ep16,
     "granite4_tiny": granite4_tiny,
     "granite4_h_small_ep2": granite4_h_small_ep2,
+    "lfm2_tiny": lfm2_tiny,
+    "lfm2_24b_a2b_pp4": lfm2_24b_a2b_pp4,
 }
 
 #: architectures the forward pass does NOT cover yet (listed so callers

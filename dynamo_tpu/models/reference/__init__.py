@@ -57,13 +57,12 @@ def mimo_v2_inputs(cfg, params) -> tuple[dict, dict]:
     return weights, hp
 
 
-def granite4_h_inputs(cfg, params, consume: bool = False
-                      ) -> tuple[dict, dict]:
-    """(weights, hp) for ``reference.granite4_h.forward`` from a
-    ModelConfig with a Mamba-2 kind and its ``init_params`` pytree.
-    ``consume``: take each stacked leaf OUT of ``params`` as it is cut into
-    its layers, so that the stacks and their cuts are never both whole on
-    the device (9.5 GB each at the published widths)."""
+def _cut_stacks(cfg, params, consume: bool) -> list:
+    """The parameter stacks of a model with layer kinds cut into one dict a
+    layer, in model order. ``consume``: take each stacked leaf OUT of
+    ``params`` as it is cut into its layers, so that the stacks and their
+    cuts are never both whole on the device (9.5 GB each at Granite's
+    published widths)."""
     from dynamo_tpu.engine.model import layer_stacks
     from dynamo_tpu.engine.quant import HEAD_MAJOR_KEYS
 
@@ -79,6 +78,15 @@ def granite4_h_inputs(cfg, params, consume: bool = False
                     w = w.reshape(-1, w.shape[-1]).T
                 layers[i][k] = w
             del a
+    return layers
+
+
+def granite4_h_inputs(cfg, params, consume: bool = False
+                      ) -> tuple[dict, dict]:
+    """(weights, hp) for ``reference.granite4_h.forward`` from a
+    ModelConfig with a Mamba-2 kind and its ``init_params`` pytree.
+    ``consume``: see :func:`_cut_stacks`."""
+    layers = _cut_stacks(cfg, params, consume)
     hp = {
         "hidden_size": cfg.hidden_size,
         "num_attention_heads": cfg.num_heads,
@@ -98,5 +106,33 @@ def granite4_h_inputs(cfg, params, consume: bool = False
         "experts_held": list(cfg.experts_held or (0, cfg.num_experts)),
     }
     weights = {"embed": params["embed"], "layers": layers,
+               "final_norm": params["final_norm"]}
+    return weights, hp
+
+
+def lfm2_moe_inputs(cfg, params, consume: bool = False
+                    ) -> tuple[dict, dict]:
+    """(weights, hp) for ``reference.lfm2_moe.forward`` from a ModelConfig
+    with a short-convolution kind and its ``init_params`` pytree.
+    ``consume``: see :func:`_cut_stacks`."""
+    hp = {
+        "hidden_size": cfg.hidden_size,
+        "num_attention_heads": cfg.num_heads,
+        "num_key_value_heads": cfg.layer_kinds[0].num_kv_heads,
+        "rope_parameters": {"rope_theta": cfg.layer_kinds[0].rope_theta,
+                            "rope_type": "default"},
+        "norm_eps": cfg.rms_norm_eps,
+        "layer_types": [("full_attention", "conv")[k]
+                        for k in cfg.layer_pattern],
+        "conv_L_cache": cfg.shortconv_taps,
+        "num_dense_layers": cfg.first_k_dense_replace,
+        "num_experts": cfg.num_experts,
+        "num_experts_per_tok": cfg.num_experts_per_tok,
+        "norm_topk_prob": cfg.norm_topk_prob,
+        "routed_scaling_factor": cfg.routed_scaling_factor,
+        "experts_held": list(cfg.experts_held or (0, cfg.num_experts)),
+    }
+    weights = {"embed": params["embed"],
+               "layers": _cut_stacks(cfg, params, consume),
                "final_norm": params["final_norm"]}
     return weights, hp

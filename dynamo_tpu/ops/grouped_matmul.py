@@ -39,9 +39,12 @@ longer than a tile's product (Granite's: 7.7 us against 4.1) the launch
 waits out the rest at every change of expert. Where the buffer is smaller
 (a decode step: an expert has one tile, there is nothing to keep, and the
 launch is bound by the copies) the blocks are ``_TILE`` square, 2 MB at
-bf16, summed in a float32 scratch, and the grid is ``(num_tiles, n // tn,
-k // tk)``, a tile's blocks together: the first block is there sooner, the
-last product is shorter than with a whole matrix, a row tile of ``x`` stays
+bf16 (a dim that ``_TILE`` does not divide takes the largest multiple of
+128 under it that does: LFM2-24B-A2B's 1,536-wide experts 768, so 2048 x
+1536 is cut 1024 x 768 and 1536 x 2048 768 x 1024; a smaller dim whole:
+Granite's 768), summed in a float32 scratch, and the grid is ``(num_tiles,
+n // tn, k // tk)``, a tile's blocks together: the first block is there
+sooner, the last product is shorter than with a whole matrix, a row tile of ``x`` stays
 while its column blocks pass, and the launch fits the compiler's default
 VMEM limit, so a decode step's buffers keep their room there (on the chip
 a whole-matrix block read within 2% a launch either way, 0.8% more a step).
@@ -95,11 +98,18 @@ def _kernel(tile_group_ref, layer_ref, x_ref, w_ref, out_ref, *acc):
 
 
 def _tile(dim: int, want: int) -> int:
+    """The block of a ``dim`` that is to be cut into blocks of ``want``:
+    ``want`` where it divides ``dim``, all of a smaller ``dim``, else the
+    largest multiple of 128 under ``want`` that divides it (1,536: 768)."""
     if dim % want == 0:
         return want
     if dim < want:
         return dim  # a block equal to the whole dim needs no alignment
-    raise ValueError(f"dim {dim} is not a multiple of its tile {want}")
+    fits = [t for t in range(128, want, 128) if dim % t == 0]
+    if not fits:
+        raise ValueError(f"dim {dim} has no tile that is a multiple of 128, "
+                         f"divides it and is at most {want}")
+    return fits[-1]
 
 
 def _n_tile(k: int, n: int, itemsize: int) -> int:

@@ -7,6 +7,9 @@ state after its last token there.
     S_t   = exp(dt_t * A) * S_{t-1} + dt_t * x_t (x) B_t
     y_t   = S_t C_t + D * x_t
 
+The convolution is ops/shortconv.py's ``causal_conv``, which the
+short-convolution mixer shares.
+
 A step's rows are of two sorts. A row of ONE token (a decode row, or a
 chunk of one) is a read-modify-write of its slot by the kernel
 :func:`mamba2_decode_update`. A row of more (a prefill chunk;
@@ -40,6 +43,7 @@ import jax.numpy as jnp
 
 from dynamo_tpu.engine.config import RAGGED_MAX_CHUNKS
 from dynamo_tpu.ops.paged_attention import kernel_interpret_mode
+from dynamo_tpu.ops.shortconv import causal_conv, step_rows
 
 #: tokens of one block of the chunked recurrence (the MXU's height; the
 #: published ``mamba_chunk_size`` 256 is a tiling and changes no equation)
@@ -58,38 +62,6 @@ def unpack_state(a, pack: int):
     *lead, G, N, W = a.shape
     a = a.reshape(*lead, G, N, pack, W // pack)
     return jnp.moveaxis(a, -3, -1).reshape(*lead, G * pack, W // pack, N)
-
-
-def _shift(x, s: int):
-    """``out[t] = x[t - s]``, zeros for ``t < s``."""
-    return x if s == 0 else jnp.pad(x, ((s, 0), (0, 0)))[:x.shape[0]]
-
-
-def _conv(xbc, w, b, tail, q_start, q_len, valid, in_row):
-    """The causal depthwise convolution over the flat token axis. ``tail``
-    [R, W-1, C]: each row's last inputs before this step (zeros where the
-    row starts a sequence). Returns silu(conv) [T, C] in float32 and the
-    rows' new tails [R, W-1, C]."""
-    T, _ = xbc.shape
-    W = w.shape[0]
-    x32, w32, t32 = (a.astype(jnp.float32) for a in (xbc, w, tail))
-    pre = b.astype(jnp.float32)[None, :] + sum(
-        w32[j][None, :] * jnp.where((in_row >= W - 1 - j)[:, None],
-                                    _shift(x32, W - 1 - j), 0.0)
-        for j in range(W))
-    for k in range(W - 1):
-        # the row's token at offset k reads tail entries k .. W-2
-        add = sum(w32[j][None, :] * t32[:, k + j] for j in range(W - 1 - k))
-        at = jnp.where(valid & (k < q_len), q_start + k, T)
-        pre = pre.at[at].add(add, mode="drop")
-    new = []
-    for i in range(W - 1):
-        p = q_len - (W - 1) + i          # offset in the row, < 0: old tail
-        old = jnp.take_along_axis(
-            tail, jnp.clip(W - 1 + p, 0, W - 2)[:, None, None], axis=1)[:, 0]
-        new.append(jnp.where((p >= 0)[:, None],
-                             xbc[jnp.clip(q_start + p, 0, T - 1)], old))
-    return jax.nn.silu(pre), jnp.stack(new, axis=1)
 
 
 #: head groups (lane rows of ``pack`` heads) of one block of the update
@@ -236,23 +208,16 @@ def mamba2_ragged(xbc, dt, lp, conv_state, ssm_state, lidx, rows, positions,
     H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     di, pack, cd = H * P, cfg.mamba_head_pack, xbc.dtype
     R = rows.shape[0]
-    q_start, q_len, slot = rows[:, 0], rows[:, 1], rows[:, 3]
-    valid = q_len > 0
+    g = step_rows(rows, positions, T)
+    _, q_len, slot, valid, first, keep, tok_row, _, tok_valid = g
     dump = ssm_state.shape[1] - 1
-    first = jnp.clip(q_start, 0, T - 1)
-    keep = valid & (positions[first] != 0)   # continues a sequence
-    # token -> row: the rows lie one after another in row order
-    t = jnp.arange(T)
-    tok_row = jnp.clip(((t[:, None] >= q_start[None, :])
-                        & valid[None, :]).sum(1) - 1, 0, R - 1)
-    in_row = t - q_start[tok_row]
-    tok_valid = in_row < q_len[tok_row]
-
+    # conv_from_slots, with the activation before the tails are put back:
+    # the order the accepted step programs were traced in
     slot_r = jnp.where(valid, slot, dump)
     tail = jnp.where(keep[:, None, None],
                      conv_state[lidx, slot_r].reshape(R, -1, C), 0)
-    xc, new_tail = _conv(xbc, lp["conv_w"], lp["conv_b"], tail, q_start,
-                         q_len, valid, in_row)
+    pre, new_tail = causal_conv(xbc, lp["conv_w"], lp["conv_b"], tail, g)
+    xc = jax.nn.silu(pre)
     conv_state = conv_state.at[lidx, slot_r].set(new_tail.reshape(R, -1))
 
     x = xc[:, :di].reshape(T, H, P)

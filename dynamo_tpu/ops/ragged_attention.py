@@ -14,14 +14,20 @@ Contract (one layer; the stacked-cache wiring lives in engine/model.py):
                                  layout), so per-token positions are pure
                                  index math: pos = kv_len - q_len + j
   k cache      [slots, KV·NB, 128]  flat paged layout (slot = block·bs +
-                                 off). A head of up to 128 dims is one row
+                                 off). A head of 128 dims is one row
                                  (NB = 1); a wider one is stored as NB
                                  whole 128-lane rows, the lanes past hd
-                                 zero (MiMo-V2's 192-wide head: NB = 2) —
-                                 Mosaic strides sublanes of 128-lane
-                                 buffers only. q is zero-padded to NB·128
-                                 here; the softmax scale is hd's
-  v cache      [slots, KV, 128]  V rows have their own width
+                                 zero (MiMo-V2's 192-wide head: NB = 2),
+                                 a narrower one as one row, zero past hd
+                                 (LFM2's 64-wide head, where the model
+                                 says ``kv_lane_pad``) — Mosaic strides
+                                 sublanes of 128-lane buffers only. q is
+                                 zero-padded to NB·128 here; the softmax
+                                 scale is hd's
+  v cache      [slots, KV, 128]  V rows have their own width (a narrow V
+                                 head padded alike: the output's lanes
+                                 past it are zero, and the caller cuts
+                                 them)
   block_tables [R, W] int32      per ROW (0 = reserved null block)
   rows3        [R, 3] int32      (q_start, q_len, kv_len) per row; padding
                                  rows carry q_len = 0 and are skipped
@@ -79,9 +85,10 @@ are rotated to the lanes of its keys' score columns. k-scales multiply the
 scores, v-scales fold into p before the PV matmul, so int8 pages cost the
 same two DMAs per page as bf16 at half the bytes. The only degrades to
 :func:`ragged_attention_xla` are page rows that are not one 128-lane row
-(hd = 64 models; a V head wider than 128; a wider K head is stored as
-lane rows, ``ModelConfig.k_cache_dim``) and scale tables past the
-VMEM budget — both static shape facts the engine counts and logs
+(a narrow head stored as it is — a model of 64-wide heads that does not
+set ``ModelConfig.kv_lane_pad``; a V head wider than 128; a wider K head
+is stored as lane rows, ``ModelConfig.k_cache_dim``) and scale tables past
+the VMEM budget — both static shape facts the engine counts and logs
 (``dynamo_ragged_fallback_total``, reasons ``lane_align`` and
 ``scale_budget``), never a silent data-dependent branch. ``DYN_RAGGED_ORACLE=1`` routes to the XLA oracle
 explicitly (bench/test A/B arms only).
@@ -128,8 +135,9 @@ def ragged_pallas_supported(num_kv_heads: int, k_dim: int,
     """Pages DMA as [rows, 128] tiles and a head's rows are a sublane-
     strided read, which Mosaic takes of 128-lane buffers only: the STORED
     row of K and of V must each be one lane row (``k_dim`` is the width of
-    a stored row: 128 for a wide head kept as lane rows). hd = 64 models
-    leave the kernel under ``lane_align``."""
+    a stored row: 128 for a wide head kept as lane rows, and for a narrow
+    one padded to a row under ``ModelConfig.kv_lane_pad``). Narrow heads
+    stored as they are leave the kernel under ``lane_align``."""
     return k_dim == _LANE and v_dim == _LANE
 
 

@@ -486,6 +486,114 @@ def test_granite_step_is_rematerialised_without_the_options(compile_for_chip):
     assert len(dots_producing(text, "bf16[2048,16768]")) == 4
 
 
+#: a KV pool near the one ``lfm2-24b-a2b-pp4.chat-steady`` sizes (8,192 B a
+#: token: 0.6 of what 10.53 GB of weights and the slots leave a 16.9 GB
+#: chip): with it a step program's arguments are about 14.2 GB
+LFM2_CELL_BLOCKS = 28000
+
+
+@pytest.fixture(scope="module")
+def lfm2_step_text(compile_for_chip):
+    """lfm2_step_text(T, chunks) -> (compiled text, cfg, slots) of a ragged
+    step of the LFM2-24B-A2B stage the benchmark runs, at the cell's 128
+    state slots, pool and ``LIBTPU_INIT_ARGS``, each compiled once."""
+    import json
+    import os
+
+    from dynamo_tpu.engine import model as M
+    from dynamo_tpu.engine.config import EngineArgs
+    from dynamo_tpu.models import lfm2_24b_a2b_pp4
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))),
+            "chipbench/configs/lfm2-24b-a2b-pp4.json")) as f:
+        env = json.load(f)["worker_env"]
+    cfg = lfm2_24b_a2b_pp4()
+    args = EngineArgs(max_num_seqs=128, max_num_batched_tokens=2048,
+                      max_model_len=8192)
+    nb, slots = LFM2_CELL_BLOCKS, args.max_num_seqs + 1
+    params = jax.eval_shape(lambda: M.init_params(cfg, jax.random.key(0)))
+    (g,), st = cfg.kv_cache_spec, cfg.state_spec
+    kc = spec((len(g.layers), nb * BS, *g.k_shape), jnp.bfloat16)
+    vc = spec((len(g.layers), nb * BS, g.kv_heads, g.v_dim), jnp.bfloat16)
+    state = (spec((len(st.layers), slots,
+                   st.conv_shape[0] * st.conv_shape[1]), jnp.bfloat16),)
+    done = {}
+
+    def text_of(T, chunks):
+        if (T, chunks) in done:
+            return done[T, chunks], cfg, slots
+        R, W = args.ragged_rows(T), args.max_blocks_per_seq
+        C, _ = M.ragged_grid_shape(T)
+        with mock.patch.object(jax, "default_backend", return_value="tpu"):
+            options = M.step_compiler_options()
+        # libtpu reads LIBTPU_INIT_ARGS once a process, and this process's
+        # is loaded: the cell's flags ride as options of this compile
+        options |= dict(flag.lstrip("-").split("=")
+                        for flag in env["LIBTPU_INIT_ARGS"].split())
+        with mock.patch.object(M, "step_compiler_options",
+                               return_value=options), \
+             mock.patch("dynamo_tpu.ops.grouped_matmul.kernel_interpret_mode",
+                        return_value=False):
+            step = M.make_ragged_step_fn(cfg, BS, None, use_pallas=True,
+                                         chunks=chunks)
+            done[T, chunks] = compile_for_chip(
+                step, params, spec((5, T), jnp.int32),
+                spec((R, 4), jnp.int32), spec((C,), jnp.int32),
+                spec((R, W), jnp.int32), kc, vc, state)
+        return done[T, chunks], cfg, slots
+
+    return text_of
+
+
+@pytest.mark.parametrize("program", ["d64", "d128", "m1024", "m2048"])
+def test_lfm2_step_compiles_with_both_kernels_and_no_copies_for_v5e(
+        lfm2_step_text, program):
+    """The whole jitted ragged step of the LFM2-24B-A2B stage the benchmark
+    runs (models.lfm2_24b_a2b_pp4: ten layers, published widths, all 64
+    experts of each, 128 state slots, a pool of the cell's size): the
+    ragged kernel — 64-wide heads stored as whole lane rows — and the
+    grouped matmul at 1,536-wide experts (every launch: the blocks of 768
+    under 2,048 tokens, the whole matrices at 2,048) are Mosaic calls; the
+    page pool and the convolutions' tails are updated in place; no layer's
+    experts or ``in_proj`` leave their stacks but as operands of their own
+    products; nothing is computed twice."""
+    T = int(program[1:])
+    text, cfg, slots = lfm2_step_text(T, program[0] == "m")
+    assert text.count("ragged_paged_attention") >= 2   # two runs of one
+    for name in ("gate", "up", "down"):
+        assert f"moe_grouped_matmul_g0_{program}_{name}" in text  # attention
+        assert f"moe_grouped_matmul_g1_{program}_{name}" in text  # conv
+    lines = text.splitlines()
+    st, (g,) = cfg.state_spec, cfg.kv_cache_spec
+    tails = (f"bf16[{len(st.layers)},{slots},"
+             f"{st.conv_shape[0] * st.conv_shape[1]}]")
+    pool = f"[{len(g.layers)},{LFM2_CELL_BLOCKS * BS},8,128]"
+    copies = [ln.strip()[:120] for ln in lines
+              if (" copy(" in ln or " copy-start(" in ln)
+              and (tails in ln.split("(")[0] or pool in ln.split(" copy")[0])]
+    assert not copies, copies[:2]
+    D, F, E = cfg.hidden_size, cfg.moe_ffn_size, cfg.num_experts_held
+    sliced = [ln for ln in lines
+              if f" = bf16[{E},{D},{F}]" in ln or f" = bf16[{E},{F},{D}]" in ln]
+    assert not sliced, sliced[:2]
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    moved, inside = [], False
+    for ln in lines:
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            inside = head.group(1) in fused
+            continue
+        op = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = bf16\[(?:\d+,)?(\d+),(\d+)\]"
+                      r"\S* (?:copy|fusion|slice|dynamic-slice)\(", ln)
+        # (at 2,048 tokens in_proj's PRODUCT has its shape: not judged)
+        if op and not inside and T != D and (
+                int(op.group(1)), int(op.group(2))) == (D, 3 * D):
+            moved.append(ln.strip()[:120])
+    assert not moved, moved[:2]
+    assert not rematerialised_ops(text)
+
+
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 def test_init_leaf_never_holds_a_float32_copy_on_v5e(compile_for_chip,
                                                      quantized):

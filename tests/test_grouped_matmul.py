@@ -86,8 +86,8 @@ def test_a_matrix_no_block_of_which_fits_is_refused():
     with mock.patch.object(gm, "_W_BLOCK_BYTES", 1024):
         with pytest.raises(ValueError, match="multiple of 128 columns"):
             gm._blocks(8, 4, 512, 256, 2)
-    with pytest.raises(ValueError, match="not a multiple of its tile"):
-        gm._blocks(7, 4, 1536, 256, 2)
+    with pytest.raises(ValueError, match="no tile that is a multiple of 128"):
+        gm._blocks(7, 4, 1100, 256, 2)
     # Granite-4.0-H's (36 held, 10 a token) and MiMo-V2.5's (16 held, 8 a
     # token) matrices at bf16, in the buffers of a 2,048-token step and of
     # a 64-row decode step
@@ -99,3 +99,35 @@ def test_a_matrix_no_block_of_which_fits_is_refused():
             assert gm._blocks(2048 * K // T + E, E, k, n, 2) == (*want, True)
             assert gm._blocks(64 * K // T + E, E, k, n, 2) == (
                 min(k, 1024), min(n, 1024), False)
+
+
+def test_blocks_of_a_1536_wide_expert():
+    """LFM2-24B-A2B's experts (64 held, 4 a token; gate and up 2048 x 1536,
+    down 1536 x 2048): the launch that keeps no block — every program
+    under 2,048 tokens, 96 tiles < 128 at 1,024 — cuts 1,536 into 768, the
+    largest multiple of 128 under 1,024 that divides it; the 2,048-token
+    program keeps whole matrices (6.3 MB)."""
+    E, K = 64, 4
+    for tokens in (8, 64, 1024):
+        tiles = -(-tokens * K // T) + E
+        assert gm._blocks(tiles, E, 2048, 1536, 2) == (1024, 768, False)
+        assert gm._blocks(tiles, E, 1536, 2048, 2) == (768, 1024, False)
+    tiles = 2048 * K // T + E
+    assert gm._blocks(tiles, E, 2048, 1536, 2) == (2048, 1536, True)
+    assert gm._blocks(tiles, E, 1536, 2048, 2) == (1536, 2048, True)
+    assert gm._tile(1536, 1024) == 768 and gm._tile(768, 1024) == 768
+    assert gm._tile(4096, 1024) == 1024 and gm._tile(2048, 1024) == 1024
+
+
+@pytest.mark.parametrize("k,n", [(256, 1536), (1536, 256)],
+                         ids=["n1536", "k1536"])
+def test_a_1536_wide_dim_in_the_launch_that_keeps_no_block(k, n):
+    """n = 1,536 (gate, up) and k = 1,536 (down) at the real ``_TILE``: two
+    blocks of 768 columns, and a contraction summed over two."""
+    x, w, tile_group, used = _case(k, n, jnp.float32, layers=2)
+    tk, tn, keep = gm._blocks(len(tile_group), len(TILES), k, n, 4)
+    assert (tk, tn, keep) == (min(k, 768), min(n, 768), False)
+    out = gm.grouped_matmul(x, w, tile_group, used, layer=1)
+    np.testing.assert_allclose(
+        np.asarray(out)[:used * T], _plain(x, w, 1, tile_group, used),
+        rtol=1e-5, atol=1e-5)

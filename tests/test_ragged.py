@@ -158,6 +158,37 @@ def test_ragged_kernel_wide_k_heads_as_lane_rows(kv, window, sinks, dtype):
                                atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_ragged_kernel_narrow_heads_padded_to_a_lane_row(dtype):
+    """LFM2's widths: 64-wide heads at G = 4, K and V stored zero-padded to
+    one 128-lane row each (``ModelConfig.kv_lane_pad``), a 64-wide q (the
+    kernel pads it, and its softmax scale is 64's): decode rows and
+    128-token tiles against the XLA oracle on the heads as they are; the
+    output's lanes past 64 are exactly zero."""
+    H, KV, hd = 8, 2, 64
+    rows = [(1, 300), (200, 260), (9, 9), (1, 40), (128, 128)]
+    need = sum(-(-kl // 8) for _, kl in rows) + 2
+    q, kc, vc, bt, rows3, t = make_ragged_case(
+        jax.random.key(7), rows, H=H, KV=KV, hd=hd, num_blocks=need, W=38,
+        pad_rows=2, pad_tokens=3)
+    q, kc, vc = (a.astype(dtype) for a in (q, kc, vc))
+    pad = ((0, 0), (0, 0), (0, 128 - hd))
+    want = ragged_attention_xla(q, kc, vc, bt, rows3, block_size=8)
+    got = ragged_paged_attention(q, jnp.pad(kc, pad), jnp.pad(vc, pad), bt,
+                                 rows3, block_size=8, interpret=True)
+    assert got.shape == (q.shape[0], H, 128) and want.shape[-1] == hd
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:t, :, :hd],
+                               np.asarray(want, np.float32)[:t],
+                               atol=tol, rtol=tol)
+    assert not np.asarray(got, np.float32)[:t, :, hd:].any()
+    # and as they are, the kernel hands them to the oracle (lane_align)
+    from dynamo_tpu.ops.ragged_attention import ragged_pallas_supported
+    assert not ragged_pallas_supported(KV, hd, hd)
+    assert ragged_pallas_supported(KV, 128, 128)
+
+
 @pytest.mark.parametrize("KV", [1, 2, 8])
 def test_ragged_kernel_bf16_pages_read_as_words(KV):
     """bf16 pages and queries: a head's rows come out of 32-bit words (two
