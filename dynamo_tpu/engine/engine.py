@@ -299,7 +299,8 @@ class AsyncJaxEngine:
                          else "pallas: ragged kernel (Mosaic)")
         #: held-experts layer counters (model.moe_stats_width), read with
         #: the step's other outputs: dynamo_moe_assignments_total{to},
-        #: dynamo_moe_expert_tokens_total{expert}, dynamo_moe_row_tiles_total
+        #: dynamo_moe_expert_tokens_total{expert}, dynamo_moe_row_tiles_total,
+        #: dynamo_moe_combine_rows_total{rows}
         self._moe_held = cfg.is_moe and cfg.experts_held is not None
         if (cfg.is_moe and not self._moe_held and mesh is None
                 and cfg.num_experts > 8):
@@ -315,10 +316,15 @@ class AsyncJaxEngine:
         self.moe_expert_tokens_total = np.zeros(
             (cfg.num_experts_held if self._moe_held else 0,), np.int64)
         self.moe_row_tiles_total = 0
+        #: buffer rows the layer's read-back fetched, beside the rows a
+        #: read-back of every pair of every padded token would fetch
+        self.moe_combine_rows_total = (
+            {"read": 0, "worst_case": 0} if self._moe_held else {})
+        self._moe_head = M.MOE_STATS_HEAD  # the experts' counts follow
         self._moe_pending: collections.deque = collections.deque()
-        #: (pairs, experts touched, row tiles) a cache group: the next
-        #: record's
-        self._moe_step = np.zeros((len(groups), 3), np.int64)
+        #: (pairs, experts touched, row tiles, rows read back, their worst
+        #: case) a cache group: the next record's
+        self._moe_step = np.zeros((len(groups), 5), np.int64)
         mem_after = dev.memory_stats()
         state = (self.params, self.k_cache, self.v_cache, self.state)
         #: what was built, as one line an operator (or chip_smoke.py) reads
@@ -1743,9 +1749,13 @@ class AsyncJaxEngine:
             stats = np.asarray(self._moe_pending.popleft())  # [groups, ·]
             self.moe_assignments_total["all"] += int(stats[:, 0].sum())
             self.moe_assignments_total["held"] += int(stats[:, 1].sum())
-            self.moe_expert_tokens_total += stats[:, 4:].sum(0)
+            self.moe_expert_tokens_total += stats[:, self._moe_head:].sum(
+                0)
             self.moe_row_tiles_total += int(stats[:, 3].sum())
-            self._moe_step += stats[:, 1:4]
+            self.moe_combine_rows_total["read"] += int(stats[:, 4].sum())
+            self.moe_combine_rows_total["worst_case"] += int(
+                stats[:, 5].sum())
+            self._moe_step += stats[:, 1:self._moe_head]
 
     def _dead_window_pages(self) -> int:
         """Pages of window cache groups wholly behind their sequence's
@@ -1824,7 +1834,10 @@ class AsyncJaxEngine:
             moe_pairs=sum(g[0] for g in moe_step),
             moe_experts_touched=sum(g[1] for g in moe_step),
             moe_tiles=sum(g[2] for g in moe_step),
-            moe_by_group=moe_step if self._moe_held else [],
+            moe_by_group=([g[:3] for g in moe_step]
+                          if self._moe_held else []),
+            moe_combine_rows=sum(g[3] for g in moe_step),
+            moe_combine_rows_max=sum(g[4] for g in moe_step),
             dead_window_pages=self._dead_window_pages(),
             **({} if self.state is None else self._state_fields(
                 kind, decode_rows, prefill_chunks, chunk_tokens, padded,

@@ -636,6 +636,14 @@ async def amain():
         "tiles minus experts touched found theirs resident").add_callback(
         lambda: ({None: engine.moe_row_tiles_total}
                  if engine.moe_assignments_total else {}))
+    runtime.metrics.counter(
+        "moe_combine_rows_total",
+        "buffer rows the held experts' read-back fetched, rows=\"read\", "
+        "beside rows=\"worst_case\", every pair of every padded token "
+        "(both counted on the device, summed over expert layers)"
+        ).add_callback(
+        lambda: {(("rows", k),): v
+                 for k, v in engine.moe_combine_rows_total.items()})
     runtime.metrics.gauge(
         "engine_warmup_skipped",
         "1 = requested AOT warmup could not run (multi-host step "

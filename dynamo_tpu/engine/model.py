@@ -1335,14 +1335,21 @@ def _router_weights(xf, router_w, router_bias, cfg: ModelConfig):
         jnp.arange(N)[:, None], topi].add(gates)
 
 
+#: entries of the held-experts layer's counter vector before the experts'
+MOE_STATS_HEAD = 6
+
+
 def moe_stats_width(cfg: ModelConfig) -> int:
     """Length of the held-experts layer's counter vector: assignments
     routed anywhere, assignments to held experts, held experts with at
     least one token, row tiles launched (an expert's weights cross HBM once
     a launch, so tiles − experts is the tiles that found theirs resident),
-    then each held expert's tokens. A step returns one such row a cache
-    group (layer kind), summed over the group's layers."""
-    return 4 + cfg.num_experts_held
+    buffer rows the read-back fetched (the copies ops/moe_combine.py
+    started) and the rows a read-back of every pair would fetch (the padded
+    token count × K), then (from :data:`MOE_STATS_HEAD`) each held expert's
+    tokens. A step returns one such row a cache group (layer kind), summed
+    over the group's layers."""
+    return MOE_STATS_HEAD + cfg.num_experts_held
 
 
 def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
@@ -1358,7 +1365,13 @@ def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
     for the worst case (every pair held), each expert's rows padded to
     whole ``ROW_TILE`` tiles, and one grouped matrix product per projection
     (ops/grouped_matmul.py) launches only the tiles in use — it reads the
-    weights of the experts somebody chose and of no other.
+    weights of the experts somebody chose and of no other. The read-back (a
+    token's gate-weighted float32 sum over its K choices, rounded once)
+    follows the routing too: the down launch writes its rows apart and
+    ops/moe_combine.py fetches the rows of the pairs held here and no other
+    — a pair that is not here is skipped, not read and masked, so what the
+    rows nobody wrote hold cannot reach ``y``, and a token with no pair
+    here (a step's padding) gets zeros, written.
 
     x [N, D]; ``valid`` [N] bool marks real tokens (a step's padding is
     routed nowhere and counted nowhere). ``experts``: the expert matrices
@@ -1375,6 +1388,7 @@ def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
     """
     from dynamo_tpu.ops.grouped_matmul import ROW_TILE as tm
     from dynamo_tpu.ops.grouped_matmul import grouped_matmul
+    from dynamo_tpu.ops.moe_combine import moe_combine
 
     N, D = x.shape
     K = cfg.num_experts_per_tok
@@ -1412,20 +1426,21 @@ def _mlp_moe_held(x, lp, cfg: ModelConfig, valid, experts=None,
     ew, layer = (lp, 0) if experts is None else (
         experts, lp["layer_in_stack"])
 
-    def gmm(a, name):
+    def gmm(a, name, **kw):
         return grouped_matmul(a, _qmat(ew["w_" + name], a.dtype), tile_group,
-                              num_tiles, layer, tag=f"{tag}_{name}")
+                              num_tiles, layer, tag=f"{tag}_{name}", **kw)
 
     inter = jax.nn.silu(gmm(xb, "gate")) * gmm(xb, "up")
-    yb = gmm(inter, "down")                             # [M, D]
     # rows of tiles that were not launched hold whatever was there: a
     # pair is read back only from a row that was written
-    y = jnp.where((row < M)[:, None], yb[jnp.minimum(row, M - 1)], 0)
-    y = (y.reshape(N, K, D).astype(jnp.float32)
-         * gates[..., None]).sum(1).astype(x.dtype)
-    stats = jnp.pad(counts, (4, 0)).at[0].set(
-        valid.sum().astype(jnp.int32) * K).at[1].set(counts.sum()).at[2].set(
-        (counts > 0).sum().astype(jnp.int32)).at[3].set(num_tiles)
+    # (named after the step program alone, "_g1_m2048" -> "_m2048": a
+    # program's layer groups then share ONE kernel, traced and lowered once;
+    # every program pays for that before it can ask the compile cache)
+    y, fetched = moe_combine(gmm(inter, "down", rows_apart=True), row, gates,
+                             tag=tag and "_" + tag.rsplit("_")[-1])
+    head = [valid.sum() * K, counts.sum(), (counts > 0).sum(), num_tiles,
+            fetched, P_]  # MOE_STATS_HEAD of them
+    stats = jnp.concatenate([jnp.stack(head).astype(jnp.int32), counts])
     return y, stats, topi
 
 
@@ -1731,8 +1746,9 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     #: decode-only variant (no chunk grid) or the mixed one, and its tokens
     program = ("d" if ragged is not None and ragged[3] is None
                else "m") + str(B * S)
-    if held and ragged is not None:
-        # a step's padding tokens (past the rows' last) are routed nowhere
+    if (held or spec is not None) and ragged is not None:
+        # a step's padding tokens (past the rows' last) are routed nowhere,
+        # and what a kernel left in their rows reaches no state
         n_real = jnp.max(ragged[0][:, 0] + ragged[0][:, 1])
         tok_valid = (jnp.arange(B * S) < n_real)
     elif held:
@@ -1927,6 +1943,12 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                     q[0], kc, vc, lidx, block_tables, positions[0],
                     rows3, grid_row, grid_col, grid_rows, cfg, block_size,
                     window=window, sinks=lp.get("sink"))[None]
+            if use_ragged_kernel and spec is not None:
+                # the kernel writes no row of a padding token: such a row
+                # holds whatever was there (NaN, for all anyone knows), and a
+                # state mixer further up sums over the token axis. Selected
+                # away, not multiplied: 0 x NaN is NaN
+                attn = jnp.where(tok_valid[None, :, None, None], attn, 0)
         elif ring_ok:
             from dynamo_tpu.parallel.ring_attention import ring_prefill_paged
 

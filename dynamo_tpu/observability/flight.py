@@ -174,6 +174,12 @@ class StepRecord:
     #: the same three, a cache group (layer kind):
     #: [[pairs, touched, tiles], ...]
     moe_by_group: list = field(default_factory=list)
+    #: buffer rows the layer's read-back fetched (ops/moe_combine.py: the
+    #: copies it started) beside the rows a read-back of every pair of every
+    #: padded token would fetch, both counted by the layer on the device
+    #: and summed over the step's expert layers
+    moe_combine_rows: int = 0
+    moe_combine_rows_max: int = 0
     #: pages of window cache groups that lie wholly behind their
     #: sequence's window: what releasing them would free
     dead_window_pages: int = 0
@@ -238,6 +244,7 @@ class StepRecord:
                   "swap_in_blocks", "starved_decode", "onboard_inflight",
                   "restore_inflight", "constrained_rows", "wide_tile_rows",
                   "moe_pairs", "moe_experts_touched", "moe_tiles",
+                  "moe_combine_rows", "moe_combine_rows_max",
                   "dead_window_pages",
                   "state_slots_used", "state_rows_prefill",
                   "state_rows_decode", "state_program", "profile_path"):

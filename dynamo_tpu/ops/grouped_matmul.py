@@ -12,7 +12,11 @@ Contract:
                           every launch, touched experts or not
   tile_group [M // ROW_TILE] int32   expert of each row tile
   num_tiles  [] int32     row tiles in use (the groups' tiles, packed first)
-  → out      [M, n]       rows of tiles past ``num_tiles`` are NOT written
+  → out      [M, n]       rows of tiles past ``num_tiles`` are NOT written:
+                          whoever reads the result back reads only rows of
+                          pairs (ops/moe_combine.py never fetches another).
+                          ``rows_apart`` (the down launch): ``[M, n // 128,
+                          128]``, the same rows, each its own run of tiles
 
 One grid bound is ``num_tiles`` itself, a traced value: a step that routed
 40 pairs to 12 experts launches 12 row tiles and reads 12 experts' weights,
@@ -65,6 +69,7 @@ from dynamo_tpu.ops.paged_attention import kernel_interpret_mode
 
 #: rows of one tile: the MXU's own height on a v5e
 ROW_TILE = 128
+_LANES = 128
 #: the largest weight block [k, tn]; the pipeline holds two of them
 _W_BLOCK_BYTES = 8 * 2 ** 20
 #: contraction and output tile of a launch that keeps no block: 2 MB at bf16
@@ -81,8 +86,8 @@ def _kernel(tile_group_ref, layer_ref, x_ref, w_ref, out_ref, *acc):
     del tile_group_ref, layer_ref  # read by the index maps only
     part = jnp.dot(x_ref[...], w_ref[...],
                    preferred_element_type=jnp.float32)
-    if not acc:  # the contraction in one block
-        out_ref[...] = part.astype(out_ref.dtype)
+    if not acc:  # the contraction in one block; rows apart: [rows, C, 128]
+        out_ref[...] = part.astype(out_ref.dtype).reshape(out_ref.shape)
         return
     (acc_ref,), kk = acc, pl.program_id(2)
 
@@ -94,7 +99,8 @@ def _kernel(tile_group_ref, layer_ref, x_ref, w_ref, out_ref, *acc):
 
     @pl.when(kk == pl.num_programs(2) - 1)
     def _():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
+        out_ref[...] = acc_ref[...].astype(out_ref.dtype).reshape(
+            out_ref.shape)
 
 
 def _tile(dim: int, want: int) -> int:
@@ -135,10 +141,10 @@ def _blocks(tiles: int, experts: int, k: int, n: int,
     return k, _n_tile(k, n, itemsize), True
 
 
-@functools.partial(jax.jit, static_argnames=("tk", "tn", "keep", "tag",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("tk", "tn", "keep", "apart",
+                                             "tag", "interpret"))
 def _call(x, w, tile_group, num_tiles, layer, *, tk: int, tn: int,
-          keep: bool, tag: str, interpret: bool):
+          keep: bool, apart: bool, tag: str, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -156,9 +162,15 @@ def _call(x, w, tile_group, num_tiles, layer, *, tk: int, tn: int,
             return lambda j, i, kk, tg, ly: index(i, j, kk, tg, ly)
         return index
 
+    # rows apart: a row of the result is [n // 128, 128], its own tiles (a
+    # width that is no multiple of 128 lanes, a test's: [1, n])
+    lanes = _LANES if tn % _LANES == 0 else tn
+    cols = (lambda rows, c: (rows, c // lanes, lanes)) if apart else (
+        lambda rows, c: (rows, c))
+
     return pl.pallas_call(
         _kernel,
-        out_shape=jax.ShapeDtypeStruct((M, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct(cols(M, n), x.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=((n // tn, num_tiles, 1) if keep else
@@ -169,8 +181,9 @@ def _call(x, w, tile_group, num_tiles, layer, *, tk: int, tn: int,
                 pl.BlockSpec((None, None, tk, tn), at(
                     lambda i, j, kk, tg, ly: (ly[0], tg[i], kk, j))),
             ],
-            out_specs=pl.BlockSpec((ROW_TILE, tn),
-                                   at(lambda i, j, kk, tg, ly: (i, j))),
+            out_specs=pl.BlockSpec(
+                cols(ROW_TILE, tn),
+                at(lambda i, j, kk, tg, ly: (i, j, 0) if apart else (i, j))),
             scratch_shapes=([] if keep else
                             [pltpu.VMEM((ROW_TILE, tn), jnp.float32)]),
         ),
@@ -183,11 +196,15 @@ def _call(x, w, tile_group, num_tiles, layer, *, tk: int, tn: int,
     )(tile_group, layer.reshape(1), x, w)
 
 
-def grouped_matmul(x, w, tile_group, num_tiles, layer=0, tag: str = ""):
+def grouped_matmul(x, w, tile_group, num_tiles, layer=0, tag: str = "",
+                   rows_apart: bool = False):
     """See the module docstring for the contract; ``w`` [E, k, n] is a
     stack of one layer. ``tag`` joins the op's name in the device trace
     (``moe_grouped_matmul<tag>``): which launch of a step this is, for a
-    reader that holds its time against its own work."""
+    reader that holds its time against its own work. ``rows_apart``: the
+    result is ``[M, n // 128, 128]`` (``[M, 1, n]`` where n is no multiple
+    of 128), the same rows, each its own run of tiles in memory, so that
+    ops/moe_combine.py can fetch one row with one copy."""
     assert x.shape[0] % ROW_TILE == 0, x.shape
     tk, tn, keep = _blocks(x.shape[0] // ROW_TILE, w.shape[-3], x.shape[1],
                            w.shape[-1], w.dtype.itemsize)
@@ -195,5 +212,5 @@ def grouped_matmul(x, w, tile_group, num_tiles, layer=0, tag: str = ""):
                  tile_group.astype(jnp.int32),
                  jnp.asarray(num_tiles, jnp.int32),
                  jnp.asarray(layer, jnp.int32),
-                 tk=tk, tn=tn, keep=keep, tag=tag,
+                 tk=tk, tn=tn, keep=keep, apart=rows_apart, tag=tag,
                  interpret=kernel_interpret_mode())

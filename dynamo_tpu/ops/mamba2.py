@@ -155,6 +155,11 @@ def _ssd_flat(x, la, dx, Bm, Cm, onek, S0, cd):
     inside = onek.any(-1)
     la = jnp.where(inside[:, None], la, 0.0)
     dx = jnp.where(inside[:, None, None], dx, 0.0)
+    # selected, not multiplied by 0 below: a padding token's row may hold
+    # NaN (a kernel further down wrote no such row), and 0 x NaN is NaN in
+    # the state of every chunk row of the step
+    Bm = jnp.where(inside[:, None], Bm, 0)
+    Cm = jnp.where(inside[:, None], Cm, 0)
     rid = jnp.where(inside, jnp.argmax(onek, axis=-1), -1)
     ok, rla, rdx, rB, rC, rrid = r(onek), r(la), r(dx), r(Bm), r(Cm), r(rid)
     cum = jnp.cumsum(rla, axis=1)                        # [nC, Q, H]

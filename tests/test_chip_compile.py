@@ -83,6 +83,8 @@ def compile_for_chip(one_chip, no_compile_cache):
              mock.patch("dynamo_tpu.ops.ragged_attention."
                         "kernel_interpret_mode", return_value=False), \
              mock.patch("dynamo_tpu.ops.flash_prefill.kernel_interpret_mode",
+                        return_value=False), \
+             mock.patch("dynamo_tpu.ops.moe_combine.kernel_interpret_mode",
                         return_value=False):
             # an already-jitted step keeps its own donation of the caches
             jitted = (fn if hasattr(fn, "lower")
@@ -239,6 +241,43 @@ def test_grouped_matmul_compiles_for_v5e(compile_for_chip, shape, tokens):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("tokens", [2048, 8], ids=["chunk", "decode"])
+@pytest.mark.parametrize("shape", [(16, 8, 4096, 2048), (36, 10, 4096, 768),
+                                   (64, 4, 2048, 1536)],
+                         ids=["mimo", "granite", "lfm2"])
+def test_moe_combine_compiles_for_v5e(compile_for_chip, shape, tokens):
+    """The held-experts layer's read-back at MiMo-V2.5's, Granite-4.0-H-
+    Small's and LFM2-24B-A2B's widths (held experts, choices a token, D,
+    F), a 2,048-token step and an 8-row decode step, with the down launch
+    that writes the rows apart for it (whole-contraction blocks and square
+    ones): Mosaic accepts both, and the combine asks for no more than the
+    compiler's default VMEM (what a launch reserves beyond it, XLA loses for
+    a decode step's buffers: ops/grouped_matmul.py)."""
+    from dynamo_tpu.ops.grouped_matmul import ROW_TILE, grouped_matmul
+    from dynamo_tpu.ops.moe_combine import moe_combine
+
+    E, K, D, F = shape
+    tiles = -(-tokens * K // ROW_TILE) + E
+
+    def read_back(inter, w, tile_group, num_tiles, row, gates):
+        return moe_combine(
+            grouped_matmul(inter, w, tile_group, num_tiles, rows_apart=True),
+            row, gates, tag="_m2048")
+
+    with mock.patch("dynamo_tpu.ops.grouped_matmul.kernel_interpret_mode",
+                    return_value=False):
+        text = compile_for_chip(
+            read_back, spec((tiles * ROW_TILE, F), jnp.bfloat16),
+            spec((E, F, D), jnp.bfloat16), spec((tiles,), jnp.int32),
+            spec((), jnp.int32), spec((tokens * K,), jnp.int32),
+            spec((tokens, K), jnp.float32))
+    call, = [ln for ln in text.splitlines()
+             if "custom-call(" in ln and "%moe_combine_m2048" in ln]
+    assert "vmem_limit_bytes" not in call.replace(
+        '"vmem_limit_bytes":null', ""), call
+    assert f"bf16[{tiles * ROW_TILE},{D // 128},128]" in call  # rows apart
+
+
 @pytest.mark.parametrize("pages", ["bf16", "int8"])
 def test_flash_prefill_compiles_for_v5e(compile_for_chip, pages):
     from dynamo_tpu.ops.flash_prefill import flash_prefill_paged
@@ -319,6 +358,7 @@ def test_serving_step_compiles_with_kernel_for_v5e(
         text, "s8", [(H, hd, cfg.hidden_size), (KV, hd, cfg.hidden_size)])
     assert not moved, moved
     assert not rematerialised_ops(text)
+    assert "moe_combine" not in text  # no expert layer: no read-back
 
 
 @pytest.mark.parametrize("T", [64, 2048], ids=["decode_heavy", "mixed"])
@@ -470,6 +510,13 @@ def test_granite_step_compiles_with_its_kernels_and_no_copies_for_v5e(
     assert not rematerialised_ops(text)
     if T >= 512:  # one scan a run of Mamba-2 layers: two in the program
         assert len(dots_producing(text, f"bf16[{T},{wide}]")) == 2
+    # the experts' read-back: the kernel that fetches the held pairs' rows,
+    # and nothing of the worst case's size (every pair's row gathered, then
+    # re-laid [T, K, D] for the sum)
+    K = cfg.num_experts_per_tok
+    assert "moe_combine" in text
+    assert not [ln.strip()[:120] for ln in lines if re.search(
+        rf" = bf16\[(?:{T * K},{D}|{T},{K},{D})\]", ln)]
 
 
 def test_granite_step_is_rematerialised_without_the_options(compile_for_chip):

@@ -35,7 +35,7 @@ PIECES = ["embedding_multiplier", "residual_multiplier", "logits_scaling",
           "gate_before_norm", "shared_expert", "nope"]
 
 
-def _operands(rows, seqs, tables, slots):
+def _operands(rows, seqs, tables, slots, T=T, W=W):
     """The ragged step's operands for ``rows`` = [(seq, start, chunk)], as
     engine._run_ragged lays them out for a state model (rows3 [R, 4])."""
     C, S_C = M.ragged_grid_shape(T)
@@ -365,6 +365,86 @@ def test_state_that_leaves_no_pool_for_one_sequence_raises_the_arithmetic(
     with pytest.raises(RuntimeError, match=r"50 blocks = 200 tokens, fewer "
                                            r"than the 201 .* state slots"):
         C.hbm_sized_num_blocks(cfg, 4, 0.5, min_tokens=201)
+
+
+def test_a_padded_long_chunk_leaves_a_state_that_decodes_right():
+    """A prompt that runs PADDED in a program long enough for the dropless
+    buffer to have two tiles an expert (150 tokens in the 256-token program;
+    the scan's blocks are 128), every row of every expert launch that no
+    pair names NaN, then decode steps from the state the chunk left: the
+    logits are the reference's within the limit every other step is held
+    to, and nothing in the state arrays is NaN. The shape that failed on
+    the chip (PERF.md section 6, PRs 44 and 46), not a full unpadded chunk;
+    what only the chip can show is chipbench/check_padded_chunk.py's."""
+    from dynamo_tpu.ops.grouped_matmul import ROW_TILE, _blocks
+    from tests.poisoned_launches import padded_chunk_then_decode
+
+    cfg, long, n, steps = granite4_tiny(), 256, 150, 4
+    (_, Eh), K = cfg.experts_held, cfg.num_experts_per_tok
+    assert _blocks(-(-long * K // ROW_TILE) + Eh, Eh, 64, 64, 4)[2]
+    assert not _blocks(-(-T * K // ROW_TILE) + Eh, Eh, 64, 64, 4)[2]
+    params = M.init_params(cfg, jax.random.key(0))
+    seq = {"A": np.random.default_rng(3).integers(1, 250, n + steps)}
+    got, state = padded_chunk_then_decode(
+        cfg, params, lambda row, width: _operands(
+            [row], seq, {"A": list(range(1, 64))}, {"A": 1}, T=width, W=64),
+        allocate_device_cache(cfg, NB, BS), allocate_state(cfg, SLOTS),
+        [(long, True, ("A", 0, n))] + [
+            (T, False, ("A", n + i, 1)) for i in range(steps)])
+    for a in state:  # the sequence's slot, the others, the dump slot
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+    ref = np.asarray(_reference(cfg, params, seq)["A"][0])
+    for i, lg in enumerate(got):
+        assert float(np.abs(lg - ref[n - 1 + i]).max()) < TOL_F32, i
+
+
+def test_what_a_padding_tokens_row_holds_reaches_no_state():
+    """The scan over a step's flat token axis with the padding tokens' rows
+    NaN on the way in (what a kernel further down may leave there on the
+    chip): the state and the valid tokens' y are bit for bit what zeros
+    there give. The padding is selected away, not multiplied: 0 x NaN is
+    NaN in the state of every chunk row of the step."""
+    from dynamo_tpu.ops.mamba2 import mamba2_ragged
+
+    cfg, long, n = granite4_tiny(), 256, 150
+    lp = jax.tree.map(lambda a: a[0], M.init_params(
+        cfg, jax.random.key(0))["stacks"][0])
+    C = cfg.mamba_d_inner + 2 * cfg.mamba_d_state
+    ks = jax.random.split(jax.random.key(1), 2)
+    xbc = jax.random.normal(ks[0], (long, C))
+    dt = jax.random.normal(ks[1], (long, cfg.mamba_n_heads))
+    rows = jnp.zeros((R, 4), jnp.int32).at[:, 3].set(SLOTS).at[0].set(
+        jnp.array([0, n, n, 1]))
+    pos = jnp.where(jnp.arange(long) < n, jnp.arange(long), 0)
+    pad = (jnp.arange(long) >= n)[:, None]
+
+    def run(fill):
+        conv, ssm = allocate_state(cfg, SLOTS)
+        return mamba2_ragged(
+            jnp.where(pad, fill, xbc), jnp.where(pad, fill, dt), lp, conv,
+            ssm, 0, rows, pos, cfg=cfg, chunks=True)
+
+    (y_n, conv_n, ssm_n), (y_z, conv_z, ssm_z) = run(jnp.nan), run(0.0)
+    assert np.isfinite(np.asarray(ssm_n)).all()
+    assert np.isfinite(np.asarray(conv_n)).all()
+    assert np.asarray(ssm_n == ssm_z).all() and np.asarray(
+        conv_n == conv_z).all()
+    assert np.asarray(y_n[:n] == y_z[:n]).all()
+
+
+@pytest.mark.anyio
+async def test_a_padded_chunk_through_the_engine_counts_what_it_read_back():
+    """The padded prompt through the engine: the first pick is the
+    reference's and the flight records hold the layer's own counts
+    (tests/poisoned_launches.py has the assertions)."""
+    from tests.poisoned_launches import padded_prompt_through_the_engine
+
+    cfg = granite4_tiny()
+    params = M.init_params(cfg, jax.random.key(0))
+    prompt = np.random.default_rng(3).integers(1, 250, 150)
+    await padded_prompt_through_the_engine(
+        cfg, params, "granite4_tiny", prompt, 256,
+        np.asarray(_reference(cfg, params, {"A": prompt})["A"][0])[-1], cfg.num_layers)
 
 
 @pytest.mark.anyio

@@ -134,6 +134,34 @@ def test_the_interpreted_kernels_give_the_same_logits(tiny_f32):
     assert _max_err(steps, _reference(cfg, params, seqs)) < TOL_F32
 
 
+def test_a_padded_long_chunk_leaves_a_tail_that_decodes_right():
+    """A prompt that runs PADDED (150 tokens in the 256-token program), the
+    attention layers through the ragged kernel (interpreted here; it writes
+    no row of a padding token, and the step selects those rows away), every
+    row of every expert launch that no pair names NaN, then decode steps
+    from the tails the chunk left: the logits are the reference's within the
+    limit every other step is held to, and no tail holds a NaN — the twin of
+    tests/test_granite4_h.py's case, the shape that failed on the chip."""
+    from tests.poisoned_launches import padded_chunk_then_decode
+    from tests.test_granite4_h import T
+
+    cfg, long, n, steps = lfm2_tiny(), 256, 150, 4
+    params = M.init_params(cfg, jax.random.key(0))
+    seq = {"A": np.random.default_rng(3).integers(1, 250, n + steps)}
+    got, state = padded_chunk_then_decode(
+        cfg, params, lambda row, width: _operands(
+            [row], seq, {"A": list(range(1, 64))}, {"A": 1}, T=width, W=64),
+        allocate_device_cache(cfg, NB, BS), allocate_state(cfg, SLOTS),
+        [(long, True, ("A", 0, n))] + [
+            (T, False, ("A", n + i, 1)) for i in range(steps)],
+        use_pallas=True)
+    for a in state:
+        assert np.isfinite(np.asarray(a, np.float32)).all()
+    ref = np.asarray(_reference(cfg, params, seq)["A"][0])
+    for i, lg in enumerate(got):
+        assert float(np.abs(lg - ref[n - 1 + i]).max()) < TOL_F32, i
+
+
 @pytest.mark.parametrize("piece", PIECES)
 def test_comparison_fails_when_a_piece_is_left_out(tiny_f32, piece):
     """The tolerance is tight enough to see each piece: the reference with

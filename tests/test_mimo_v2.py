@@ -31,7 +31,7 @@ TOL_F32 = 2e-4
 TOL_BF16 = 0.12
 
 
-def _rows_operands(rows, seqs, tables):
+def _rows_operands(rows, seqs, tables, T=T, W=W):
     """The ragged step's operands for ``rows`` = [(seq, start, chunk)], as
     engine._run_ragged lays them out."""
     C, S_C = M.ragged_grid_shape(T)
@@ -90,7 +90,7 @@ def run_engine_steps(cfg, params, seqs, *, routing=False):
         n_moe = sum(st.moe * len(st.layers) for st in M.layer_stacks(cfg))
         # every real token's K choices, in every expert layer, and no pad's
         assert stats[0] == n_tok * cfg.num_experts_per_tok * n_moe
-        assert stats[1] == stats[4:].sum() <= stats[0]
+        assert stats[1] == stats[M.MOE_STATS_HEAD:].sum() <= stats[0]
         # row tiles launched: one at least an expert touched
         assert stats[2] <= stats[3] <= stats[1]
         t = 0
@@ -224,9 +224,52 @@ def test_row_tiles_are_counted_as_the_kernel_launches_them():
     x = jax.random.normal(jax.random.key(5), (n, cfg.hidden_size))
     _, stats, _ = M._mlp_moe_held(x, lp, cfg, jnp.ones((n,), bool))
     stats = np.asarray(stats)
-    per_expert = stats[4:]
+    per_expert = stats[M.MOE_STATS_HEAD:]
     assert stats[2] == (per_expert > 0).sum()
     assert stats[3] == (-(-per_expert // ROW_TILE)).sum() > stats[2]
+
+
+def test_a_padded_long_chunk_then_decode_match_the_reference():
+    """A prompt that runs PADDED in a program long enough for the dropless
+    buffer to have two tiles an expert (150 tokens in the 256-token
+    program), every row of every expert launch that no pair names NaN, then
+    decode steps over the pages the chunk wrote: the logits are the
+    reference's within the limit every other step is held to (the twin of
+    tests/test_granite4_h.py's case: no recurrent state here, so a padding
+    token's row cannot outlive its step)."""
+    from dynamo_tpu.ops.grouped_matmul import ROW_TILE, _blocks
+    from tests.poisoned_launches import padded_chunk_then_decode
+
+    cfg, long, n, steps = mimo_tiny(), 256, 150, 4
+    (_, Eh), K = cfg.experts_held, cfg.num_experts_per_tok
+    assert _blocks(-(-long * K // ROW_TILE) + Eh, Eh, 64, 64, 4)[2]
+    assert not _blocks(-(-T * K // ROW_TILE) + Eh, Eh, 64, 64, 4)[2]
+    params = M.init_params(cfg, jax.random.key(0))
+    seq = {"A": np.random.default_rng(3).integers(1, 250, n + steps)}
+    got, _ = padded_chunk_then_decode(
+        cfg, params, lambda row, width: _rows_operands(
+            [row], seq, {"A": list(range(1, 64))}, T=width, W=64),
+        allocate_device_cache(cfg, NB, BS), None,
+        [(long, True, ("A", 0, n))] + [
+            (T, False, ("A", n + i, 1)) for i in range(steps)])
+    ref = _reference_logits(cfg, params, seq)["A"]
+    for i, lg in enumerate(got):
+        assert float(np.abs(lg - ref[n - 1 + i]).max()) < TOL_F32, i
+
+
+@pytest.mark.anyio
+async def test_a_padded_chunk_through_the_engine_counts_what_it_read_back():
+    """The padded prompt through the engine: the first pick is the
+    reference's and the flight records hold the layer's own counts
+    (tests/poisoned_launches.py has the assertions)."""
+    from tests.poisoned_launches import padded_prompt_through_the_engine
+
+    cfg = mimo_tiny()
+    params = M.init_params(cfg, jax.random.key(0))
+    prompt = np.random.default_rng(3).integers(1, 250, 150)
+    await padded_prompt_through_the_engine(
+        cfg, params, "mimo_tiny", prompt, 256,
+        _reference_logits(cfg, params, {"A": prompt})["A"][-1], 12)
 
 
 def test_k_rows_wider_than_a_lane_row_are_stored_padded():
