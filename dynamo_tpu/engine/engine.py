@@ -33,7 +33,9 @@ from dynamo_tpu.engine.cache import (
     BlockPool, NULL_BLOCK, SwapStore, allocate_device_cache, allocate_state,
     hbm_sized_num_blocks, slot_bytes, tree_nbytes,
 )
-from dynamo_tpu.engine.config import EngineArgs, ModelConfig
+from dynamo_tpu.engine.config import (
+    RAGGED_MAX_CHUNKS, EngineArgs, ModelConfig,
+)
 from dynamo_tpu.engine.scheduler import Scheduler, SeqState, StepPlan
 from dynamo_tpu.protocols import FinishReason, LLMEngineOutput, PreprocessedRequest
 from dynamo_tpu.runtime.chaos import get_chaos as _get_chaos
@@ -273,6 +275,13 @@ class AsyncJaxEngine:
         #: engages — dynamo_ragged_wide_tile_rows_total, and per step in
         #: the flight record
         self.wide_tile_rows_total = 0
+        #: the chunked Mamba-2 scan (ops/mamba2.py): (block, chunk row)
+        #: pairs its kernel walked, and the blocks x RAGGED_MAX_CHUNKS a
+        #: walk of every pair would take, summed over the Mamba-2 layers of
+        #: every chunk-holding step — counted on the host from the plan;
+        #: dynamo_ssd_block_rows_total{kind}, and per step in the flight
+        #: record
+        self.ssd_block_rows_total = {"walked": 0, "max": 0}
         if self.ragged_fallback_reason is not None:
             logger.warning(
                 "ragged Pallas kernel unavailable (reason=%s): steps take "
@@ -1801,7 +1810,9 @@ class AsyncJaxEngine:
         cur = {"ps": sched.preempt_swap_total,
                "pr": sched.preempt_recompute_total,
                "so": self.swap_out_blocks, "si": self.swap_in_blocks,
-               "wt": self.wide_tile_rows_total}
+               "wt": self.wide_tile_rows_total,
+               "sw": self.ssd_block_rows_total["walked"],
+               "sm": self.ssd_block_rows_total["max"]}
         last = self._flight_last
         delta = {k: cur[k] - last.get(k, 0) for k in cur}
         self._flight_last = cur
@@ -1841,7 +1852,7 @@ class AsyncJaxEngine:
             dead_window_pages=self._dead_window_pages(),
             **({} if self.state is None else self._state_fields(
                 kind, decode_rows, prefill_chunks, chunk_tokens, padded,
-                decode_seqs)),
+                decode_seqs, delta)),
             waiting=sched.num_waiting(), swapped=len(sched.swapped),
             running=len(sched.running),
             starved_decode=(sched.last_starved_decode
@@ -1862,10 +1873,11 @@ class AsyncJaxEngine:
             self.anomaly_profiler.on_record(rec)
 
     def _state_fields(self, kind, decode_rows, prefill_chunks, chunk_tokens,
-                      padded, decode_seqs) -> dict:
+                      padded, decode_seqs, delta) -> dict:
         """A state model's flight fields: slots held, the rows whose state
         the step moved (a pipelined step's: every row it was dispatched
-        with, finished meanwhile or not) and the step program that did."""
+        with, finished meanwhile or not), the step program that did and
+        what the chunked scan walked of what it could have."""
         sched = self.scheduler
         if kind == "decode_pipe":
             decode_rows = len(decode_seqs)
@@ -1876,7 +1888,25 @@ class AsyncJaxEngine:
                 "state_rows_prefill": prefill_chunks,
                 "state_rows_decode": decode_rows,
                 "state_program": ("m" if prefill_chunks else "d")
-                + str(bucket)}
+                + str(bucket),
+                "ssd_block_rows": delta["sw"],
+                "ssd_block_rows_max": delta["sm"]}
+
+    def _count_ssd_blocks(self, rows3, T: int) -> None:
+        """Called where a chunk-holding step's ``rows3`` is built: the
+        blocks of the flat token axis each chunk row (q_len > 1) overlaps
+        — the (block, row) pairs ``mamba2_chunk_scan`` walks — beside the
+        blocks x RAGGED_MAX_CHUNKS of the bucket, a Mamba-2 layer."""
+        spec = self.cfg.state_spec
+        if spec is None or spec.mixer != "mamba2":
+            return
+        from dynamo_tpu.ops.mamba2 import SSD_BLOCK as Q
+
+        q0, n = rows3[..., 0], rows3[..., 1]
+        walked = np.where(n > 1, (q0 + n - 1) // Q - q0 // Q + 1, 0).sum()
+        L = len(spec.layers)
+        self.ssd_block_rows_total["walked"] += L * int(walked)
+        self.ssd_block_rows_total["max"] += L * -(-T // Q) * RAGGED_MAX_CHUNKS
 
     def _count_wide_rows(self, rows3) -> None:
         """Called where a step's ``rows3`` is built: the rows whose q_len
@@ -2156,6 +2186,8 @@ class AsyncJaxEngine:
             # host-oracle guided fallbacks, penalties, swapped/waiting
             # work pending): the no-chunk-grid variant
             kind, fn = "ragged_dec", self.ragged_dec_fn
+        if works:
+            self._count_ssd_blocks(rows3, T)
         new_sig = (kind, T) not in self.compiled_signatures
         self.compiled_signatures.add((kind, T))
         self._mark("put")

@@ -104,6 +104,40 @@ def build_engine(cli, cfg: ModelConfig, args: EngineArgs):
                           guided_vocab=getattr(cli, "_guided_vocab", None))
 
 
+def register_state_metrics(metrics, engine) -> None:
+    """The /metrics families of a model with recurrent state (Mamba-2 or
+    short-convolution layers: one slot a running sequence beside the KV
+    pool); a model without state has none of them."""
+    if engine.state is None:
+        return
+    metrics.gauge(
+        "state_slots_in_use",
+        "recurrent-state slots held by running sequences").add_callback(
+        lambda: {None: engine.scheduler.state_slots
+                 - len(engine.scheduler.state_free)})
+    metrics.counter(
+        "state_slot_wait_total",
+        "admissions that had a row and blocks, and no free "
+        "recurrent-state slot").add_callback(
+        lambda: {None: engine.scheduler.state_slot_wait_total})
+    metrics.gauge(
+        "state_bytes",
+        "device bytes of the recurrent-state arrays (every slot and "
+        "the dump slot: the convolution's tails and, for Mamba-2 "
+        "layers, the SSM state)").add_callback(
+        lambda: {None: engine.state_bytes})
+    if engine.cfg.state_spec.mixer != "mamba2":
+        return
+    metrics.counter(
+        "ssd_block_rows_total",
+        "the chunked Mamba-2 scan, summed over Mamba-2 layers and "
+        "chunk-holding steps: (block, chunk row) pairs its kernel "
+        "walked, kind=\"walked\", and the blocks x chunk rows a walk "
+        "of every pair would take, kind=\"max\"").add_callback(
+        lambda: {(("kind", k),): v
+                 for k, v in engine.ssd_block_rows_total.items()})
+
+
 async def amain():
     ap = argparse.ArgumentParser(description="dynamo-tpu JAX engine worker")
     ap.add_argument("--model", default="jax-model", help="served model name")
@@ -594,26 +628,7 @@ async def amain():
         "128-lane rows for the ragged kernel (0.5: 64-wide heads padded "
         "to one row; 0: heads stored as they are)").add_callback(
         lambda: {None: engine.cfg.kv_lane_pad_share})
-    if engine.state is not None:
-        # recurrent state (a model with Mamba-2 or short-convolution
-        # layers): one slot a running sequence beside the KV pool; a model
-        # without state has no family
-        runtime.metrics.gauge(
-            "state_slots_in_use",
-            "recurrent-state slots held by running sequences").add_callback(
-            lambda: {None: engine.scheduler.state_slots
-                     - len(engine.scheduler.state_free)})
-        runtime.metrics.counter(
-            "state_slot_wait_total",
-            "admissions that had a row and blocks, and no free "
-            "recurrent-state slot").add_callback(
-            lambda: {None: engine.scheduler.state_slot_wait_total})
-        runtime.metrics.gauge(
-            "state_bytes",
-            "device bytes of the recurrent-state arrays (every slot and "
-            "the dump slot: the convolution's tails and, for Mamba-2 "
-            "layers, the SSM state)").add_callback(
-            lambda: {None: engine.state_bytes})
+    register_state_metrics(runtime.metrics, engine)
     # held-experts layer (one rank's share of an expert-parallel layer):
     # how much of the routing lands here, and on which experts
     runtime.metrics.counter(

@@ -193,6 +193,12 @@ class StepRecord:
     #: which step program moved them, as the update kernel's op names have
     #: it: "d" (decode-only) or "m" (mixed) and the token bucket
     state_program: str = ""
+    #: a Mamba-2 model's chunk-holding step: (block, chunk row) pairs the
+    #: chunked scan's kernel walked and the blocks x RAGGED_MAX_CHUNKS of
+    #: the step's token bucket, summed over the Mamba-2 layers (counted on
+    #: the host from the plan)
+    ssd_block_rows: int = 0
+    ssd_block_rows_max: int = 0
     kv_tiers: dict = field(default_factory=dict)  # {g1..g4: blocks}
     onboard_inflight: int = 0
     restore_inflight: int = 0
@@ -247,7 +253,8 @@ class StepRecord:
                   "moe_combine_rows", "moe_combine_rows_max",
                   "dead_window_pages",
                   "state_slots_used", "state_rows_prefill",
-                  "state_rows_decode", "state_program", "profile_path"):
+                  "state_rows_decode", "state_program", "ssd_block_rows",
+                  "ssd_block_rows_max", "profile_path"):
             v = getattr(self, k)
             if v:
                 d[k] = v

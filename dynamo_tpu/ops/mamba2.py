@@ -10,16 +10,28 @@ state after its last token there.
 The convolution is ops/shortconv.py's ``causal_conv``, which the
 short-convolution mixer shares.
 
-A step's rows are of two sorts. A row of ONE token (a decode row, or a
-chunk of one) is a read-modify-write of its slot by the kernel
-:func:`mamba2_decode_update`. A row of more (a prefill chunk;
+A step's rows are of two sorts, and each sort is ONE Pallas launch a layer.
+A row of ONE token (a decode row, or a chunk of one) is a read-modify-write
+of its slot by :func:`mamba2_decode_update`. A row of more (a prefill chunk;
 a step holds at most ``RAGGED_MAX_CHUNKS``) runs the chunked form of the
-recurrence (state-space duality) over the step's FLAT token axis,
-:func:`_ssd_flat`: inside a block of ``SSD_BLOCK`` tokens the output is a
+recurrence (state-space duality) over the step's FLAT token axis in
+:func:`mamba2_chunk_scan`, which walks the axis in blocks of ``SSD_BLOCK``
+tokens, a group of heads at a time, with the chunk rows' running states in
+VMEM from the first block to the last: inside a block a head's output is a
 masked matrix product in which a token sees only earlier tokens of its own
-row (the decay across a row boundary is zero), and between blocks each
-chunk row carries its own state, which tokens of other rows leave as it is
-— so a prompt fed in three budgets leaves the state one pass would.
+row (the decay across a row boundary is zero); between blocks each chunk row
+carries its own state, read and moved only in the blocks the row has tokens
+in, and tokens of other rows leave it as it is — so a prompt fed in three
+budgets leaves the state one pass would. No per-block state and no
+per-head decay matrix is ever written to HBM: the kernel reads x, B, C and
+four ``[T, H]`` float32 arrays of within-row cumulative sums, the rows'
+states once, and writes y and the states once. Everything a token is a row
+of ``[T, H·P]``, a head's P channels side by side on the lanes as in the
+state's lane rows below, so neither the scan nor what surrounds it
+transposes anything. Padding and foreign tokens are SELECTED away
+(``jnp.where``), never multiplied by zero: on the chip a row no kernel
+wrote may hold NaN; the scan writes every row of y (zeros outside the chunk
+rows). A token bucket under one block is padded to one.
 
 Whether a row starts a sequence is read off its first token's position
 (0): such a row starts from zeros whatever its slot holds, so a slot needs
@@ -32,11 +44,14 @@ last the compiler lays the array out in another order than its fusions
 read, and copies the whole array into and out of every step), ``ssm`` ``[L, slots + 1, H // pack, N, pack * P]`` in float32 —
 ``pack`` heads side by side on the minor axis so that a row of the state is
 a whole 128-lane row (P = 64 alone would leave half of every lane row
-empty, in memory too). :func:`pack_state` / :func:`unpack_state` turn it
-from and to ``[..., H, P, N]``.
+empty, in memory too), the layout both kernels compute in.
+:func:`unpack_state` turns it to ``[..., H, P, N]`` for whoever wants a
+head's state by itself (the tests).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -48,13 +63,6 @@ from dynamo_tpu.ops.shortconv import causal_conv, step_rows
 #: tokens of one block of the chunked recurrence (the MXU's height; the
 #: published ``mamba_chunk_size`` 256 is a tiling and changes no equation)
 SSD_BLOCK = 128
-
-
-def pack_state(s, pack: int):
-    """``[..., H, P, N]`` → ``[..., H // pack, N, pack * P]``."""
-    *lead, H, P, N = s.shape
-    s = s.reshape(*lead, H // pack, pack, P, N)
-    return jnp.moveaxis(s, -1, -3).reshape(*lead, H // pack, N, pack * P)
 
 
 def unpack_state(a, pack: int):
@@ -134,66 +142,198 @@ def mamba2_decode_update(ssm_state, lidx, slots, keep, n_rows, a, dx, Bm, Cm,
     return state, y
 
 
-def _ssd_flat(x, la, dx, Bm, Cm, onek, S0, cd):
-    """The chunk rows of a step, over its flat token axis.
+#: lane rows of ``pack`` heads a grid step of the scan: the K chunk rows'
+#: states of a group, 4 x 8 x 128 x 128 float32 = 2 MB, stay in VMEM from
+#: the step's first block to its last (in and out, double-buffered, 8 MB of
+#: the compiler's default 16 MiB)
+_SCAN_GROUPS = 8
 
-    x, dx [T, H, P] (dx = dt·x), la [T, H] = dt·A, Bm, Cm [T, N], ``onek``
-    [T, K] bool: token t belongs to chunk row k (no k: a token of a
-    one-token row or padding, which neither reads nor moves any state
-    here), S0 [K, H, P, N] the rows' states before the step. Returns
-    y [T, H, P] float32 (zeros outside the chunk rows) and the rows' states
-    after the step. ``cd``: dtype the matrix products take their inputs in.
+
+def _scan_kernel(present_ref, x_ref, b_ref, bt_ref, c_ref, st_ref, dec_ref,
+                 s0_ref, y_ref, s_ref, dxw_ref, eo_ref, *, P, pack, hb, cd):
+    from jax.experimental import pallas as pl
+
+    blk = pl.program_id(1)
+    Q, K = x_ref.shape[0], s_ref.shape[0]
+    W = pack * P
+    gb = x_ref.shape[1] // W
+
+    @pl.when(blk == 0)
+    def _():
+        s_ref[...] = s0_ref[...]
+
+    st = st_ref[...]                                     # [R, Q] by token
+    # the same numbers a token a sublane: what scales a row of x or of y
+    stT = jnp.concatenate(
+        [st, jnp.zeros((Q - st.shape[0], Q), st.dtype)], axis=0).T
+    ridr, ridc = st[4 * hb:4 * hb + 1, :], stT[:, 4 * hb:4 * hb + 1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    same = (ridc == ridr) & (ridc >= 0) & (ii >= jj)
+    Cm = c_ref[...]
+    cb = jax.lax.dot_general(Cm, b_ref[...], (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (Q, W), 1)
+    inside = ridc >= 0
+    # a padding token's x may be NaN: selected away, never multiplied by 0
+    head = [inside & (lane >= p * P) & (lane < (p + 1) * P)
+            for p in range(pack)]
+
+    def by_lane(r0, g):
+        """Rows ``r0 + head`` of the group's lane row g, a head's number
+        over its P lanes: [Q, W]."""
+        out = stT[:, r0 + g * pack + pack - 1:r0 + (g + 1) * pack]
+        for p in reversed(range(pack - 1)):
+            h = r0 + g * pack + p
+            out = jnp.where(lane < (p + 1) * P, stT[:, h:h + 1], out)
+        return out
+
+    for g in range(gb):
+        cols = slice(g * W, (g + 1) * W)
+        xg = x_ref[:, cols]
+        ms, xs = [], []
+        for p in range(pack):
+            h = g * pack + p
+            # decay x dt of token j as token i sees it: exp(cum_i - cum_j)
+            # dt_j, one exponential; outside the row and above the
+            # diagonal exp(-1e30) = 0
+            arg = stT[:, h:h + 1] - st[hb + h:hb + h + 1, :]
+            ms.append((jnp.exp(jnp.where(same, arg, -1e30)) * cb).astype(cd))
+            xs.append(jnp.where(head[p], xg, 0))
+        y_ref[:, cols] = jnp.dot(jnp.concatenate(ms, axis=1),
+                                 jnp.concatenate(xs, axis=0),
+                                 preferred_element_type=jnp.float32)
+        eo_ref[:, cols] = by_lane(2 * hb, g)
+        dxw_ref[:, cols] = (jnp.where(inside, xg, 0).astype(jnp.float32)
+                            * by_lane(3 * hb, g)).astype(cd)
+
+    def row(k, carry):   # one text for the K rows: a quarter to lower
+        @pl.when(present_ref[blk, k] > 0)
+        def _():
+            kf = k.astype(jnp.float32)
+            Ck = jnp.where(ridc == kf, Cm, 0)
+            Bk = jnp.where(ridr == kf, bt_ref[...], 0)
+            dec = dec_ref[...]       # row k of it, without a dynamic load
+            dec = jnp.sum(jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, dec.shape, 0) == k, dec, 0.0), axis=0,
+                keepdims=True)
+            for g in range(gb):
+                cols = slice(g * W, (g + 1) * W)
+                S = s_ref[k, g]
+                y_ref[:, cols] += eo_ref[:, cols] * jnp.dot(
+                    Ck, S.astype(cd), preferred_element_type=jnp.float32)
+                s_ref[k, g] = dec[:, cols] * S + jnp.dot(
+                    Bk, dxw_ref[:, cols], preferred_element_type=jnp.float32)
+        return carry
+
+    jax.lax.fori_loop(0, K, row, 0)
+
+
+def mamba2_chunk_scan(x, delta, la, Bm, Cm, onek, S0, *, P: int, pack: int,
+                      interpret: bool, tag: str = ""):
+    """The chunk rows of a step over its flat token axis, as ONE Pallas
+    launch (op ``mamba2_chunk_scan<tag>`` in the device trace) that walks
+    the axis block by block with the rows' running states in VMEM.
+
+    x [T, H·P] in the dtype the matrix products take their inputs in,
+    delta [T, H] = dt and la [T, H] = dt·A in float32, Bm, Cm [T, N],
+    ``onek`` [T, K] bool: token t belongs to chunk row k (no k: a token of
+    a one-token row or padding, which neither reads nor moves any state
+    here and whose x, dt, B and C may hold anything, NaN included), S0
+    [K, G, N, W] float32 the rows' states before the step, packed. Returns
+    y [T, H·P] float32 (zeros outside the chunk rows: every row is
+    written) and the rows' states after the step.
+
+    Inside a block of ``SSD_BLOCK`` tokens a head's output is the masked
+    product (C Bᵀ ∘ decay ∘ dt) x over the tokens of the same row; between
+    blocks a row's state is read (``C S``, decayed from the block's start)
+    and moved (``exp(Σ dt·A) S + Bᵀ (dt x decayed to the block's end)``)
+    only in the blocks the row has tokens in (``present``, prefetched).
+    The wrapper makes, in float32 and on [T, H] arrays, the within-row
+    cumulative sums the decays are exponentials of; the kernel makes every
+    [Q, Q] and [Q, W] product from them.
     """
-    T, H, P = x.shape
-    Q = min(T, SSD_BLOCK)
-    nC = T // Q
-    assert nC * Q == T, (T, Q)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    def r(a):
-        return a.reshape(nC, Q, *a.shape[1:])
+    T0, di = x.shape
+    H, N, K = delta.shape[1], Bm.shape[1], onek.shape[1]
+    G, W, Q, cd = H // pack, pack * P, SSD_BLOCK, x.dtype
+    pad = -T0 % Q
+    if pad:   # a bucket under one block: tokens outside every row
+        x, delta, la, Bm, Cm, onek = (
+            jnp.pad(a, ((0, pad), (0, 0))) for a in (x, delta, la, Bm, Cm,
+                                                     onek))
+    T = T0 + pad
+    nC = T // Q
+    gb = _SCAN_GROUPS if G % _SCAN_GROUPS == 0 else G
+    hb, HG = gb * pack, G // gb
+    R = -(-(4 * hb + 1) // 8) * 8
+    assert R <= Q, (hb, Q)
 
     inside = onek.any(-1)
-    la = jnp.where(inside[:, None], la, 0.0)
-    dx = jnp.where(inside[:, None, None], dx, 0.0)
-    # selected, not multiplied by 0 below: a padding token's row may hold
-    # NaN (a kernel further down wrote no such row), and 0 x NaN is NaN in
-    # the state of every chunk row of the step
-    Bm = jnp.where(inside[:, None], Bm, 0)
-    Cm = jnp.where(inside[:, None], Cm, 0)
     rid = jnp.where(inside, jnp.argmax(onek, axis=-1), -1)
-    ok, rla, rdx, rB, rC, rrid = r(onek), r(la), r(dx), r(Bm), r(Cm), r(rid)
-    cum = jnp.cumsum(rla, axis=1)                        # [nC, Q, H]
-    # inside a block: token i sees token j <= i of its own row, decayed
-    same = ((rrid[:, :, None] == rrid[:, None, :]) & (rrid[:, :, None] >= 0)
-            & (jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :])[None])
-    decay = jnp.exp(jnp.minimum(cum[:, :, None, :] - cum[:, None, :, :], 0.0))
-    cb = jnp.einsum("cin,cjn->cij", rC.astype(cd), rB.astype(cd),
-                    preferred_element_type=jnp.float32)
-    m = jnp.where(same[..., None], decay * cb[..., None], 0.0)
-    y = jnp.einsum("cijh,cjhp->cihp", m.astype(cd), rdx.astype(cd),
-                   preferred_element_type=jnp.float32)
-    # between blocks: every chunk row carries its own state; the tokens of
-    # other rows leave it as it is (their decay is 1, they add nothing)
-    okf = ok.astype(jnp.float32)
-    cum_k = jnp.cumsum(rla[:, :, None, :] * okf[..., None], axis=1)
-    tot_k = cum_k[:, -1]                                 # [nC, K, H]
-    cum_own = (cum_k * okf[..., None]).sum(2)            # [nC, Q, H]
-    to_end = ((tot_k[:, None] - cum_k) * okf[..., None]).sum(2)
-    dxw = rdx * jnp.exp(to_end)[..., None]
-    Bk = rB.astype(jnp.float32)[:, :, None, :] * okf[..., None]
-    Sc = jnp.einsum("cqhp,cqkn->ckhpn", dxw.astype(cd), Bk.astype(cd),
-                    preferred_element_type=jnp.float32)
+    # selected, never multiplied by 0: a padding token's row may hold NaN
+    la = jnp.where(inside[:, None], la, 0.0).reshape(nC, Q, H)
+    delta = jnp.where(inside[:, None], delta, 1.0)
+    Bm = jnp.where(inside[:, None], Bm, 0).astype(cd)
+    Cm = jnp.where(inside[:, None], Cm, 0).astype(cd)
+    ridb = rid.reshape(nC, Q)
+    own = (ridb[:, :, None] == ridb[:, None, :]) & (ridb[:, :, None] >= 0)
+    tri = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+    hi = jax.lax.Precision.HIGHEST
+    # sums of dt·A over a row's tokens in the block: up to and with token i
+    # (0 >= cum), after token j to the row's last in the block, all of them
+    cum = jnp.einsum("cij,cjh->cih", (own & tri).astype(jnp.float32), la,
+                     precision=hi).reshape(T, H)
+    to_end = jnp.einsum("cij,cjh->cih", (own & ~tri).astype(jnp.float32), la,
+                        precision=hi).reshape(T, H)
+    tot = jnp.einsum("cqk,cqh->ckh",
+                     onek.reshape(nC, Q, K).astype(jnp.float32), la,
+                     precision=hi)
 
-    def step(carry, inp):
-        sc, tot = inp
-        return jnp.exp(tot)[..., None, None] * carry + sc, carry
+    def rows(a):   # [T, H] -> [HG, hb, T]
+        return a.T.reshape(HG, hb, T)
 
-    S_fin, S_in = jax.lax.scan(step, S0, (Sc, tot_k))
-    Ck = rC.astype(jnp.float32)[:, :, None, :] * okf[..., None]
-    y_in = jnp.einsum("cqkn,ckhpn->cqhp", Ck.astype(cd), S_in.astype(cd),
-                      preferred_element_type=jnp.float32)
-    y = y + jnp.exp(cum_own)[..., None] * y_in
-    return y.reshape(T, H, P), S_fin
+    # what the kernel reads a (token, head), by token along the lanes: hb
+    # rows each of cum (token i of the decay), cum - log dt (token j: its
+    # dt rides the exponent), exp(cum) (a row's state as token i sees it),
+    # dt exp(to_end) (token j's share of the state the block leaves); then
+    # the token's chunk row, -1 outside every row
+    st = jnp.concatenate(
+        [rows(cum), rows(cum - jnp.log(delta)), rows(jnp.exp(cum)),
+         rows(delta * jnp.exp(to_end)),
+         jnp.broadcast_to(rid.astype(jnp.float32), (HG, 1, T)),
+         jnp.zeros((HG, R - 4 * hb - 1, T), jnp.float32)], axis=1)
+    dec = jnp.repeat(jnp.exp(tot), P, axis=-1)           # [nC, K, H·P]
+    present = onek.reshape(nC, Q, K).any(1).astype(jnp.int32)
+
+    tok = pl.BlockSpec((Q, gb * W), lambda j, c, pr: (c, j))
+    bc = pl.BlockSpec((Q, N), lambda j, c, pr: (c, 0))
+    state = pl.BlockSpec((K, gb, N, W), lambda j, c, pr: (0, j, 0, 0))
+    y, S = pl.pallas_call(
+        functools.partial(_scan_kernel, P=P, pack=pack, hb=hb, cd=cd),
+        out_shape=(jax.ShapeDtypeStruct((T, di), jnp.float32),
+                   jax.ShapeDtypeStruct(S0.shape, jnp.float32)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(HG, nC),
+            in_specs=[tok, bc,
+                      pl.BlockSpec((N, Q), lambda j, c, pr: (0, c)), bc,
+                      pl.BlockSpec((None, R, Q), lambda j, c, pr: (j, 0, c)),
+                      pl.BlockSpec((None, K, gb * W),
+                                   lambda j, c, pr: (c, 0, j)),
+                      state],
+            out_specs=(tok, state),
+            scratch_shapes=[pltpu.VMEM((Q, gb * W), cd),
+                            pltpu.VMEM((Q, gb * W), jnp.float32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="mamba2_chunk_scan" + tag,
+    )(present, x, Bm, Bm.T, Cm, st, dec, S0)
+    return y[:T0], S
 
 
 def mamba2_ragged(xbc, dt, lp, conv_state, ssm_state, lidx, rows, positions,
@@ -225,26 +365,30 @@ def mamba2_ragged(xbc, dt, lp, conv_state, ssm_state, lidx, rows, positions,
     xc = jax.nn.silu(pre)
     conv_state = conv_state.at[lidx, slot_r].set(new_tail.reshape(R, -1))
 
-    x = xc[:, :di].reshape(T, H, P)
+    # everything a token a row of [T, H·P]: a head's P channels lie side by
+    # side on the lanes, as the state's lane rows do (no [T, H, P] array,
+    # which the compiler lays head-minor and transposes to and fro)
+    x = xc[:, :di]
     Bm, Cm = xc[:, di:di + N], xc[:, di + N:]
     delta = jax.nn.softplus(dt.astype(jnp.float32)
                             + lp["dt_bias"].astype(jnp.float32)[None, :])
     la = delta * -jnp.exp(lp["A_log"].astype(jnp.float32))[None, :]
-    dx = x * delta[..., None]
 
     # rows of one token: a batched update of their slots
     one = valid & (q_len == 1)
     order = jnp.argsort(~one, stable=True)   # the kernel walks them only
     tok = first[order]
     G, Wl = H // pack, pack * P
+
+    def lanes(a):   # a head's number over its P lanes
+        return jnp.repeat(a, P, axis=1).reshape(R, G, Wl)
+
     ssm_state, y_k = mamba2_decode_update(
         ssm_state, lidx, jnp.where(one, slot, dump)[order], keep[order],
-        one.sum().astype(jnp.int32),
-        jnp.repeat(jnp.exp(la[tok]), P, axis=1).reshape(R, G, Wl),
-        dx[tok].reshape(R, G, Wl), Bm[tok], Cm[tok],
+        one.sum().astype(jnp.int32), lanes(jnp.exp(la[tok])),
+        x[tok].reshape(R, G, Wl) * lanes(delta[tok]), Bm[tok], Cm[tok],
         interpret=kernel_interpret_mode(), tag=tag)
-    y_a = jnp.zeros((R, H, P), jnp.float32).at[order].set(
-        y_k.reshape(R, H, P))
+    y_a = jnp.zeros((R, di), jnp.float32).at[order].set(y_k.reshape(R, di))
     if chunks:
         K = RAGGED_MAX_CHUNKS
         crow = jnp.nonzero(valid & (q_len > 1), size=K, fill_value=R)[0]
@@ -252,15 +396,16 @@ def mamba2_ragged(xbc, dt, lp, conv_state, ssm_state, lidx, rows, positions,
         crow = jnp.clip(crow, 0, R - 1)
         slot_c = jnp.where(cvalid, slot[crow], dump)
         S0 = jnp.where((cvalid & keep[crow])[:, None, None, None],
-                       unpack_state(ssm_state[lidx, slot_c], pack
-                                    ).astype(jnp.float32), 0.0)
+                       ssm_state[lidx, slot_c].astype(jnp.float32), 0.0)
         onek = ((tok_row[:, None] == crow[None, :]) & cvalid[None, :]
                 & tok_valid[:, None])
-        y, S_fin = _ssd_flat(x, la, dx, Bm, Cm, onek, S0, cd)
+        y, S_fin = mamba2_chunk_scan(
+            x.astype(cd), delta, la, Bm, Cm, onek, S0, P=P, pack=pack,
+            interpret=kernel_interpret_mode(), tag=tag)
         ssm_state = ssm_state.at[lidx, slot_c].set(
-            pack_state(S_fin, pack).astype(ssm_state.dtype))
+            S_fin.astype(ssm_state.dtype))
     else:
-        y = jnp.zeros((T, H, P), jnp.float32)
+        y = jnp.zeros((T, di), jnp.float32)
     y = y.at[jnp.where(one, first, T)].set(y_a, mode="drop")
-    y = y + lp["D"].astype(jnp.float32)[None, :, None] * x
-    return y.reshape(T, di), conv_state, ssm_state
+    y = y + jnp.repeat(lp["D"].astype(jnp.float32), P)[None, :] * x
+    return y, conv_state, ssm_state

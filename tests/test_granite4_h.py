@@ -500,6 +500,27 @@ async def test_engine_serves_granite_through_slots_and_says_what_it_did():
     assert eng.moe_assignments_total["held"] > 0
     assert sum(r.get("moe_tiles", 0) for r in recs) == \
         eng.moe_row_tiles_total > 0
+    # the chunked scan's counter: every step that held a chunk says what
+    # its kernel walked (a chunk row a block here: the buckets are under
+    # one block) of the four rows a block it could have, a Mamba-2 layer,
+    # and /metrics adds them up
+    from dynamo_tpu.engine.main import register_state_metrics
+    from dynamo_tpu.runtime.metrics import MetricsRegistry
+
+    held = [r for r in recs if r.get("state_rows_prefill")]
+    assert held and all(
+        0 < r["ssd_block_rows"] <= r["ssd_block_rows_max"] == 5 * 4
+        and r["ssd_block_rows"] % 5 == 0 for r in held)
+    assert not any(r.get("ssd_block_rows_max") for r in recs
+                   if not r.get("state_rows_prefill"))
+    registry = MetricsRegistry()
+    register_state_metrics(registry, eng)
+    text = registry.render()
+    for kind, total in eng.ssd_block_rows_total.items():
+        assert total >= sum(r["ssd_block_rows" + "_max" * (kind == "max")]
+                            for r in held) > 0
+        assert f'dynamo_ssd_block_rows_total{{kind="{kind}"}} {total}' in text
+    assert "dynamo_state_slots_in_use" in text
     # greedy tokens are the reference's: each request alone, one pass
     weights, hp = granite4_h_inputs(cfg, eng.params)
     for ids, out in zip(prompts, outs):
