@@ -6,7 +6,7 @@ conditionally delegate prefill to a dedicated prefill fleet, and the computed
 KV blocks move prefill→decode.
 
 TPU-native transfer: no RDMA exists on TPU-VMs, so blocks ship host-staged —
-prefill gathers its pages (ops.block_copy.gather_blocks, one device→host
+prefill gathers its pages (engine/cache.py KvPages.gather, one device→host
 DMA), the bundle rides the existing TCP response plane back to the decode
 worker, which scatters it into its own paged cache (host→device). Intra-pod
 (same process/mesh) hand-off skips the host round-trip via device-to-device

@@ -10,7 +10,8 @@ Layout:
 - config.py    — ModelConfig / EngineArgs
 - model.py     — llama-family forward pass over a paged KV cache (scan layers)
 - sampling.py  — on-device sampling (greedy / temperature / top-k / top-p)
-- cache.py     — device cache allocation + host-side block pool & prefix cache
+- cache.py     — device cache allocation and its one holder (KvPages) +
+                host-side block pool & prefix cache
 - scheduler.py — continuous batching: admission, chunked prefill, decode batch
 - engine.py    — AsyncJaxEngine: the async generate() loop + KV events
 - loader.py    — HF checkpoint loading / random init

@@ -108,7 +108,7 @@ def register_state_metrics(metrics, engine) -> None:
     """The /metrics families of a model with recurrent state (Mamba-2 or
     short-convolution layers: one slot a running sequence beside the KV
     pool); a model without state has none of them."""
-    if engine.state is None:
+    if engine.kv.state is None:
         return
     metrics.gauge(
         "state_slots_in_use",
@@ -125,7 +125,7 @@ def register_state_metrics(metrics, engine) -> None:
         "device bytes of the recurrent-state arrays (every slot and "
         "the dump slot: the convolution's tails and, for Mamba-2 "
         "layers, the SSM state)").add_callback(
-        lambda: {None: engine.state_bytes})
+        lambda: {None: engine.kv.state_nbytes})
     if engine.cfg.state_spec.mixer != "mamba2":
         return
     metrics.counter(
@@ -205,10 +205,6 @@ async def amain():
                     help="AOT-precompile every configured prefill/decode "
                          "bucket before serving so the first request pays "
                          "no XLA compile (engine.warmup())")
-    ap.add_argument("--warmup-seq-lens", default=None,
-                    help="comma-separated expected total sequence lengths "
-                         "for --warmup-buckets (picks the block-table-width "
-                         "buckets to trace; default: max-model-len)")
     ap.add_argument("--no-pipeline-decode", dest="pipeline_decode",
                     action="store_false", default=True,
                     help="disable the depth-2 pipelined decode loop "
@@ -421,17 +417,6 @@ async def amain():
         # test tokenizer the frontend will serve with
         from dynamo_tpu.llm.tokenizer import make_test_tokenizer
         cli._guided_vocab = make_test_tokenizer().guided_vocab()
-    # parse BEFORE the heavy engine build: a typo'd value must fail in
-    # milliseconds, not after minutes of weight loading
-    warmup_seq_lens = None
-    if cli.warmup_seq_lens:
-        try:
-            warmup_seq_lens = [int(x) for x in cli.warmup_seq_lens.split(",")
-                               if x.strip()]
-        except ValueError:
-            ap.error(f"--warmup-seq-lens must be comma-separated ints, "
-                     f"got {cli.warmup_seq_lens!r}")
-
     if cfg.state_spec is not None and cli.role != "aggregated":
         raise SystemExit(
             f"--role {cli.role}: a model with recurrent state serves "
@@ -441,7 +426,7 @@ async def amain():
     if args.warmup_buckets:
         # before joining the control plane: no request can race the dummy
         # dispatches, and a slow compile can't starve the lease keepalive
-        await engine.warmup(seq_lens=warmup_seq_lens)
+        await engine.warmup()
     runtime = await DistributedRuntime.create()
 
     if cli._mh_world > 1 and cli._mh_rank > 0:

@@ -39,6 +39,9 @@ from dynamo_tpu.engine.quant import is_qtensor as _is_q
 from dynamo_tpu.engine.quant import materialize as _qmat
 from dynamo_tpu.engine.quant import qmm as _mm
 from dynamo_tpu.engine.quant import qmm_heads as _mm_heads
+from dynamo_tpu.ops.kv_pages import (
+    cache_shape, gather_pages, is_quant_cache, quantize_kv,
+)
 
 # ---------------------------------------------------------------------------
 # Parameter init / pytree layout
@@ -739,8 +742,6 @@ def _paged_attention(q, k_cache, v_cache, lidx, block_tables, positions,
     contract.)
     """
     B, S, H, hd = q.shape
-    from dynamo_tpu.engine.cache import cache_shape, gather_pages
-
     KV = cache_shape(v_cache)[2]
     G = H // KV
     W = block_tables.shape[1]
@@ -824,8 +825,6 @@ def _paged_attention_seg(q, k_cache, v_cache, lidx, block_tables, positions,
     paths keep their exact historical numerics.
     """
     B, S, H, hd = q.shape
-    from dynamo_tpu.engine.cache import cache_shape, gather_pages
-
     KV, vd = cache_shape(v_cache)[2:]
     G = H // KV
     W = block_tables.shape[1]
@@ -930,8 +929,6 @@ def _ragged_attention(q, kc, vc, lidx, block_tables, positions, rows3,
     (q_start, q_len, kv_len); grid_rows None = no-chunk variant (the
     pipelined decode path) — the grid sub-call is skipped entirely.
     """
-    from dynamo_tpu.engine.cache import cache_shape
-
     T, H, hd = q.shape
     vd = cache_shape(vc)[3]
     R = rows3.shape[0]
@@ -999,7 +996,6 @@ def _mla_attention_seg(q_eff, q_rot, kc, vc, lidx, block_tables, positions,
     dr = q_rot.shape[-1]
     W = block_tables.shape[1]
     bs = block_size
-    from dynamo_tpu.engine.cache import gather_pages
 
     spp = max(1, min(W, -(-seg_keys // bs)))
     SEG = spp * bs
@@ -1128,8 +1124,6 @@ def _mla_attention(h, lp, lidx, kc, vc, slot_map, block_tables, positions,
     k_rot = _rope(ckv[..., None, r:], positions, cfg.rope_theta,
                   cfg.rope_scaling)  # [B,S,1,dr]
 
-    from dynamo_tpu.engine.cache import is_quant_cache
-
     kv_quant = is_quant_cache(kc)
     flat = slot_map.reshape(B * S)
     rot_pad = jnp.pad(k_rot.reshape(B * S, 1, dr),
@@ -1137,8 +1131,6 @@ def _mla_attention(h, lp, lidx, kc, vc, slot_map, block_tables, positions,
     if kv_quant:
         # int8 latent pages: one scale per (slot, stream) — the latent and
         # rope streams quantize independently (their magnitudes differ)
-        from dynamo_tpu.engine.cache import quantize_kv
-
         cq, cs = quantize_kv(c.reshape(B * S, 1, r))
         rq, rs = quantize_kv(rot_pad)
         kc = {"q": kc["q"].at[lidx, flat].set(cq, mode="drop"),
@@ -1152,7 +1144,6 @@ def _mla_attention(h, lp, lidx, kc, vc, slot_map, block_tables, positions,
     w_uk = lp["w_uk"].reshape(r, H, dn).astype(jnp.float32)
     q_eff = jnp.einsum("bshd,rhd->bshr", q_nope.astype(jnp.float32), w_uk)
 
-    from dynamo_tpu.engine.cache import cache_shape
     from dynamo_tpu.ops.paged_attention import mla_int8_kernel_supported
 
     _L, _slots, _, _ = cache_shape(kc)
@@ -1223,10 +1214,8 @@ def _mla_attention(h, lp, lidx, kc, vc, slot_map, block_tables, positions,
         slot_idx = (block_tables[:, :, None] * block_size
                     + jnp.arange(block_size)[None, None, :]).reshape(B, T)
         # gather_pages dequantizes int8 caches to f32 in the gather (the
-        # shared contract for every XLA-level attention read — cache.py);
+        # shared contract for every XLA-level attention read — kv_pages.py);
         # plain caches come back in cache dtype
-        from dynamo_tpu.engine.cache import gather_pages
-
         cg = gather_pages(kc, lidx, slot_idx)[:, :, 0]   # [B,T,r]
         krg = gather_pages(vc, lidx, slot_idx)[:, :, 0]  # [B,T,pr] (padded)
         if use_flash and S > 1:
@@ -1633,7 +1622,6 @@ def _pallas_decode_attn(q1, kc, vc, lidx, block_tables, kv_lens, window,
     is a (possibly per-layer traced) scalar, 0 = full attention; ``sinks``
     [H] are gpt-oss attention-sink logits (ignored unless has_sink).
     """
-    from dynamo_tpu.engine.cache import cache_shape, is_quant_cache
     from dynamo_tpu.ops.paged_attention import paged_attention_decode
 
     L_, slots_, KV, hd = cache_shape(kc)
@@ -1734,7 +1722,6 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
     D, hd, vd = cfg.hidden_size, cfg.head_dim, cfg.v_dim
     vcd = cfg.v_cache_dim  # width of a stored V row
     H = cfg.num_heads
-    from dynamo_tpu.engine.cache import gather_pages, is_quant_cache
     kv_quant = is_quant_cache(k_cache)
     #: the ring, bucketed-decode and flash-prefill kernels know one KV-head
     #: count and one head width
@@ -1833,8 +1820,6 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
 
         flat_slots = slot_map.reshape(B * S)
         if kv_quant:
-            from dynamo_tpu.engine.cache import quantize_kv
-
             kq, ks = quantize_kv(k.reshape(B * S, KV, -1))
             vq, vs = quantize_kv(v.reshape(B * S, KV, vcd))
             kq = kq.reshape(B * S, *kc["q"].shape[2:])
@@ -1907,8 +1892,6 @@ def forward(params: dict, tokens, positions, slot_map, block_tables, kv_lens,
                                      KV, cfg.k_cache_dim // cfg.k_lane_rows,
                                      vcd))
             if use_ragged_kernel:
-                from dynamo_tpu.engine.cache import cache_shape
-
                 # K's rows: KV heads, times the lane rows of a wide head
                 L_, slots_, KV_, hd_ = cache_shape(kc)
                 nb = slots_ // block_size

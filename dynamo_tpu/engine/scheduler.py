@@ -177,12 +177,11 @@ class Scheduler:
                  on_stored: Optional[Callable] = None,
                  onboard_cb: Optional[Callable] = None,
                  swapper: Optional[object] = None,
-                 token_budget: bool = True,
                  hot_cb: Optional[Callable] = None,
                  state_slots: int = 0):
         self.args = args
         self.pool = pool
-        #: free recurrent-state slots (engine/cache.py:allocate_state), or
+        #: free recurrent-state slots (engine/cache.py:KvPages.state), or
         #: None for a model without state layers: nothing below looks at
         #: slots then. A sequence that starts at position 0 starts from
         #: zeros whatever its slot holds (ops/shortconv.py), so a slot is a
@@ -202,9 +201,7 @@ class Scheduler:
         #: accounting: better-class chunks are admitted first (class
         #: order), and decode rows cost one token each — they never
         #: inflate a better-class prefill's padded step shape, so there is
-        #: nothing to shed. (``token_budget`` is accepted for API
-        #: compatibility and ignored — the bucketed planner is gone.)
-        self.token_budget = True
+        #: nothing to shed.
         self.on_stored = on_stored  # fn(parent_hash, [StoredBlock], [block_id])
         #: fn(probe: TokenBlockSequence, start_block, end_block) -> [block_id]
         #: — KVBM onboard hook: device-misses found in host/disk tiers come

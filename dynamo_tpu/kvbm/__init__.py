@@ -6,9 +6,9 @@ registration, onboard on cache miss, ref: block_manager/offload.rs:4-34).
 
 TPU mapping: G1 is the engine's paged HBM cache (engine/cache.py BlockPool);
 G2 is TPU-VM host DRAM (generous on TPU-VMs — it doubles as the disagg
-staging buffer); G3 is local NVMe. Transfers ride ops/block_copy
-gather/scatter (one DMA per bundle) instead of CUDA copy streams; there is
-no NIXL — cross-host movement goes through the response plane (disagg) or
+staging buffer); G3 is local NVMe. Transfers ride engine/cache.py's KvPages
+(ops/block_copy gather/scatter, one DMA per bundle) instead of CUDA copy
+streams; there is no NIXL — cross-host movement goes through the response plane (disagg) or
 the object store.
 """
 

@@ -183,7 +183,7 @@ class StepRecord:
     #: pages of window cache groups that lie wholly behind their
     #: sequence's window: what releasing them would free
     dead_window_pages: int = 0
-    #: a model with recurrent state (engine/cache.py:allocate_state): slots
+    #: a model with recurrent state (engine/cache.py:KvPages.state): slots
     #: held by running sequences after the step, and the rows whose state
     #: the step moved — prefill chunks (the chunked scan) and decode rows
     #: (one update each); all absent for a model without state layers

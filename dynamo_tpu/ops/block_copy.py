@@ -17,9 +17,16 @@ sharding (XLA inserts the all-to-all).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from dynamo_tpu.ops.kv_pages import (
+    dequantize_kv, is_quant_cache, pack_kv_blocks, quantize_kv,
+    unpack_kv_blocks,
+)
 
 
 def _pad_pow2_ids(block_ids: np.ndarray) -> np.ndarray:
@@ -41,15 +48,13 @@ def gather_blocks(cache, block_ids, *, block_size: int) -> jax.Array:
 
     cache: [L, num_slots, KV, hd] array → bundle [L, P, block_size, KV, hd];
     int8 {"q","s"} cache → PACKED uint8 bundle [L, P, bs·KV·(hd+4)] (native
-    (q, s) bytes — engine/cache.pack_kv_blocks). P = next pow2 ≥ n (trailing
+    (q, s) bytes — ops/kv_pages.pack_kv_blocks). P = next pow2 ≥ n (trailing
     entries repeat the last block; slice axis 1 host-side for exact n).
 
     Packed bundles keep KVBM tiers and the disagg wire at ~1 byte/element
     (4x smaller than an f32 bundle, 2x smaller than bf16) and make the
     offload→onboard roundtrip bit-exact by construction — the packing
     happens on device, so the device→host copy shrinks identically."""
-    from dynamo_tpu.engine.cache import is_quant_cache, pack_kv_blocks
-
     if is_quant_cache(cache):
         L, slots, KV, hd = cache["q"].shape
         ids = jnp.asarray(_pad_pow2_ids(np.asarray(block_ids, np.int32)))
@@ -63,9 +68,6 @@ def gather_blocks(cache, block_ids, *, block_size: int) -> jax.Array:
     return jnp.take(paged, jnp.asarray(ids), axis=1)
 
 
-import functools
-
-
 @functools.partial(jax.jit, static_argnames=("block_size",), donate_argnums=(0,))
 def _scatter(cache, block_ids, bundle, *, block_size):
     L, slots, KV, hd = cache.shape
@@ -76,8 +78,6 @@ def _scatter(cache, block_ids, bundle, *, block_size):
 @functools.partial(jax.jit, static_argnames=("block_size",), donate_argnums=(0,))
 def _scatter_quant(cache, block_ids, bundle, *, block_size):
     """Quantize the f32 bundle in-trace and write both cache leaves."""
-    from dynamo_tpu.engine.cache import quantize_kv
-
     L, slots, KV, hd = cache["q"].shape
     qb, sb = quantize_kv(bundle)  # [L, n, bs, KV, hd] / [L, n, bs, KV]
     qp = cache["q"].reshape(L, slots // block_size, block_size, KV, hd)
@@ -92,8 +92,6 @@ def _scatter_quant(cache, block_ids, bundle, *, block_size):
 def _scatter_packed(cache, block_ids, bundle, *, block_size):
     """Write a packed uint8 bundle's (q, s) bytes straight into the cache
     leaves — no requant, bit-exact by construction."""
-    from dynamo_tpu.engine.cache import unpack_kv_blocks
-
     L, slots, KV, hd = cache["q"].shape
     qb, sb = unpack_kv_blocks(bundle, block_size, KV, hd)
     qp = cache["q"].reshape(L, slots // block_size, block_size, KV, hd)
@@ -123,8 +121,6 @@ def _scatter_layers(cache, block_ids, bundle, *, block_size, start_layer):
 def _scatter_packed_layers(cache, block_ids, bundle, *, block_size,
                            start_layer):
     """Layer-sliced write of a packed uint8 [nL, n, X] quant bundle."""
-    from dynamo_tpu.engine.cache import unpack_kv_blocks
-
     L, slots, KV, hd = cache["q"].shape
     nL = bundle.shape[0]
     qb, sb = unpack_kv_blocks(bundle, block_size, KV, hd)
@@ -144,8 +140,6 @@ def _scatter_quant_layers(cache, block_ids, bundle, *, block_size,
                           start_layer):
     """Layer-sliced write of a VALUE bundle into an int8 cache (quantize
     in-trace — the cross-layout pair of _scatter_quant)."""
-    from dynamo_tpu.engine.cache import quantize_kv
-
     L, slots, KV, hd = cache["q"].shape
     nL = bundle.shape[0]
     qb, sb = quantize_kv(bundle)
@@ -180,17 +174,13 @@ def scatter_blocks(cache, block_ids, bundle, *, block_size: int,
     Cross-layout pairs both work: a packed bundle into a plain cache
     dequantizes on the way in (mixed prefill/decode deployments); a value
     bundle into an int8 cache re-quantizes in-trace (bit-exact for bundles
-    that started as quantized pages — engine/cache.py int8 notes).
+    that started as quantized pages — ops/kv_pages.py's numerics contract).
 
     ``start_layer`` (int) means the bundle is a LAYER SLICE: its leading
     axis covers only layers [start_layer, start_layer + nL) of the cache —
     the layer-interleaved disagg transfer path (docs/disagg.md). None =
     full depth.
     """
-    from dynamo_tpu.engine.cache import (
-        is_quant_cache, unpack_kv_blocks, dequantize_kv,
-    )
-
     ids = np.asarray(block_ids, np.int32)
     pids = _pad_pow2_ids(ids)
     packed = _is_packed(bundle)

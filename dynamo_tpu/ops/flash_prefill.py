@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.ops.kv_pages import gather_pages
 from dynamo_tpu.ops.paged_attention import kernel_interpret_mode
 
 _NEG = -1e30
@@ -232,8 +233,6 @@ def flash_prefill_paged(q, k_cache, v_cache, lidx, block_tables, positions,
     Same signature family as engine/model._paged_attention; q [B,S,H,hd],
     caches [L, slots, KV, hd].
     """
-    from dynamo_tpu.engine.cache import gather_pages
-
     B = q.shape[0]
     W = block_tables.shape[1]
     slot_idx = (block_tables[:, :, None] * block_size

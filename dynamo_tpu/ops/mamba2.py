@@ -45,8 +45,6 @@ read, and copies the whole array into and out of every step), ``ssm`` ``[L, slot
 ``pack`` heads side by side on the minor axis so that a row of the state is
 a whole 128-lane row (P = 64 alone would leave half of every lane row
 empty, in memory too), the layout both kernels compute in.
-:func:`unpack_state` turns it to ``[..., H, P, N]`` for whoever wants a
-head's state by itself (the tests).
 """
 
 from __future__ import annotations
@@ -63,13 +61,6 @@ from dynamo_tpu.ops.shortconv import causal_conv, step_rows
 #: tokens of one block of the chunked recurrence (the MXU's height; the
 #: published ``mamba_chunk_size`` 256 is a tiling and changes no equation)
 SSD_BLOCK = 128
-
-
-def unpack_state(a, pack: int):
-    """``[..., H // pack, N, pack * P]`` → ``[..., H, P, N]``."""
-    *lead, G, N, W = a.shape
-    a = a.reshape(*lead, G, N, pack, W // pack)
-    return jnp.moveaxis(a, -3, -1).reshape(*lead, G * pack, W // pack, N)
 
 
 #: head groups (lane rows of ``pack`` heads) of one block of the update

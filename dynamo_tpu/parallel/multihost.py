@@ -354,8 +354,8 @@ class StepFollower:
                                            "multi": "multi_fn"}[kind])
                     outs = fn(eng.params,
                               *(eng._put_batch(k, a[k]) for k in keys),
-                              eng.k_cache, eng.v_cache)
-                    eng.k_cache, eng.v_cache = outs[-2], outs[-1]
+                              eng.kv.k, eng.kv.v)
+                    eng.kv.k, eng.kv.v = outs[-2], outs[-1]
                 self.steps_replayed += 1
             except asyncio.CancelledError:
                 raise

@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from dynamo_tpu.ops.kv_pages import gather_pages
+
 _NEG = -1e30
 
 
@@ -196,8 +198,6 @@ def ring_prefill_paged(q, kc, vc, lidx, block_tables, positions, kv_lens, *,
     local_bt = jax.lax.dynamic_slice_in_dim(block_tables, idx * Wl, Wl, axis=1)
     slot_idx = (local_bt[:, :, None] * block_size
                 + jnp.arange(block_size)[None, None, :]).reshape(B, Tl)
-    from dynamo_tpu.engine.cache import gather_pages
-
     # int8 caches dequantize inside the gather; ring slices then rotate
     # as q-dtype chunks exactly like the plain-cache path
     k = gather_pages(kc, lidx, slot_idx).astype(q.dtype)  # [B, Tl, KV, hd]

@@ -129,7 +129,7 @@ async def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     cks = jax.jit(lambda a: jnp.sum(jnp.abs(a.astype(jnp.float32))),
-                  out_shardings=NamedSharding(mesh, P()))(eng.k_cache)
+                  out_shardings=NamedSharding(mesh, P()))(eng.kv.k)
     print(f"CKSUM {float(cks):.6f}", flush=True)
     await eng.close()
     await plane.close()

@@ -20,7 +20,6 @@ from dynamo_tpu.engine.cache import allocate_device_cache, allocate_state
 from dynamo_tpu.engine.config import RAGGED_MAX_CHUNKS
 from dynamo_tpu.models import granite4_tiny
 from dynamo_tpu.models.reference import granite4_h, granite4_h_inputs
-from dynamo_tpu.ops.mamba2 import unpack_state
 
 BS, NB, T, R, W, SLOTS = 4, 64, 32, 4, 16, 3
 #: float32 against float32 on one backend: what is left is the order of the
@@ -33,6 +32,14 @@ TOL_BF16 = 0.15
 PIECES = ["embedding_multiplier", "residual_multiplier", "logits_scaling",
           "attention_multiplier", "D", "dt_bias", "conv_bias",
           "gate_before_norm", "shared_expert", "nope"]
+
+
+def unpack_state(a, pack: int):
+    """The SSM state as ``ops/mamba2.py`` lays it out, ``[..., H // pack, N,
+    pack * P]``, turned to a head's state by itself: ``[..., H, P, N]``."""
+    *lead, G, N, W = a.shape
+    a = a.reshape(*lead, G, N, pack, W // pack)
+    return np.moveaxis(a, -3, -1).reshape(*lead, G * pack, W // pack, N)
 
 
 def _operands(rows, seqs, tables, slots, T=T, W=W):

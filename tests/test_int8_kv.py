@@ -6,7 +6,7 @@ sizing, and e2e engine serving.
 Mirrors the KV-capacity role of the reference's G1 tier (ref:
 lib/llm/src/block_manager/) — the reference gets KV compression from
 engine-side fp8 KV caches (vllm flags pass through); here int8 pages are a
-first-class cache layout (engine/cache.py int8 notes).
+first-class cache layout (ops/kv_pages.py).
 """
 
 import asyncio
@@ -14,11 +14,11 @@ import asyncio
 import numpy as np
 import pytest
 
-from dynamo_tpu.engine.cache import (
-    allocate_device_cache, cache_shape, dequantize_kv, hbm_sized_num_blocks,
-    is_quant_cache, quantize_kv,
-)
+from dynamo_tpu.engine.cache import allocate_device_cache, hbm_sized_num_blocks
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
+from dynamo_tpu.ops.kv_pages import (
+    cache_shape, dequantize_kv, is_quant_cache, quantize_kv,
+)
 
 pytestmark = pytest.mark.anyio
 
@@ -206,7 +206,7 @@ def test_flash_prefill_paged_int8():
 # ------------------------------------------------------- gather/scatter paths
 
 def test_pack_unpack_roundtrip():
-    from dynamo_tpu.engine.cache import (
+    from dynamo_tpu.ops.kv_pages import (
         pack_kv_blocks, packed_block_width, unpack_kv_blocks,
     )
 
@@ -227,7 +227,7 @@ def test_gather_scatter_roundtrip_bit_exact():
     """offload → onboard over an int8 cache must restore the identical
     quantized pages (the determinism KVBM promises across tiers). The
     native bundle is PACKED uint8 — ~1 byte/element on the wire/tiers."""
-    from dynamo_tpu.engine.cache import packed_block_width
+    from dynamo_tpu.ops.kv_pages import packed_block_width
     from dynamo_tpu.ops.block_copy import gather_blocks, scatter_blocks
 
     cfg = ModelConfig.tiny()
@@ -317,7 +317,7 @@ async def test_engine_int8_kv_serves_and_matches_bf16_greedy():
     f32 model — quantization noise far below the logit gaps)."""
     e_ref = _engine()
     e_q = _engine(kv_cache_dtype="int8")
-    assert e_q._kv_quant and is_quant_cache(e_q.k_cache)
+    assert e_q._kv_quant and is_quant_cache(e_q.kv.k)
     prompt = list(range(1, 20))
     t_ref = await _collect(e_ref, _req(prompt))
     t_q = await _collect(e_q, _req(prompt))

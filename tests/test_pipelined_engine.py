@@ -230,7 +230,7 @@ async def test_warmup_compiles_each_bucket_exactly_once():
 
     eng.ragged_fn = wrap("ragged", eng.ragged_fn)
     eng.ragged_dec_fn = wrap("ragged_dec", eng.ragged_dec_fn)
-    rep = await eng.warmup(seq_lens=[14])
+    rep = await eng.warmup()
     buckets = list(eng.args.ragged_token_buckets)
     # both variants trace every configured token bucket, exactly once
     for kind in ("ragged", "ragged_dec"):

@@ -24,9 +24,9 @@ import numpy as np
 import pytest
 
 from dynamo_tpu.engine import model as M
-from dynamo_tpu.engine.cache import is_quant_cache, quantize_kv
 from dynamo_tpu.engine.config import EngineArgs, ModelConfig
 from dynamo_tpu.engine.engine import AsyncJaxEngine
+from dynamo_tpu.ops.kv_pages import is_quant_cache, quantize_kv
 from dynamo_tpu.ops.ragged_attention import (
     ragged_attention_xla, ragged_int8_kernel_supported,
     ragged_paged_attention,
@@ -290,7 +290,7 @@ async def test_quant_weights_with_int8_kv_match_bf16_kv_oracle():
     sampler."""
     e_q = _engine(quantization="int8", kv_cache_dtype="int8")
     e_o = _engine(quantization="int8")
-    assert is_quant_cache(e_q.k_cache)
+    assert is_quant_cache(e_q.kv.k)
     assert await _run(e_q, PROMPTS) == await _run(e_o, PROMPTS)
     assert await _run(e_q, PROMPTS, seed0=5) == \
         await _run(e_o, PROMPTS, seed0=5)

@@ -551,7 +551,6 @@ async def test_ragged_is_the_only_path():
         EngineArgs(ragged_step=False)
     eng = tiny_engine()
     assert eng.ragged_fn is not None and eng.ragged_dec_fn is not None
-    assert eng.scheduler.token_budget
     toks, _ = await collect(eng, req(range(1, 20), max_tokens=6))
     assert len(toks) == 6
     kinds = {sig[0] for sig in eng.compiled_signatures}
@@ -795,7 +794,6 @@ async def test_token_budget_plan_deletes_chunk_clamp():
     chunks to the largest bucket; token-budget planning lets a chunk use
     the whole step budget — the 31-token prompt prefills in ONE step."""
     eng = tiny_engine(max_num_batched_tokens=32, prefill_buckets=(8,))
-    assert eng.scheduler.token_budget
     toks, _ = await collect(eng, req(range(1, 32), max_tokens=2))
     assert len(toks) == 2
     ragged_entries = [r for r in eng.flight.snapshot()
@@ -851,7 +849,7 @@ async def test_warmup_shrinks_to_token_buckets():
     kw = dict(block_size=4, num_blocks=256, max_num_seqs=8,
               max_num_batched_tokens=128, max_model_len=256)
     e_r = tiny_engine(**kw)
-    rep_r = await e_r.warmup(seq_lens=[128], prefill_batches=[1, 4])
+    rep_r = await e_r.warmup()
     # two variants (mixed + decode-only) per token bucket, nothing else
     assert len(rep_r["ragged"]) == 2 * len(e_r.args.ragged_token_buckets)
     assert {k for k, *_ in rep_r["ragged"]} == {"ragged", "ragged_dec"}
